@@ -15,8 +15,7 @@ is kept linear in the number of CHECKS, not signatures: an aggregate of
 same-message signatures from keys {sk_i} equals Sign(sum sk_i mod r), so
 each committee costs one G2 multiply to construct — and the whole built
 check set is cached on disk keyed by its shape, so only the FIRST attempt
-of a round pays it (a granted TPU window must never be spent on host-side
-setup; see TPU_NOTES.md).
+on a machine pays it (chip time is not spent on host-side setup).
 
 Env: BENCH_EPOCH_SLOTS, BENCH_EPOCH_COMMITTEES, BENCH_EPOCH_K,
 BENCH_EPOCH_K_SYNC, BENCH_EPOCH_POOL (pubkey pool size), BENCH_REPS.
@@ -55,7 +54,7 @@ def build_epoch_checks(slots, committees, k_att, k_sync, pool_size):
     32-block replay had just been collected). The (pubkeys, message,
     signature) triples are disk-cached by shape: they are deterministic in
     the parameters, and rebuilding them costs minutes of host-side G2
-    multiplies that would otherwise eat a granted TPU window."""
+    multiplies that would otherwise eat chip time."""
     pool_size = max(pool_size, k_att, k_sync)
     path = _cache_path(slots, committees, k_att, k_sync, pool_size)
     try:
@@ -110,8 +109,8 @@ def build_epoch_checks(slots, committees, k_att, k_sync, pool_size):
 def _seed_host_caches(col, slots, committees, k_att, k_sync, pool_size):
     """Disk-persist the limb encodings (hash-to-G2 points, decoded
     signatures/pubkeys) for this workload and seed the backend caches from
-    them — the checks are deterministic in the shape, and a granted TPU
-    window must not spend ~15 s re-hashing 2k messages in a cold process."""
+    them — the checks are deterministic in the shape, and a chip run must
+    not spend ~15 s re-hashing 2k messages in a cold process."""
     from ..ops import bls_backend as B
 
     msgs, sigs, pks = set(), set(), set()

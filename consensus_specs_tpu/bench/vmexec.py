@@ -73,7 +73,7 @@ def _run_cold_arm(dedup: bool, timeout_s: float = None) -> dict:
         timeout_s = float(os.environ.get("VMEXEC_COLD_TIMEOUT_S", "1800"))
     env = dict(os.environ)
     cache_dir = tempfile.mkdtemp(prefix="vmexec_cold_xla_")
-    env["CONSENSUS_SPECS_TPU_XLA_CACHE"] = cache_dir
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     env["CONSENSUS_SPECS_TPU_VM_CACHE"] = os.path.join(cache_dir, "vm")
     env["CONSENSUS_SPECS_TPU_VM_DEDUP"] = "1" if dedup else "0"
     env.pop("CONSENSUS_SPECS_TPU_VM_EXEC", None)

@@ -46,7 +46,7 @@ def main(argv=None) -> int:
         import tempfile
 
         smoke_cache = tempfile.mkdtemp(prefix="vmexec_cold_xla_")
-        os.environ["CONSENSUS_SPECS_TPU_XLA_CACHE"] = smoke_cache
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = smoke_cache
         os.environ["CONSENSUS_SPECS_TPU_VM_CACHE"] = os.path.join(
             smoke_cache, "vm")
 

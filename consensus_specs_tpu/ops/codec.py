@@ -31,10 +31,12 @@ verification:
   point addition, and cofactor clearing — the bulk of the field work —
   are lowered to the `h2g_finish` VM program.
 
-On the CPU fallback (no accelerator) the same algorithms run as a
-class-free raw-int host path instead — see the "host (CPU-fallback)
-batched path" section below for why and what stays batched there.
-`CONSENSUS_SPECS_TPU_CODEC_DEVICE=1/0` forces the placement.
+By default the same algorithms run as a class-free raw-int host path
+instead, on every backend — see the "host (CPU-fallback) batched path"
+section below for why and what stays batched there.
+`CONSENSUS_SPECS_TPU_CODEC_DEVICE=1` opts into the device placement off
+the TPU; on the TPU it raises (`_use_device`): the route is not brought
+up there.
 
 Every path is gated by oracle-equivalence tests (tests/test_codec.py)
 against `utils/bls12_381.py`, bit-identical including invalid encodings,
@@ -554,18 +556,20 @@ def hash_to_g2_batch(
 
 
 def _use_device() -> bool:
-    """Codec field math placement: VM/jax programs on a real accelerator,
-    raw-int host math on CPU. CONSENSUS_SPECS_TPU_CODEC_DEVICE=1/0
-    forces (tests use it to exercise the device path on CPU)."""
-    mode = os.environ.get("CONSENSUS_SPECS_TPU_CODEC_DEVICE", "auto")
-    if mode == "1":
-        return True
-    if mode == "0":
+    """Codec field math placement: raw-int host math unless
+    CONSENSUS_SPECS_TPU_CODEC_DEVICE=1 opts into the VM/jax programs
+    (tests use it to exercise the device path on CPU). The device route
+    is not brought up on the TPU, so opting in there is refused."""
+    if os.environ.get("CONSENSUS_SPECS_TPU_CODEC_DEVICE", "0") != "1":
         return False
-    try:
-        return jax.default_backend() != "cpu"
-    except Exception:
-        return False
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "CONSENSUS_SPECS_TPU_CODEC_DEVICE=1: the codec device route is "
+            "not brought up on the TPU. XLA's TPU compile of "
+            "codec._sswu_map_kernel never finished (stalled 25 and 7 "
+            "minutes on a v5e, PR 21); unset the variable to run the "
+            "bit-identical host path.")
+    return True
 
 
 _X_ABS = 0xD201000000010000  # |x|, the BLS parameter magnitude

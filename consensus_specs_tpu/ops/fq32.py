@@ -3,7 +3,7 @@ fallback representation for BLS12-381 on TPU (SURVEY §7.3 risk #1).
 
 The production path (ops/fq.py) uses 15x28-bit limbs with uint64
 accumulators; on v5e the vector unit is 32-bit, so u64 elementwise work is
-XLA-emulated. If hardware measurement (tools/tpu_probe.py) shows that
+XLA-emulated. If a chip measurement shows that
 emulation is the bottleneck, THIS module is the drop-in representation:
 
   - 32 limbs x 12 bits (384 bits capacity, p is 381 bits)

@@ -9,15 +9,17 @@ serializes exactly the axis the mesh just parallelized — and XLA's ``psum``
 cannot help, because its monoid vocabulary is scalar add/mul/min/max, not
 a 12-coefficient tower-field multiply.
 
-So the reduction rides the interconnect the same way the G1 aggregation
-tree does (``ops/mesh_reduce.py``): each device folds its LOCAL shard of
-chunk products with ``towers.fq12_mul``, then a log2(n)-round XOR
-butterfly of ``jax.lax.ppermute`` neighbor exchanges — an all-reduce whose
-monoid is the Fq12 multiply, spelled out because the collective library
-only knows scalar monoids. Fq12 multiplication is exact mod p and
-associative, so any association order (host left-fold, local fold +
-butterfly) yields the same field element: verdicts stay bit-identical to
-the single-device path, which is what tests/test_mesh_rlc.py pins.
+So the reduction rides the interconnect: one ``jax.lax.all_gather`` of
+the chunk products (a few KiB per device), then every device folds the
+whole gathered batch with ``towers.fq12_mul`` in one scan — an all-reduce
+whose monoid is the Fq12 multiply, spelled out because the collective
+library only knows scalar monoids. It replaced a local fold plus a
+log2(n)-round ``ppermute`` butterfly: each inlined ``fq12_mul`` is a large
+u64-emulated graph on the v5e, and the butterfly's three of them took
+122 s to compile for a described v5e:2x2, the single fold 31 s (PR 21).
+Fq12 multiplication is exact mod p and associative, so any association
+order yields the same field element: verdicts stay bit-identical to the
+single-device path, which is what tests/test_mesh_rlc.py pins.
 
 Identity filler: inactive lanes carry f = 1 (the product's identity), so
 padding the chunk-product batch up to the device count can never perturb
@@ -55,18 +57,6 @@ def _local_fold(fs):
     return acc
 
 
-def _butterfly_reduce(local, axis_name, n_dev):
-    """XOR butterfly all-reduce with Fq12 multiplication as the monoid:
-    after log2(n) ppermute rounds every device holds the full product."""
-    step = 1
-    while step < n_dev:
-        perm = [(i, i ^ step) for i in range(n_dev)]
-        recv = jax.lax.ppermute(local, axis_name, perm)
-        local = tw.fq12_mul(local, recv)
-        step *= 2
-    return local
-
-
 @functools.lru_cache(maxsize=8)
 def _mesh_prod_fn(mesh, n_dev: int):
     try:
@@ -78,8 +68,8 @@ def _mesh_prod_fn(mesh, n_dev: int):
     axis = mesh.axis_names[0]
 
     def per_device(fs):  # (rows/n, 12, L) local shard of chunk products
-        local = _local_fold(fs)
-        return _butterfly_reduce(local[None], axis, n_dev)
+        everyone = jax.lax.all_gather(fs, axis, tiled=True)
+        return _local_fold(everyone)[None]  # every device: the product
 
     return jax.jit(
         shard_map(
@@ -94,7 +84,7 @@ def _mesh_prod_fn(mesh, n_dev: int):
 def mesh_fq12_product(products: np.ndarray, mesh) -> np.ndarray:
     """Multiply a (n, 12, L) batch of flat Fq12 elements (loose Montgomery
     limbs) into ONE element over the mesh's first axis: local fold per
-    device + ICI butterfly. Returns (12, L) (device 0's replica)."""
+    device after one all-gather. Returns (12, L) (device 0's replica)."""
     n_dev = int(mesh.shape[mesh.axis_names[0]])  # reduction rides axis 0 only
     assert n_dev & (n_dev - 1) == 0, "mesh axis size must be a power of two"
     n = products.shape[0]

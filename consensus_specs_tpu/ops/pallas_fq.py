@@ -28,13 +28,12 @@ Value contract is identical to fq.mont_mul: loose Montgomery residues in,
 loose out (result < a*b/R + p), limbs of the INPUT must be < 2^28 (which
 every VM register and fq.add/sub/carry output satisfies). Cross-checked
 limb-exactly against fq.mont_mul and the exact-integer oracle in
-tests/test_ops_pallas.py (interpret mode on CPU; the real-hardware A/B is
-staged in tools/tpu_probe.py).
+tests/test_ops_pallas.py (interpret mode on CPU); its Mosaic compile for
+v5e is pinned in tests/test_chip_compile.py, and chip_smoke.py holds a
+whole VM program under this mode bit-identical to the default on the chip.
 
-Enable via CONSENSUS_SPECS_TPU_PALLAS=1 (see fq.mont_mul dispatch). Kept
-opt-in until a granted TPU window validates the Mosaic lowering end-to-end
-(TPU_NOTES.md: windows are scarce; the driver bench must never gamble on
-an unproven path).
+Enable via CONSENSUS_SPECS_TPU_PALLAS=1 (see fq.mont_mul dispatch). Opt-in
+until a chip measurement says it wins.
 """
 import functools
 import os
@@ -85,7 +84,16 @@ def _carry_rows(t, n_rows):
 
 
 def _pad_rows(v, top, total):
-    return jnp.pad(v, ((top, total - v.shape[0] - top), (0, 0)))
+    """Zero rows above and below ``v`` up to ``total`` rows. Built from
+    uint32 zeros, not ``jnp.pad``: under x64 the pad value is an int64
+    constant, which Mosaic cannot convert (RecursionError at lowering)."""
+    parts = [v]
+    bottom = total - v.shape[0] - top
+    if top:
+        parts.insert(0, jnp.zeros((top, v.shape[1]), dtype=v.dtype))
+    if bottom:
+        parts.append(jnp.zeros((bottom, v.shape[1]), dtype=v.dtype))
+    return jnp.concatenate(parts, axis=0) if len(parts) > 1 else v
 
 
 def mont_rows(a, b, p14):
@@ -141,11 +149,14 @@ def _pallas_mm(m_padded: int, interpret: bool):
     from jax.experimental.pallas import tpu as pltpu
 
     grid = m_padded // TILE_M
+    # block indices are int32: under x64 a bare 0 lowers to i64, which
+    # Mosaic refuses in an index map
     spec = pl.BlockSpec(
-        (L_PAD, TILE_M), lambda i: (0, i), memory_space=pltpu.VMEM
+        (L_PAD, TILE_M), lambda i: (jnp.int32(0), i), memory_space=pltpu.VMEM
     )
     p_spec = pl.BlockSpec(
-        (L_PAD, 1), lambda i: (0, 0), memory_space=pltpu.VMEM
+        (L_PAD, 1), lambda i: (jnp.int32(0), jnp.int32(0)),
+        memory_space=pltpu.VMEM,
     )
     call = pl.pallas_call(
         _mont_mul_kernel,
@@ -202,7 +213,6 @@ def mont_mul(a, b):
 
 
 def enabled() -> bool:
-    """Dispatch switch for fq.mont_mul. Opt-in (CONSENSUS_SPECS_TPU_PALLAS=1)
-    until a granted hardware window validates the Mosaic lowering; =0 forces
-    off. See tools/tpu_probe.py stage 'pallas_mont_mul'."""
+    """Dispatch switch for fq.mont_mul: opt-in (CONSENSUS_SPECS_TPU_PALLAS=1)
+    until a chip measurement says it wins; =0 forces off."""
     return os.environ.get("CONSENSUS_SPECS_TPU_PALLAS", "0") == "1"

@@ -87,15 +87,18 @@ def _fused_call(mm_padded: int, ml_padded: int, interpret: bool):
     gl = ml_padded // TILE_M
     grid = max(gm, gl)
 
+    # int32 block indices: Mosaic refuses the i64 a bare 0 lowers to
+    # under x64
     def tile_spec(g):
         return pl.BlockSpec(
             (L_PAD, TILE_M),
-            lambda i, g=g: (0, jnp.minimum(i, g - 1)),
+            lambda i, g=g: (jnp.int32(0), jnp.minimum(i, g - 1)),
             memory_space=pltpu.VMEM,
         )
 
     col_spec = pl.BlockSpec(
-        (L_PAD, 1), lambda i: (0, 0), memory_space=pltpu.VMEM
+        (L_PAD, 1), lambda i: (jnp.int32(0), jnp.int32(0)),
+        memory_space=pltpu.VMEM,
     )
     call = pl.pallas_call(
         functools.partial(_step_kernel, gm, gl),

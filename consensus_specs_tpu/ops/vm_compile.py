@@ -594,11 +594,21 @@ _FUSED: Dict[int, FusedProgram] = {}
 # ---------------------------------------------------------------------------
 
 
+def device_kind_tag() -> str:
+    """The device kind JAX runs on, as a file-name tag: the plan's
+    measured ms/row pick fused or interpreter for THIS device, so a CPU
+    measurement never picks the chip's route."""
+    import re
+
+    kind = jax.devices()[0].device_kind
+    return re.sub(r"[^0-9a-z]+", "-", kind.lower()).strip("-")
+
+
 def _plan_cache_path(program) -> Optional[str]:
-    """Disk path for this program's lowering plan, or None when the
-    program carries no cache identity (directly-assembled test programs,
-    pre-meta pickles). ``fusedplan_l<ver>`` re-keys fused artifacts
-    independently of the interpreter tensors; the retired PR 13
+    """Disk path for this program's lowering plan on this device kind, or
+    None when the program carries no cache identity (directly-assembled
+    test programs, pre-meta pickles). ``fusedplan_l<ver>`` re-keys fused
+    artifacts independently of the interpreter tensors; the retired PR 13
     ``fused_l…`` per-program keying is evicted by ``prune_vm_cache``."""
     meta = program.meta or {}
     key = meta.get("fused_key")
@@ -611,7 +621,8 @@ def _plan_cache_path(program) -> Optional[str]:
         bb._vm_cache_dir(),
         f"fusedplan_l{LOWERING_VERSION}_v{bb._VM_CACHE_VERSION}_{fp}"
         f"_{kind}_k{k}_f{fold}_w{meta.get('w_mul', 0)}x"
-        f"{meta.get('w_lin', 0)}_p{program.n_steps}_c{chunk_steps()}.pkl",
+        f"{meta.get('w_lin', 0)}_p{program.n_steps}_c{chunk_steps()}"
+        f"_d{device_kind_tag()}.pkl",
     )
 
 

@@ -34,6 +34,7 @@ then re-measure.
 import hashlib
 import json
 import os
+import socket
 import subprocess
 import sys
 import threading
@@ -124,6 +125,68 @@ class HashRing:
         return owners[i]
 
 
+def host_tpu_chips() -> int:
+    """TPU chips on this host's PCI bus, counted the way JAX counts them
+    before it loads libtpu (the router itself never takes a chip)."""
+    from jax._src import hardware_utils
+
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
+def _router_holds_tpu() -> bool:
+    """True once this process has brought up a TPU backend: it then holds
+    every chip, and no worker could get one."""
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return False
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
+def _free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("localhost", 0))
+        return s.getsockname()[1]
+
+
+def chip_envs(n_workers: int, env: Optional[Dict[str, str]] = None,
+              chips: Optional[int] = None) -> List[Dict[str, str]]:
+    """Per-worker environment placing each worker on its own device.
+
+    An explicit ``JAX_PLATFORMS=cpu`` (or a host with no TPU) adds
+    nothing: the workers inherit the platform. On a TPU host each worker
+    gets one chip of its own, with ports the OS hands out; more workers
+    than chips, or a router that already holds the TPU, is refused,
+    since a second process on a chip fails or hangs."""
+    platforms = (env or {}).get("JAX_PLATFORMS",
+                                os.environ.get("JAX_PLATFORMS", ""))
+    if chips is None:
+        chips = host_tpu_chips()
+    if platforms == "cpu" or chips == 0:
+        return [{} for _ in range(n_workers)]
+    if _router_holds_tpu():
+        raise ValueError(
+            "the fleet router has initialized the TPU and holds every "
+            "chip; start the fleet before this process touches the chip")
+    if n_workers > chips:
+        raise ValueError(
+            f"fleet of {n_workers} workers on a host with {chips} TPU "
+            f"chip(s): each worker needs a chip of its own; run at most "
+            f"{chips} workers, or set JAX_PLATFORMS=cpu")
+    envs = []
+    for i in range(n_workers):
+        port = str(_free_port())
+        envs.append({"TPU_VISIBLE_CHIPS": str(i),
+                     "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                     "TPU_PROCESS_BOUNDS": "1,1,1",
+                     "TPU_PROCESS_PORT": port,
+                     "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+                     "TPU_RUNTIME_METRICS_PORTS": str(_free_port())})
+    return envs
+
+
 class WorkerHandle:
     """One spawned worker process + its protocol plumbing.
 
@@ -139,7 +202,6 @@ class WorkerHandle:
         full_env.update(env or {})
         full_env[WORKER_ENV] = label
         full_env[BACKEND_ENV] = backend
-        full_env.setdefault("JAX_PLATFORMS", "cpu")
         self._proc = subprocess.Popen(
             [sys.executable, "-m", "consensus_specs_tpu.serve.worker"],
             stdin=subprocess.PIPE, stdout=subprocess.PIPE,
@@ -364,8 +426,9 @@ class FleetRouter:
         # comes back with the same backend/env/core slice it launched with
         self._backend = backend
         self._spawn_env: Dict[str, Dict[str, str]] = {}
-        for label, cores in zip(self._labels, slices):
-            worker_env = dict(env or {})
+        devices = chip_envs(len(self._labels), env)
+        for label, cores, device_env in zip(self._labels, slices, devices):
+            worker_env = dict(device_env, **(env or {}))
             if cores is not None:
                 worker_env.setdefault(CPU_ENV, cores)
             self._spawn_env[label] = worker_env

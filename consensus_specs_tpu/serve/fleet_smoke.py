@@ -248,5 +248,80 @@ def main() -> int:
             router.close()
 
 
+def chip_main(n_workers: int) -> int:
+    """``--chips N``: an N-worker fleet on a TPU host, each worker on a
+    chip of its own (``fleet.chip_envs``). The router signs the checks
+    on the CPU and never takes a chip; the verdicts must equal their
+    ground truth, and every worker must report one TPU device and hold
+    chip device files no other worker holds. Exits non-zero on a host
+    without N chips."""
+    import jax
+
+    # the router's own JAX stays on the CPU; the environment the workers
+    # inherit keeps the host's platforms
+    jax.config.update("jax_platforms", "cpu")
+    from ..utils import bls
+    from ..utils.bls12_381 import R
+    from .cache import check_key
+    from .fleet import FleetRouter, host_tpu_chips
+
+    chips = host_tpu_chips()
+    if chips < n_workers:
+        print(f"fleet-smoke FAIL: {n_workers} workers need {n_workers} "
+              f"TPU chips, the host has {chips}")
+        return 1
+    router = None
+    try:
+        t0 = time.perf_counter()
+        router = FleetRouter(workers=n_workers, backend="bls",
+                             env={"SERVE_MAX_WAIT_MS": "300"})
+        spawn_s = time.perf_counter() - t0
+        # committees of 4 until every worker owns at least two checks,
+        # every third one signed over another message
+        items, want, owners = [], [], {}
+        tag = 0
+        while min(owners.get(w, 0) for w in router.live_workers) < 2:
+            sks = [9000 * tag + j + 1 for j in range(4)]
+            msg = (b"chip%04d" % tag) + b"\x00" * 24
+            good = tag % 3 != 2
+            sig = bls.Sign(sum(sks) % R, msg if good else msg[::-1])
+            item = ("fast_aggregate", [bls.SkToPk(sk) for sk in sks],
+                    msg, sig)
+            label = router.route_label(check_key(*item))
+            owners[label] = owners.get(label, 0) + 1
+            items.append(item)
+            want.append(good)
+            tag += 1
+        t0 = time.perf_counter()
+        got = [bool(f.result(timeout=900))
+               for f in [router.submit(*it) for it in items]]
+        verify_s = time.perf_counter() - t0
+        assert got == want, f"fleet verdicts {got} != ground truth {want}"
+        devices = {label: snap["extra"]["device"]
+                   for label, snap in router.poll_snapshots().items()}
+        assert len(devices) == n_workers, f"snapshots from {list(devices)}"
+        for label, dev in devices.items():
+            assert dev and dev["platform"] == "tpu" and dev["count"] == 1, (
+                f"worker {label} is not on one chip: {dev}")
+        held = [f for d in devices.values() for f in d["chip_files"]]
+        assert all(d["chip_files"] for d in devices.values()) and (
+            len(set(held)) == len(held)), f"workers share a chip: {devices}"
+        print(json.dumps({"phase": "fleet", "workers": n_workers,
+                          "host_chips": chips, "checks": len(items),
+                          "spawn_s": spawn_s, "verify_s": verify_s,
+                          "verdicts": {"valid": sum(got),
+                                       "invalid": len(got) - sum(got)},
+                          "devices": devices}))
+        return 0
+    except Exception as e:
+        print(f"fleet-smoke FAIL: {type(e).__name__}: {e}")
+        return 1
+    finally:
+        if router is not None:
+            router.close()
+
+
 if __name__ == "__main__":
+    if sys.argv[1:2] == ["--chips"]:
+        sys.exit(chip_main(int(sys.argv[2])))
     sys.exit(main())

@@ -11,7 +11,7 @@ request).
 Emits the sustained signatures/sec + occupancy + cache-hit-rate + p95
 latency record that `bench.py --mode serve` prints as its JSON line.
 
-Env overrides (CPU-sized defaults; a granted TPU window can scale up):
+Env overrides (CPU-sized defaults; a chip run can scale up):
   SERVE_COMMITTEES, SERVE_K, SERVE_EVENTS, SERVE_RATE_HZ,
   SERVE_MAX_BATCH, SERVE_MAX_WAIT_MS, SERVE_INJECT_FAILURE (1/0),
   SERVE_SEED, SERVE_METRICS_PORT (opt-in /metrics + /snapshot + /healthz

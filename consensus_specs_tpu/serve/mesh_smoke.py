@@ -89,6 +89,8 @@ def main() -> int:
         # single-device reference run below, which also bisects)
         svc_bisections = (bls_backend.RLC_STATS["bisections"]
                           - stats_before["bisections"])
+        svc_reductions = (bls_backend.RLC_STATS["mesh_reductions"]
+                          - stats_before["mesh_reductions"])
         # direct evidence the flush ran SHARDED: the VM executions it
         # paid must carry sharded=True labels (narrow flushes would have
         # routed single-device and still produced matching verdicts)
@@ -106,15 +108,15 @@ def main() -> int:
             f"{svc.metrics.mesh_fallbacks} mesh fallback(s): the smoke "
             "only passed on the single-device path"
         )
-        # "zero SILENT fallbacks" covers both rungs: the serve-level
-        # degraded_* transitions AND the combine's host-multiply escape
-        # hatch (vm/mesh_reduce_fallback — verdicts stay right, but the
-        # cross-replica butterfly this smoke gates would be dead)
+        # "zero SILENT fallbacks": no serve-level degraded_* transition,
+        # and the flush's combine folded its chunk products over the mesh
         degraded = [e for e in rec.events()
-                    if e["kind"].startswith("degraded")
-                    or e["kind"] == "mesh_reduce_fallback"]
+                    if e["kind"].startswith("degraded")]
         assert not degraded, f"degradation transitions on clean traffic: " \
             f"{[e['kind'] for e in degraded]}"
+        assert svc_reductions > 0, (
+            "the service flush never reduced across the mesh (mesh_rlc)"
+        )
         assert svc_bisections > 0, (
             "the service flush never bisected — the corrupted item did "
             "not exercise the failed-sharded-combine path"

@@ -194,6 +194,31 @@ def _warm(k: int, sizes) -> None:
         bls_backend.batch_verify_rlc(items[:size])
 
 
+def _device():
+    """This worker's JAX devices once its backend is up, and the chip
+    device files it holds open (a chip's vfio group or accel node opens
+    in one process only); None before (a snapshot never brings JAX up
+    itself)."""
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return None
+    import jax
+
+    held = set()
+    for fd in os.listdir("/proc/self/fd"):
+        try:
+            path = os.readlink(f"/proc/self/fd/{fd}")
+        except OSError:
+            continue  # closed since the listing
+        if path.startswith("/dev/accel") or (
+                path.startswith("/dev/vfio/") and path != "/dev/vfio/vfio"):
+            held.add(path)
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs), "chip_files": sorted(held)}
+
+
 def _decode_submit(msg):
     kind = msg["kind"]
     pubkeys = [bytes.fromhex(pk) for pk in msg["pubkeys"]]
@@ -272,7 +297,8 @@ def main() -> int:
                         extra={"serve": svc.metrics.snapshot(),
                                "ladder_rung": svc.ladder_rung,
                                "faults_fired": backend.fired,
-                               "warm_bg": vm_compile._bg_warm_enabled()},
+                               "warm_bg": vm_compile._bg_warm_enabled(),
+                               "device": _device()},
                         flight_since=int(msg.get("flight_since", 0)),
                         spans_since=int(msg.get("spans_since", 0)))
                     send({"op": "snapshot", "id": req_id, "data": data})

@@ -1,20 +1,22 @@
-"""Unit tests for bench.py's parent/child harness logic.
+"""bench.py's default mode: one process, on the device JAX finds.
 
-The accelerator child emits committee-stage lines, epoch-stage lines, a
-pallas_ab probe line, and error lines, all interleaved; `_best_line` is
-the parent's only view of a killed window, so its selection rules are
-what decide whether a granted window becomes a recorded number
-(TPU_NOTES.md; round-4 verdict item 1). These tests pin those rules
-without needing any device.
+A run that was asked for an accelerator and found none prints an error
+line and exits non-zero — never a CPU number under the chip metric. An
+explicit ``JAX_PLATFORMS=cpu`` run names the CPU it ran on. Also pins
+where the persistent compile cache goes (``ops/__init__.py`` is the one
+place that sets it) and that fused-plan measurements are keyed by the
+device kind.
 """
 import importlib.util
 import json
 import os
+import subprocess
 import sys
 
 import pytest
 
-_BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "bench.py")
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_BENCH = os.path.join(_REPO, "bench.py")
 
 
 @pytest.fixture(scope="module")
@@ -25,180 +27,98 @@ def bench():
     return mod
 
 
-def _lines(*objs):
-    return ("\n".join(json.dumps(o) for o in objs)).encode()
+def _run(args, env_updates, drop=()):
+    env = dict(os.environ)
+    for k in drop:
+        env.pop(k, None)
+    env.update(env_updates)
+    return subprocess.run([sys.executable] + args, cwd=_REPO, env=env,
+                          capture_output=True, text=True, timeout=300)
 
 
-def test_best_line_picks_max_value(bench):
-    best, err = bench._best_line(_lines(
-        {"value": 100.0, "mode": "committee", "stage": "rep 1/3"},
-        {"value": 300.0, "mode": "committee"},
-        {"value": 250.0, "mode": "epoch", "stage": "warmup (compile-inclusive)"},
-    ))
-    assert err is None
-    assert best["value"] == 300.0
-    # both modes landed: each mode's best is attached for the record
-    assert best["per_mode_best"] == {"committee": 300.0, "epoch": 250.0}
+def _json_lines(stdout):
+    out = []
+    for line in stdout.splitlines():
+        try:
+            out.append(json.loads(line))
+        except ValueError:
+            pass
+    return out
 
 
-def test_best_line_single_mode_has_no_per_mode_key(bench):
-    best, _ = bench._best_line(_lines({"value": 42.0, "mode": "committee"}))
-    assert best["value"] == 42.0
-    assert "per_mode_best" not in best
+@pytest.mark.parametrize("platforms", ["tpu", None])
+def test_bench_without_accelerator_exits_nonzero_with_no_number(platforms):
+    """Asked for a TPU (or for whatever JAX finds) on a machine with none:
+    one error line, no value, a failing exit."""
+    env = {"BENCH_N": "1", "BENCH_K": "1", "BENCH_REPS": "1"}
+    if platforms is None:
+        proc = _run([_BENCH], env, drop=("JAX_PLATFORMS",))
+    else:
+        proc = _run([_BENCH], dict(env, JAX_PLATFORMS=platforms))
+    assert proc.returncode != 0
+    lines = _json_lines(proc.stdout)
+    assert lines and all("value" not in ln for ln in lines)
+    assert "error" in lines[-1]
 
 
-def test_best_line_warmup_shape_never_shadows_comparable(bench):
-    """ADVICE round 5: the stage-0 4x8 liveness shape posts absurd
-    per-sig rates; it must not become the headline OR occupy the
-    committee slot of per_mode_best when a comparable shape landed."""
-    best, err = bench._best_line(_lines(
-        {"value": 9000.0, "mode": "committee", "n": 4, "k": 8},
-        {"value": 310.0, "mode": "committee", "n": 32, "k": 128},
-        {"value": 250.0, "mode": "epoch"},
-    ))
-    assert err is None
-    assert best["value"] == 310.0 and (best["n"], best["k"]) == (32, 128)
-    assert best["per_mode_best"] == {
-        "committee[4x8]": 9000.0,
-        "committee[32x128]": 310.0,
-        "epoch": 250.0,
-    }
+def test_device_info_refuses_an_unrequested_cpu(bench, monkeypatch):
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(bench.NoAccelerator):
+        bench.device_info()
 
 
-def test_best_line_warmup_shape_used_when_alone(bench):
-    """A window that only landed the liveness pre-pass still records it
-    (better a tiny-shape number than none)."""
-    best, _ = bench._best_line(_lines(
-        {"value": 9000.0, "mode": "committee", "n": 4, "k": 8},
-    ))
-    assert best["value"] == 9000.0
+def test_device_info_names_an_explicit_cpu(bench, monkeypatch):
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    info = bench.device_info()
+    assert info["platform"] == "cpu" and info["count"] >= 1
+    assert info["kind"]
 
 
-def test_best_line_attaches_probes_and_surfaces_error(bench):
-    best, err = bench._best_line(_lines(
-        {"value": 500.0, "mode": "committee"},
-        {"value": 0.0, "error": "epoch stage RuntimeError: device lost"},
-        {"probe": "pallas_ab", "pallas_over_u64": 2.5, "pallas_chain_match": True},
-        {"probe": "vm_step_ab", "fused_over_u64": 3.0},
-    ))
-    # a later stage's failure must not discard the landed committee number
-    assert best["value"] == 500.0
-    # BOTH probe lines survive, keyed by name, without the "probe" key
-    assert best["probes"]["pallas_ab"]["pallas_over_u64"] == 2.5
-    assert best["probes"]["vm_step_ab"]["fused_over_u64"] == 3.0
-    assert "probe" not in best["probes"]["pallas_ab"]
-    assert "device lost" in err
-
-
-def test_best_line_none_on_errors_only(bench):
-    best, err = bench._best_line(_lines({"value": 0.0, "error": "backend init hang"}))
-    assert best is None
-    assert err == "backend init hang"
-
-
-def test_best_line_ignores_garbage(bench):
-    raw = b"WARNING: noise\n" + _lines({"value": 7.0, "mode": "committee"}) + b"\nnot json"
-    best, err = bench._best_line(raw)
-    assert best["value"] == 7.0 and err is None
-
-
-@pytest.mark.slow  # ~185 s: the worst tier-1 offender (ISSUE 11 audit)
-def test_child_runs_committee_then_epoch_then_probe(bench, monkeypatch, capsys):
-    """The child must run the window-proven committee shape FIRST, then
-    epoch, then the pallas A/B — one process, every stage surviving the
-    previous one's failure (round-4 verdict: a grant must never be
-    gambled on epoch mode alone)."""
-    calls = []
-
-    def fake_run_workload(emit_partial=None, override=None, child_quick=False):
-        calls.append(override)
-        if override[3] == "epoch":
-            raise RuntimeError("window died mid-epoch")
-        return {"value": 123.0, "vs_baseline": 0.1, "mode": override[3]}
-
-    class FakeJax:
-        @staticmethod
-        def default_backend():
-            return "tpu"
-
-    monkeypatch.setattr(bench, "run_workload", fake_run_workload)
-    monkeypatch.setitem(sys.modules, "jax", FakeJax())
-    monkeypatch.setenv(bench._CHILD_FLAG, "1")
+def test_workload_params_defaults_and_overrides(bench, monkeypatch):
     for v in ("BENCH_N", "BENCH_K", "BENCH_REPS", "BENCH_MODE"):
         monkeypatch.delenv(v, raising=False)
-
-    bench.main()
-    out = [json.loads(l) for l in capsys.readouterr().out.strip().splitlines()]
-
-    assert calls[0] == (4, 8, 1, "committee")  # instant first TPU number
-    assert calls[1] == (32, 128, 3, "committee")
-    assert calls[2][3] == "epoch"
-    assert out[0]["value"] == 123.0 and out[0]["mode"] == "committee"
-    assert any("epoch stage RuntimeError" in o.get("error", "") for o in out)
-    # both probe stages still ran after the epoch failure (probe_error is
-    # fine here: the fake jax can't run a real kernel)
-    assert [o["probe"] for o in out if "probe" in o] == [
-        "pallas_ab", "vm_step_ab",
-    ]
-
-
-def test_child_env_override_collapses_to_single_stage(bench, monkeypatch, capsys):
-    calls = []
-
-    def fake_run_workload(emit_partial=None, override=None, child_quick=False):
-        calls.append((override, child_quick))
-        return {"value": 9.0, "vs_baseline": 0.01, "mode": "epoch"}
-
-    monkeypatch.setattr(bench, "run_workload", fake_run_workload)
-    monkeypatch.setenv(bench._CHILD_FLAG, "1")
+    assert bench._workload_params() == (32, 128, 3, "committee")
     monkeypatch.setenv("BENCH_MODE", "epoch")
-
-    bench.main()
-    out = [json.loads(l) for l in capsys.readouterr().out.strip().splitlines()]
-    assert calls == [(None, True)]
-    assert out[-1]["value"] == 9.0
+    monkeypatch.setenv("BENCH_K", "8")
+    assert bench._workload_params() == (32, 8, 3, "epoch")
 
 
-def test_init_watchdog_fires_on_hang(bench, monkeypatch, capsys):
-    """A backend init that outlives BENCH_INIT_DEADLINE must flush a
-    parseable error line and exit the child — the harvest loop's sampling
-    rate depends on dead attempts dying fast."""
-    import threading
-
-    monkeypatch.setenv("BENCH_INIT_DEADLINE", "0.05")
-    exited = threading.Event()
-    codes = []
-
-    def fake_exit(code):
-        codes.append(code)
-        exited.set()
-
-    class HangingJax:
-        @staticmethod
-        def default_backend():
-            exited.wait(5)  # blocks until the watchdog "exits"
-            return "tpu"
-
-    monkeypatch.setitem(sys.modules, "jax", HangingJax())
-    got = bench._init_backend_with_watchdog(exit_fn=fake_exit)
-    assert codes == [3]
-    assert got is False  # the fake backend eventually answered 'tpu'
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert "backend init exceeded" in line["error"]
+def test_bench_has_no_child_watchdog_or_fallback(bench):
+    """The old harness is gone: no child flag, no init watchdog,
+    no best-line selection across a child's output."""
+    for name in ("_CHILD_FLAG", "_init_backend_with_watchdog", "_best_line",
+                 "_run_child_attempt"):
+        assert not hasattr(bench, name), name
 
 
-def test_init_watchdog_noop_on_fast_init(bench, monkeypatch, capsys):
-    monkeypatch.setenv("BENCH_INIT_DEADLINE", "5")
+def _cache_dir_in_fresh_process(env_updates, drop=()):
+    proc = _run(["-c", (
+        "import jax, consensus_specs_tpu.ops.bls_backend; "
+        "print(jax.config.jax_compilation_cache_dir)")],
+        dict(env_updates, JAX_PLATFORMS="cpu"), drop=drop)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return proc.stdout.strip().splitlines()[-1]
 
-    class FastJax:
-        @staticmethod
-        def default_backend():
-            return "cpu"
 
-    monkeypatch.setitem(sys.modules, "jax", FastJax())
-    codes = []
-    assert bench._init_backend_with_watchdog(exit_fn=codes.append) is True
-    import time
+def test_compile_cache_honours_jax_compilation_cache_dir(tmp_path):
+    want = str(tmp_path / "outside_cache")
+    assert _cache_dir_in_fresh_process(
+        {"JAX_COMPILATION_CACHE_DIR": want}) == want
 
-    time.sleep(0.1)
-    assert codes == [] and capsys.readouterr().out == ""
+
+def test_compile_cache_defaults_to_repo_jax_cache():
+    got = _cache_dir_in_fresh_process(
+        {}, drop=("JAX_COMPILATION_CACHE_DIR",))
+    assert got == os.path.join(_REPO, ".jax_cache")
+
+
+def test_fused_plan_path_carries_the_device_kind():
+    from consensus_specs_tpu.ops import bls_backend, vm_compile
+
+    program, _ = bls_backend._program("g1_subgroup")
+    tag = vm_compile.device_kind_tag()
+    assert tag == "cpu"
+    path = vm_compile._plan_cache_path(program)
+    assert os.path.basename(path).endswith(f"_d{tag}.pkl")
+    # the cache-prune parser still reads the keyed name
+    assert not bls_backend._vm_cache_entry_stale(os.path.basename(path))

@@ -122,6 +122,26 @@ def test_reset_prep_state_clears_pool_latch_and_counters():
     assert profiling.summary()["bls.prep_pool_broken"]["gauge"] == 0.0
 
 
+def test_easy_parts_pool_failure_is_counted(monkeypatch):
+    """A failed easy-part pool still answers (serially) but counts its
+    items as serial fallbacks, which chip_smoke.py gates on."""
+    import numpy as np
+
+    from consensus_specs_tpu.ops import bls_backend
+
+    def broken(*args, **kwargs):
+        raise OSError("fork refused")
+
+    monkeypatch.setattr(bls_backend, "_pool_map", broken)
+    monkeypatch.setenv("CONSENSUS_SPECS_TPU_HASH_PROCS", "2")
+    monkeypatch.setitem(bls_backend.PREP_STATS, "serial_fallback_items", 0)
+    coeffs = {i: [i + 2] + [i] * 11 for i in range(64)}
+    got = bls_backend._easy_parts_pooled(coeffs)
+    assert bls_backend.PREP_STATS["serial_fallback_items"] == 64
+    for i in (0, 63):
+        assert np.array_equal(got[i], bls_backend._easy_worker(coeffs[i]))
+
+
 # -- .vm_cache pruning (ISSUE 6 satellite) -----------------------------------
 
 

@@ -275,6 +275,26 @@ def force_device(monkeypatch):
     monkeypatch.setenv("CONSENSUS_SPECS_TPU_CODEC_DEVICE", "1")
 
 
+@pytest.mark.parametrize("backend,env,want", [
+    ("cpu", None, False), ("tpu", None, False), ("tpu", "0", False),
+    ("cpu", "1", True)])
+def test_codec_placement(monkeypatch, backend, env, want):
+    monkeypatch.setattr(codec.jax, "default_backend", lambda: backend)
+    if env is None:
+        monkeypatch.delenv("CONSENSUS_SPECS_TPU_CODEC_DEVICE", raising=False)
+    else:
+        monkeypatch.setenv("CONSENSUS_SPECS_TPU_CODEC_DEVICE", env)
+    assert codec._use_device() is want
+
+
+def test_codec_device_route_refused_on_tpu(force_device, monkeypatch):
+    """The route that never finished compiling on a v5e is refused there
+    with the reason, not left to hang."""
+    monkeypatch.setattr(codec.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="_sswu_map_kernel"):
+        codec.pubkey_limbs_batch(_G1[:1])
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("n", [1, 3, 8])
 def test_device_pubkey_batch(force_device, n):

@@ -15,7 +15,8 @@ from consensus_specs_tpu.obs import snapshot as osnap
 from consensus_specs_tpu.obs.fleet import FleetAggregator
 from consensus_specs_tpu.obs.slo import ShedPolicy, SloTracker, worst_burn
 from consensus_specs_tpu.serve.cache import check_key
-from consensus_specs_tpu.serve.fleet import FleetRouter, HashRing
+from consensus_specs_tpu.serve import fleet as fleet_mod
+from consensus_specs_tpu.serve.fleet import FleetRouter, HashRing, chip_envs
 from consensus_specs_tpu.serve.load import BAD_SIGNATURE
 from consensus_specs_tpu.ops import profiling
 
@@ -85,6 +86,63 @@ def test_ring_removal_only_remaps_the_drained_workers_keys():
 
 
 # -- aggregator merge algebra -------------------------------------------------
+
+
+def test_workers_stay_on_cpu_without_a_tpu_or_when_told(monkeypatch):
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    assert chip_envs(3, chips=0) == [{}] * 3
+    assert chip_envs(2, {"JAX_PLATFORMS": "cpu"}, chips=4) == [{}] * 2
+
+
+def test_each_worker_gets_a_chip_of_its_own(monkeypatch):
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(fleet_mod, "_router_holds_tpu", lambda: False)
+    envs = chip_envs(4, chips=4)
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    ports = [e[k] for e in envs
+             for k in ("TPU_PROCESS_PORT", "TPU_RUNTIME_METRICS_PORTS")]
+    assert all(p.isdigit() and int(p) > 0 for p in ports)
+    assert all(e["TPU_PROCESS_ADDRESSES"]
+               == "localhost:" + e["TPU_PROCESS_PORT"] for e in envs)
+    assert all("JAX_PLATFORMS" not in e for e in envs)
+
+
+@pytest.mark.parametrize("why,chips,router_holds,match", [
+    ("more workers than chips", 1, False, "chip of its own"),
+    ("router holds the chips", 4, True, "holds every chip")])
+def test_chip_assignment_refusals(monkeypatch, why, chips, router_holds,
+                                  match):
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(fleet_mod, "_router_holds_tpu",
+                        lambda: router_holds)
+    with pytest.raises(ValueError, match=match):
+        chip_envs(2, chips=chips)
+
+
+def test_host_chip_count_reads_the_pci_bus(monkeypatch, tmp_path):
+    """Chips are TPU devices on the PCI bus (Google vendor id, a TPU
+    device id), not any device file: two v5e chips beside a Google NIC
+    and a foreign device count as 2."""
+    from jax._src import hardware_utils
+
+    for name, vendor, device in [("a", "0x1ae0", "0x0063"),
+                                 ("b", "0x1ae0", "0x0063"),
+                                 ("c", "0x1ae0", "0x0042"),
+                                 ("d", "0x8086", "0x0063")]:
+        (tmp_path / name).mkdir()
+        (tmp_path / name / "vendor").write_text(vendor + "\n")
+        (tmp_path / name / "device").write_text(device + "\n")
+    monkeypatch.setattr(hardware_utils.glob, "glob",
+                        lambda pat: sorted(str(p) for p in
+                                           tmp_path.glob("*/vendor")))
+    assert fleet_mod.host_tpu_chips() == 2
+
+
+def test_fleet_chip_smoke_refuses_a_host_without_chips(capsys):
+    from consensus_specs_tpu.serve import fleet_smoke
+
+    assert fleet_smoke.chip_main(2) == 1
+    assert "need 2 TPU chips, the host has 0" in capsys.readouterr().out
 
 
 def test_aggregator_merges_hists_exactly_and_namespaces_gauges():

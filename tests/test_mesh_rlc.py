@@ -3,7 +3,7 @@
 Covers the whole rung: the CONSENSUS_SPECS_TPU_MESH provider
 (utils/jax_env.get_mesh), the _FoldLayout mesh fold-capping / row-padding
 rules (previously untested — the ceil(n/devices) clamp and the
-pad-rows-to-device-count floor), the cross-replica Fq12 butterfly
+pad-rows-to-device-count floor), the cross-replica Fq12 all-gather
 reduction (ops/mesh_rlc.py) against the exact-int oracle, end-to-end
 verdict identity of ``batch_verify_rlc(items, mesh=...)`` vs the
 single-device path over valid/invalid/malformed/infinity inputs
@@ -12,7 +12,7 @@ plane's mesh degradation rung (mesh failure -> single-device RLC with a
 ``degraded_mesh_to_single`` flight event + the serve.mesh_fallbacks
 gauge).
 
-Tier-1 keeps to the 4-device mixed batch (the multi-chunk butterfly
+Tier-1 keeps to the 4-device mixed batch (the multi-chunk cross-replica
 case) plus jax-free layout/serve tests; the wider device counts
 (2 and 8, wide batches) ride --run-slow with the other device-deep
 suites.
@@ -161,7 +161,7 @@ def test_rlc_chunk_shards_the_width():
     assert bb._rlc_chunk(2, _fake_mesh(2)) == 1
 
 
-# -- cross-replica Fq12 butterfly (ops/mesh_rlc.py) -------------------------
+# -- cross-replica Fq12 reduction (ops/mesh_rlc.py) ------------------------
 
 
 def _rand_f(rng: random.Random) -> O.Fq12:
@@ -173,9 +173,9 @@ def _rand_f(rng: random.Random) -> O.Fq12:
     )
 
 
-@pytest.mark.slow  # ~36 s of butterfly compiles (ISSUE 11 tier-1 audit)
+@pytest.mark.slow  # tens of seconds of fold compiles (ISSUE 11 tier-1 audit)
 def test_mesh_fq12_product_matches_oracle():
-    """Local fold + ppermute butterfly == exact-int oracle product, at
+    """All-gather + Fq12 fold == exact-int oracle product, at
     sub-device-count (identity padding) and multi-row widths."""
     rng = random.Random(17)
     mesh = _mesh(4)
@@ -226,7 +226,7 @@ def test_mesh_verdict_identity_mixed_batch(monkeypatch):
     """batch_verify_rlc over a 4-device mesh: bit-identical to the
     single-device path and the pinned host-oracle pattern, with the
     corrupted item bisecting through the failed SHARDED combine (chunk 1
-    per device -> the cross-replica butterfly actually reduces)."""
+    per device -> the cross-replica reduction actually reduces)."""
     monkeypatch.setenv("CONSENSUS_SPECS_TPU_RLC_CHUNK", "2")
     items = _mixed_items()
     single = bb.batch_verify_rlc(items, rng=random.Random(0xA5))
@@ -240,6 +240,25 @@ def test_mesh_verdict_identity_mixed_batch(monkeypatch):
     assert d["items"] == 2
     assert d["bisections"] >= 1  # the failed sharded combine split
     assert d["final_exps"] == 3  # root combine + 2 singleton finalizations
+    # the chunk products were folded across the mesh, not on the host
+    assert d["mesh_reductions"] >= 1
+
+
+def test_mesh_reduction_failure_is_not_hidden(monkeypatch):
+    """A failing cross-replica reduction propagates out of the combine
+    (the service's mesh rung counts it) instead of a silent host
+    multiply that would leave the verdicts right and the mesh path dead."""
+    monkeypatch.setenv("CONSENSUS_SPECS_TPU_RLC_CHUNK", "2")
+
+    def broken(products, mesh):
+        raise RuntimeError("all_gather refused")
+
+    monkeypatch.setattr(mesh_rlc, "mesh_fq12_product", broken)
+    before = bb.RLC_STATS["mesh_reductions"]
+    with pytest.raises(RuntimeError, match="all_gather refused"):
+        bb.batch_verify_rlc(_mixed_items(), mesh=_mesh(4),
+                            rng=random.Random(0xA5))
+    assert bb.RLC_STATS["mesh_reductions"] == before
 
 
 @pytest.mark.slow
