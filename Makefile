@@ -127,8 +127,7 @@ serve-bench:
 
 # serve bench with the full observability plane on: per-request span
 # tracing exported as Chrome trace-event JSON (open serve_trace.json in
-# chrome://tracing or Perfetto — device-occupancy and flight-recorder
-# lanes included), the flight recorder's JSONL journal dumped next to it,
+# chrome://tracing or Perfetto — flight-recorder lane included), the flight recorder's JSONL journal dumped next to it,
 # and the /metrics + /snapshot + /healthz (SLO-bearing) + /flightdump
 # endpoint live on an ephemeral port during the run. CI uploads
 # serve_trace.json as a build artifact.
@@ -139,7 +138,7 @@ serve-trace:
 # device count (SERVE_MESH_DEVICES, default 1,2,4,8 virtual CPU devices;
 # the count is frozen at XLA backend init, hence child processes), fault
 # injection off. The JSON line's `mesh` section carries per-count
-# sigs/sec, per-device occupancy lanes, mesh fallbacks, and scaling
+# sigs/sec, mesh fallbacks, and scaling
 # efficiency vs single-device (report-only on CPU — two host cores
 # timeshare every virtual device; tools/bench_compare.py gates the
 # ok-STATE: a device count that verified last round and errors now fails)

@@ -32,8 +32,7 @@ hit rate, p50/p95/p99 submit->result latency, and the prep-vs-device time
 split per flush (knobs: SERVE_* env vars, see serve/load.py). Add
 `--trace out.json` to record per-request spans (queue-wait/prep/device/
 combine/finalize) + VM program executions and export Chrome trace-event
-JSON (device-occupancy and flight-recorder lanes included when those
-planes are armed); `--flight out.jsonl` arms the flight recorder
+JSON (the flight-recorder lane included when it is armed); `--flight out.jsonl` arms the flight recorder
 (obs/flight.py) and dumps its structured-event journal after the run;
 SERVE_METRICS_PORT=<port|0> additionally serves Prometheus `/metrics` +
 `/snapshot` + `/healthz` (now SLO-state-bearing) + `/flightdump` during
@@ -46,7 +45,7 @@ axis, the combine's product folds cross-replica via the Fq12 ppermute
 butterfly, and the flush still pays ONE final exponentiation).
 `--mode serve-mesh` is the scaling sweep: one `--mode serve --mesh d`
 child per device count (SERVE_MESH_DEVICES, default 1,2,4,8), emitting a
-`mesh` section — per-count sigs/sec, per-device occupancy, mesh
+`mesh` section — per-count sigs/sec, mesh
 fallbacks, efficiency vs single-device — that tools/bench_compare.py
 gates on ok-state round over round (`make serve-bench-mesh`).
 

@@ -351,8 +351,9 @@ def mesh_shards(mesh, run):
     seen = []
     inner = vm._execute_device
 
-    def spy(stacked, template, input_regs, output_regs, instr, mesh_):
-        out = inner(stacked, template, input_regs, output_regs, instr, mesh_)
+    def spy(stacked, template, input_regs, output_regs, instr, mesh_, **kw):
+        out = inner(stacked, template, input_regs, output_regs, instr, mesh_,
+                    **kw)
         if mesh_ is not None:
             idx = out.sharding.devices_indices_map(out.shape)
             seen.append({d.id: str(ix[0]) for d, ix in idx.items()})

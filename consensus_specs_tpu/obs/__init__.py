@@ -8,8 +8,10 @@ it exports three surfaces:
                   sweep) in a bounded ring with slow-request exemplar
                   pinning, plus VM execution events; Chrome trace-event
                   export (``dump_trace`` / ``bench.py --mode serve
-                  --trace``) composing the device-occupancy and
-                  flight-recorder lanes. Opt-in ``CONSENSUS_SPECS_TPU_TRACE=1``.
+                  --trace``) composing the flight-recorder lane. Opt-in
+                  ``CONSENSUS_SPECS_TPU_TRACE=1``. Always on: program
+                  spans (``span``) on the profiler's clock and the ring
+                  of ``rlc``/``serve`` flush records (``flush_records``).
 - ``registry``  — the canonical metric-name registry + span-stage
                   registry (both drift-gated by tier-1) and the
                   Prometheus text renderer (histogram exposition incl.).
@@ -21,8 +23,6 @@ it exports three surfaces:
 - ``hist``      — mergeable log-bucketed histograms (fixed base-2/
                   8-subbucket bounds: exact cross-device/node merges) —
                   the latency metric type behind ``ops/profiling``.
-- ``devices``   — per-device occupancy ledger (busy/idle timelines,
-                  ``device[<lane>]`` utilization gauges, Chrome lane).
 - ``flight``    — cross-plane flight recorder (bounded ring journal of
                   serve/chain/vm events, JSONL dump on fault/demand).
 - ``slo``       — declared latency objectives + multi-window burn rates

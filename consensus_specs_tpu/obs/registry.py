@@ -150,10 +150,6 @@ GAUGES: Dict[str, str] = {
                                  ".vm_cache/ prune in this process",
     "hist.families": "latency-histogram families tracked by this process "
                      "(mergeable log-bucketed distributions)",
-    "device.count": "devices (plus the host prep lane) the occupancy "
-                    "ledger has seen busy",
-    "device.busy_s": "total busy seconds across all device lanes since "
-                     "ledger start/reset",
     "flight.events": "structured events the flight recorder has journaled "
                      "(ring-bounded; see flight.dropped)",
     "flight.dropped": "flight-recorder events overwritten by ring churn "
@@ -290,9 +286,6 @@ LATENCIES: Dict[str, str] = {
 DYNAMIC_PREFIXES: Dict[str, tuple] = {
     "vm[": ("vm_execute", "per-program VM execution timing, labelled "
                           "vm[steps=...,regs=...,batch=...,sharded=...]"),
-    "device[": ("device_busy_frac", "per-device occupancy (busy seconds / "
-                                    "elapsed), labelled device[<index>] "
-                                    "(device[host] is the prep lane)"),
     "latency[": ("latency_stage", "per-stage gossip→head latency "
                                   "histograms, labelled latency[<stage>] "
                                   "over the fixed obs/latency.py stage "

@@ -22,10 +22,19 @@ executions on pid 2, plus the per-program registry (``obs/programs.py``:
 steps, register-file size, assembly time, ``.vm_cache/`` hit/miss) under
 the top-level ``programRegistry`` key. ``bench.py --mode serve --trace
 out.json`` wires the whole thing end to end.
+
+Program spans and flush records (always on, see the section at the end):
+``span(name, **args)`` marks a stage of the RLC flush or of the service
+on the profiler's clock (a ``jax.profiler.TraceAnnotation``, recorded
+only inside a profiler session) and adds its seconds to the flush record
+open on the calling thread; ``flush_records()`` returns the bounded ring
+of ``rlc`` and ``serve`` records.
 """
+import contextlib
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -340,8 +349,8 @@ class Tracer:
 # router-side chain batch still emits the flow FINISH — Perfetto joins
 # the two halves by id across pids.
 
-# worker lanes start here: pid 1-4 are the router's own lanes (serve /
-# vm / devices / flight), workers take 100+index in snapshot order
+# worker lanes start here: pids below are the router's own lanes (serve
+# 1, vm 2, flight 4), workers take 100+index in snapshot order
 WORKER_PID_BASE = 100
 
 
@@ -489,45 +498,37 @@ def reset_global() -> None:
 
 def dump_trace(path: str) -> str:
     """Export the global tracer's rings as Chrome trace-event JSON, with
-    the fleet lanes composed in: the per-device occupancy timeline
-    (obs/devices.py, pid 3) and the flight-recorder journal
-    (obs/flight.py, pid 4 instants) share the tracer's clock, so the span
-    view, the busy/idle view, and the black box line up on one timeline.
-    Disabled/empty lanes contribute nothing (``Tracer.dump`` alone stays
-    the lane-free export the golden test pins)."""
-    from . import devices, flight
+    the flight-recorder journal (obs/flight.py, pid 4 instants) composed
+    in: it shares the tracer's clock, so the span view and the black box
+    line up on one timeline. A disabled/empty journal contributes nothing
+    (``Tracer.dump`` alone stays the lane-free export the golden test
+    pins)."""
+    from . import flight, fsio
 
     tracer = global_tracer()
-    # epoch rewind for the composed lanes: a journal/occupancy event can
-    # predate the lazily-created tracer (e.g. a program resolution noted
-    # before the first traced execution) — same rule note_execution
-    # applies to its own early events, so no lane exports negative ts
-    earliest = min(
-        (t for t in (devices.earliest_timestamp(),
-                     flight.earliest_timestamp()) if t is not None),
-        default=None)
+    # epoch rewind for the composed lane: a journal event can predate the
+    # lazily-created tracer (e.g. a program resolution noted before the
+    # first traced execution) — same rule note_execution applies to its
+    # own early events, so no lane exports negative ts
+    earliest = flight.earliest_timestamp()
     if earliest is not None:
         with tracer._lock:
             tracer._t0 = min(tracer._t0, earliest)
     doc = tracer.to_chrome()
-    doc["traceEvents"].extend(devices.chrome_events(tracer._us))
     doc["traceEvents"].extend(flight.chrome_events(tracer._us))
-    from . import fsio
-
     return fsio.atomic_write_text(
         path, json.dumps(doc, indent=1, sort_keys=True))
 
 
 def dump_stitched_trace(path: str, worker_sections: Dict[str, Dict]) -> str:
     """`dump_trace` plus the fleet's cross-process span sections: the
-    router's own lanes (pids 1-4) AND every worker's request spans on
-    per-worker pids, flow ids joining across the process boundary.
+    router's own lanes AND every worker's request spans on per-worker
+    pids, flow ids joining across the process boundary.
     ``serve/fleet.FleetRouter.dump_trace`` is the caller."""
-    from . import devices, flight, fsio
+    from . import flight, fsio
 
     tracer = global_tracer()
-    earliest = [t for t in (devices.earliest_timestamp(),
-                            flight.earliest_timestamp()) if t is not None]
+    earliest = [t for t in (flight.earliest_timestamp(),) if t is not None]
     for sec in worker_sections.values():
         t = earliest_wire_timestamp(sec.get("traces", ()))
         if t is not None:
@@ -536,7 +537,182 @@ def dump_stitched_trace(path: str, worker_sections: Dict[str, Dict]) -> str:
         with tracer._lock:
             tracer._t0 = min(tracer._t0, min(earliest))
     doc = stitched_chrome(tracer, worker_sections)
-    doc["traceEvents"].extend(devices.chrome_events(tracer._us))
     doc["traceEvents"].extend(flight.chrome_events(tracer._us))
     return fsio.atomic_write_text(
         path, json.dumps(doc, indent=1, sort_keys=True))
+
+
+# -- program spans and flush records ------------------------------------------
+#
+# Where the time goes inside one RLC flush (ops/bls_backend.batch_verify_rlc)
+# and one served micro-batch (serve/service.py). Always on: a span costs one
+# profiler check and, inside an open record, two perf_counter reads; a flush
+# appends one record. Inside a jax.profiler session every span is also a
+# TraceMe on the xplane's host plane, on the same clock as the device's
+# XLA Ops line, so a device idle gap can be named by the program span open
+# during it. Span names never start with ``bench.`` (the benchmark's own
+# spans) and never reach ops/profiling (whose ``vm[...]`` labels the
+# benchmark sums).
+
+# records kept; older ones are dropped and counted
+FLUSH_RING = 4096
+
+_local = threading.local()
+_flush_ids = itertools.count(1)
+_annotation = None  # jax.profiler.TraceAnnotation once jax is imported
+
+
+def _annotation_class():
+    """TraceAnnotation, or None while jax is not imported (no profiler
+    session can be open then, and this module never imports jax)."""
+    global _annotation
+    if _annotation is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+class span:
+    """``with span(name, **args):`` one stage of a flush. Inside a
+    profiler session it is a ``jax.profiler.TraceAnnotation`` carrying
+    ``args``; inside an open ``rlc`` record it adds its inclusive seconds
+    to ``record["spans"][name]`` (the TraceMe encloses the timed interval,
+    so the record never reads longer than the trace)."""
+
+    __slots__ = ("name", "args", "_ann", "_rec", "_t0")
+
+    def __init__(self, name: str, **args):
+        self.name = name
+        self.args = args
+
+    def __enter__(self):
+        cls = _annotation_class()
+        if cls is not None and cls.is_enabled():
+            self._ann = cls(self.name, **self.args)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self._rec = getattr(_local, "record", None)
+        if self._rec is not None:
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        rec = self._rec
+        if rec is not None:
+            spans = rec["spans"]
+            spans[self.name] = (spans.get(self.name, 0.0)
+                                + time.perf_counter() - self._t0)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+
+class FlushRecords:
+    """Bounded ring of flush records, oldest first; ``dropped`` counts the
+    records the ring has overwritten."""
+
+    def __init__(self, capacity: int = FLUSH_RING):
+        assert capacity > 0
+        self._lock = threading.Lock()
+        self._ring: "deque[Dict]" = deque(maxlen=capacity)
+        self.dropped = 0
+
+    def append(self, record: Dict) -> None:
+        with self._lock:
+            if len(self._ring) == self._ring.maxlen:
+                self.dropped += 1
+            self._ring.append(record)
+
+    def records(self) -> List[Dict]:
+        with self._lock:
+            return list(self._ring)
+
+    def clear(self) -> None:
+        with self._lock:
+            self._ring.clear()
+            self.dropped = 0
+
+
+_FLUSHES = FlushRecords()
+
+
+def flush_records() -> List[Dict]:
+    """Every retained flush record, oldest first. An ``rlc`` record:
+    ``id``, ``items``, ``seconds`` (the whole ``batch_verify_rlc`` call),
+    ``spans`` (span name -> summed inclusive seconds inside the call),
+    ``combines``, ``bisections``, ``final_exps`` and ``first`` (each
+    ``vm[...]`` label it executed first in this process, mapped to the
+    program kind). A ``serve``
+    record: ``id``, ``items``, ``prep_s``, ``device_s``, ``rlc`` (the id
+    of the rlc record run inside it, or None), ``queue_wait_s`` and
+    ``handoff_wait_s`` (each summed over the flush's checks)."""
+    return _FLUSHES.records()
+
+
+def flush_records_dropped() -> int:
+    return _FLUSHES.dropped
+
+
+def reset_flush_records() -> None:
+    _FLUSHES.clear()
+
+
+def next_flush_id() -> int:
+    """A fresh record id (rlc and serve records share one sequence)."""
+    return next(_flush_ids)
+
+
+@contextlib.contextmanager
+def rlc_record(items: int):
+    """Open the ``rlc`` record of one flush on this thread, and append it
+    to the ring when the flush ends, raising or not. Yields the record."""
+    rec = {"kind": "rlc", "id": next_flush_id(), "items": int(items),
+           "seconds": 0.0, "spans": {}, "combines": 0, "bisections": 0,
+           "final_exps": 0, "first": {}}
+    _local.record = rec
+    t0 = time.perf_counter()
+    try:
+        yield rec
+    finally:
+        rec["seconds"] = time.perf_counter() - t0
+        _local.record = None
+        _local.last_rlc = rec["id"]
+        _FLUSHES.append(rec)
+
+
+def count(name: str, n: int = 1) -> None:
+    """Add ``n`` to counter ``name`` of the record open on this thread."""
+    rec = getattr(_local, "record", None)
+    if rec is not None:
+        rec[name] += n
+
+
+def note_first(label: str, kind: str) -> None:
+    """A ``vm[...]`` label (of program ``kind``) executed for the first
+    time in this process."""
+    rec = getattr(_local, "record", None)
+    if rec is not None:
+        rec["first"][label] = kind
+
+
+def take_last_rlc() -> Optional[int]:
+    """The id of the last ``rlc`` record this thread closed since the
+    previous call, or None."""
+    rid = getattr(_local, "last_rlc", None)
+    _local.last_rlc = None
+    return rid
+
+
+def note_serve_flush(fid: int, items: int, prep_s: float, device_s: float,
+                     rlc: Optional[int], queue_wait_s: float,
+                     handoff_wait_s: float) -> None:
+    """Append the ``serve`` record of one settled service flush."""
+    _FLUSHES.append({"kind": "serve", "id": fid, "items": int(items),
+                     "prep_s": prep_s, "device_s": device_s, "rlc": rlc,
+                     "queue_wait_s": queue_wait_s,
+                     "handoff_wait_s": handoff_wait_s})

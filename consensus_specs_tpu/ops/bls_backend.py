@@ -34,6 +34,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs import tracing
 from ..utils import bls12_381 as O
 from ..utils.bls12_381 import P
 from . import fq, vm, vmlib
@@ -229,10 +230,12 @@ def _program(kind: str, k: int = 0, fold: int = None) -> Tuple[vm.Program, int]:
 
 
 def _attach_fused_key(assembled, kind: str, k: int, fold: int) -> None:
-    """Stamp the program's cache identity onto its schedule metadata so
+    """Stamp the program's kind and K (its span and XLA module name, on
+    every program), and its cache identity onto its schedule metadata so
     the fused lowering (ops/vm_compile.py) can disk-cache its plan under
-    a matching ``.vm_cache`` key. Pre-meta pickles (meta=None) are left
-    untouched — they cannot lower fused anyway (no schedule metadata)."""
+    a matching ``.vm_cache`` key. Pre-meta pickles (meta=None) keep no
+    identity — they cannot lower fused anyway (no schedule metadata)."""
+    assembled.kind, assembled.k = kind, k
     try:
         if isinstance(assembled.meta, dict):
             assembled.meta.setdefault(
@@ -678,16 +681,19 @@ def _prewarm_batched(msgs, sigs, pks) -> None:
     from . import codec
 
     if msgs:
-        for m, v in zip(msgs, codec.message_limbs_batch(msgs, DST)):
-            _cache_put(_MSG_CACHE, m, v)
+        with tracing.span("codec.hash_to_g2", n=len(msgs)):
+            for m, v in zip(msgs, codec.message_limbs_batch(msgs, DST)):
+                _cache_put(_MSG_CACHE, m, v)
     if sigs:
-        for s, v in zip(sigs, codec.signature_limbs_batch(sigs)):
-            if not isinstance(v, ValueError):
-                _cache_put(_SIG_CACHE, s, v)
+        with tracing.span("codec.signatures", n=len(sigs)):
+            for s, v in zip(sigs, codec.signature_limbs_batch(sigs)):
+                if not isinstance(v, ValueError):
+                    _cache_put(_SIG_CACHE, s, v)
     if pks:
-        for p, v in zip(pks, codec.pubkey_limbs_batch(pks)):
-            if not isinstance(v, ValueError):
-                _cache_put(_PK_CACHE, p, v)
+        with tracing.span("codec.pubkeys", n=len(pks)):
+            for p, v in zip(pks, codec.pubkey_limbs_batch(pks)):
+                if not isinstance(v, ValueError):
+                    _cache_put(_PK_CACHE, p, v)
 
 
 def prewarm_host_caches(messages: Sequence[bytes], signatures: Sequence[bytes],
@@ -764,8 +770,9 @@ def _prewarm_pool(msgs, sigs, pks) -> None:
         # of blocking verification. ('spawn' children would re-import
         # the package, and with it JAX, in processes that must stay off
         # the chip.)
-        done = _pool_map(_prewarm_worker, work, procs, chunksize=8,
-                         timeout=max(120.0, 0.2 * len(work)))
+        with tracing.span("codec.pool", n=len(work)):
+            done = _pool_map(_prewarm_worker, work, procs, chunksize=8,
+                             timeout=max(120.0, 0.2 * len(work)))
         for kind, payload, value in done:
             if value is None:
                 _note_serial_fallback(1)
@@ -1179,57 +1186,60 @@ def _miller_fast_aggregate(
     n = len(pubkey_sets)
     max_k = max((len(pks) for pks in pubkey_sets), default=1)
     k = _k_bucket(max(1, max_k))
-    L = fq.NUM_LIMBS
+    group = f"fast_aggregate/{k}"
+    with tracing.span("rlc.prep", group=group, n=n):
+        L = fq.NUM_LIMBS
+        lay = _FoldLayout("miller_product", k, n, mesh)
+        nb = lay.nb
+        prewarm_host_caches(
+            [bytes(m) for m in messages],
+            [bytes(s) for s in signatures],
+            [bytes(pk) for pks in pubkey_sets for pk in pks],
+        )
 
-    lay = _FoldLayout("miller_product", k, n, mesh)
-    prA, rows, nb = lay.program, lay.rows, lay.nb
-    prewarm_host_caches(
-        [bytes(m) for m in messages],
-        [bytes(s) for s in signatures],
-        [bytes(pk) for pks in pubkey_sets for pk in pks],
-    )
+        # stacked staging arrays (vectorized — the per-name dict assignment
+        # loop was ~1.5 s of host time at epoch scale); inactive-lane
+        # fillers: infinity pubkeys (0:1:0), generator G2 points
+        precheck = np.zeros(nb, dtype=bool)
+        pk_x = np.zeros((nb, k, L), dtype=np.uint64)
+        pk_y = np.zeros((nb, k, L), dtype=np.uint64)
+        pk_y[:] = _INF_G1[1]
+        pk_z = np.zeros((nb, k, L), dtype=np.uint64)
+        hm = np.zeros((nb, 4, L), dtype=np.uint64)
+        hm[:] = _G2GEN_LIMBS
+        sg = np.zeros((nb, 4, L), dtype=np.uint64)
+        sg[:] = _G2GEN_LIMBS
 
-    # stacked staging arrays (vectorized — the per-name dict assignment loop
-    # was ~1.5 s of host time at epoch scale); inactive-lane fillers:
-    # infinity pubkeys (0:1:0), generator G2 points
-    precheck = np.zeros(nb, dtype=bool)
-    pk_x = np.zeros((nb, k, L), dtype=np.uint64)
-    pk_y = np.zeros((nb, k, L), dtype=np.uint64)
-    pk_y[:] = _INF_G1[1]
-    pk_z = np.zeros((nb, k, L), dtype=np.uint64)
-    hm = np.zeros((nb, 4, L), dtype=np.uint64)
-    hm[:] = _G2GEN_LIMBS
-    sg = np.zeros((nb, 4, L), dtype=np.uint64)
-    sg[:] = _G2GEN_LIMBS
+        for i, (pks, msg, sig) in enumerate(
+            zip(pubkey_sets, messages, signatures)
+        ):
+            try:
+                if len(pks) == 0:
+                    raise ValueError("empty pubkey set")
+                enc = [_pubkey_limbs(bytes(pk)) for pk in pks]
+                s = _signature_limbs(bytes(sig))
+                h = _message_limbs(bytes(msg))
+            except Exception:
+                continue
+            m = len(enc)
+            pk_x[i, :m] = [e[0] for e in enc]
+            pk_y[i, :m] = [e[1] for e in enc]
+            pk_z[i, :m] = _ONE_LIMBS
+            hm[i] = h
+            sg[i] = s
+            precheck[i] = True
 
-    for i, (pks, msg, sig) in enumerate(zip(pubkey_sets, messages, signatures)):
-        try:
-            if len(pks) == 0:
-                raise ValueError("empty pubkey set")
-            enc = [_pubkey_limbs(bytes(pk)) for pk in pks]
-            s = _signature_limbs(bytes(sig))
-            h = _message_limbs(bytes(msg))
-        except Exception:
-            continue
-        m = len(enc)
-        pk_x[i, :m] = [e[0] for e in enc]
-        pk_y[i, :m] = [e[1] for e in enc]
-        pk_z[i, :m] = _ONE_LIMBS
-        hm[i] = h
-        sg[i] = s
-        precheck[i] = True
+        if not precheck.any():
+            return None, lay, precheck
 
-    if not precheck.any():
-        return None, lay, precheck
-
-    ins = {}
-    lay.scatter(ins, pk_x, lambda j: f"pk{j}.x")
-    lay.scatter(ins, pk_y, lambda j: f"pk{j}.y")
-    lay.scatter(ins, pk_z, lambda j: f"pk{j}.z")
-    lay.scatter(ins, hm, lambda ci: f"h.{_G2_COMPS[ci]}")
-    lay.scatter(ins, sg, lambda ci: f"sig.{_G2_COMPS[ci]}")
-
-    out = vm.execute(prA, ins, batch_shape=(rows,), mesh=mesh)
+        ins = {}
+        lay.scatter(ins, pk_x, lambda j: f"pk{j}.x")
+        lay.scatter(ins, pk_y, lambda j: f"pk{j}.y")
+        lay.scatter(ins, pk_z, lambda j: f"pk{j}.z")
+        lay.scatter(ins, hm, lambda ci: f"h.{_G2_COMPS[ci]}")
+        lay.scatter(ins, sg, lambda ci: f"sig.{_G2_COMPS[ci]}")
+    with tracing.span("rlc.miller", group=group):
+        out = vm.execute(lay.program, ins, batch_shape=(lay.rows,), mesh=mesh)
     return out, lay, precheck
 
 
@@ -1268,56 +1278,57 @@ def _miller_aggregate(
         (len(pks) for pks in pubkey_lists), default=1
     )
     k = _k_bucket(max(1, max_k))
-    L = fq.NUM_LIMBS
+    group = f"aggregate/{k}"
+    with tracing.span("rlc.prep", group=group, n=n):
+        L = fq.NUM_LIMBS
+        lay = _FoldLayout("aggregate_verify", k, n, mesh)
+        nb = lay.nb
+        prewarm_host_caches(
+            [bytes(m) for ms in message_lists for m in ms],
+            [bytes(s) for s in signatures],
+            [bytes(pk) for pks in pubkey_lists for pk in pks],
+        )
 
-    lay = _FoldLayout("aggregate_verify", k, n, mesh)
-    prA, rows, nb = lay.program, lay.rows, lay.nb
-    prewarm_host_caches(
-        [bytes(m) for ms in message_lists for m in ms],
-        [bytes(s) for s in signatures],
-        [bytes(pk) for pks in pubkey_lists for pk in pks],
-    )
+        precheck = np.zeros(nb, dtype=bool)
+        pk_x = np.zeros((nb, k, L), dtype=np.uint64)
+        pk_y = np.zeros((nb, k, L), dtype=np.uint64)
+        pk_y[:] = _INF_G1[1]
+        pk_z = np.zeros((nb, k, L), dtype=np.uint64)
+        hm = np.zeros((nb, k, 4, L), dtype=np.uint64)
+        hm[:] = _G2GEN_LIMBS
+        sg = np.zeros((nb, 4, L), dtype=np.uint64)
+        sg[:] = _G2GEN_LIMBS
 
-    precheck = np.zeros(nb, dtype=bool)
-    pk_x = np.zeros((nb, k, L), dtype=np.uint64)
-    pk_y = np.zeros((nb, k, L), dtype=np.uint64)
-    pk_y[:] = _INF_G1[1]
-    pk_z = np.zeros((nb, k, L), dtype=np.uint64)
-    hm = np.zeros((nb, k, 4, L), dtype=np.uint64)
-    hm[:] = _G2GEN_LIMBS
-    sg = np.zeros((nb, 4, L), dtype=np.uint64)
-    sg[:] = _G2GEN_LIMBS
+        for i, (pks, msgs, sig) in enumerate(
+            zip(pubkey_lists, message_lists, signatures)
+        ):
+            try:
+                if len(pks) == 0 or len(pks) != len(msgs):
+                    raise ValueError("bad pubkey/message lists")
+                enc = [_pubkey_limbs(bytes(pk)) for pk in pks]
+                hs = [_message_limbs(bytes(m)) for m in msgs]
+                s = _signature_limbs(bytes(sig))
+            except Exception:
+                continue
+            m = len(enc)
+            pk_x[i, :m] = [e[0] for e in enc]
+            pk_y[i, :m] = [e[1] for e in enc]
+            pk_z[i, :m] = _ONE_LIMBS
+            hm[i, :m] = hs
+            sg[i] = s
+            precheck[i] = True
 
-    for i, (pks, msgs, sig) in enumerate(
-        zip(pubkey_lists, message_lists, signatures)
-    ):
-        try:
-            if len(pks) == 0 or len(pks) != len(msgs):
-                raise ValueError("bad pubkey/message lists")
-            enc = [_pubkey_limbs(bytes(pk)) for pk in pks]
-            hs = [_message_limbs(bytes(m)) for m in msgs]
-            s = _signature_limbs(bytes(sig))
-        except Exception:
-            continue
-        m = len(enc)
-        pk_x[i, :m] = [e[0] for e in enc]
-        pk_y[i, :m] = [e[1] for e in enc]
-        pk_z[i, :m] = _ONE_LIMBS
-        hm[i, :m] = hs
-        sg[i] = s
-        precheck[i] = True
+        if not precheck.any():
+            return None, lay, precheck
 
-    if not precheck.any():
-        return None, lay, precheck
-
-    ins = {}
-    lay.scatter(ins, pk_x, lambda j: f"pk{j}.x")
-    lay.scatter(ins, pk_y, lambda j: f"pk{j}.y")
-    lay.scatter(ins, pk_z, lambda j: f"pk{j}.z")
-    lay.scatter(ins, hm, lambda j, ci: f"h{j}.{_G2_COMPS[ci]}")
-    lay.scatter(ins, sg, lambda ci: f"sig.{_G2_COMPS[ci]}")
-
-    out = vm.execute(prA, ins, batch_shape=(rows,), mesh=mesh)
+        ins = {}
+        lay.scatter(ins, pk_x, lambda j: f"pk{j}.x")
+        lay.scatter(ins, pk_y, lambda j: f"pk{j}.y")
+        lay.scatter(ins, pk_z, lambda j: f"pk{j}.z")
+        lay.scatter(ins, hm, lambda j, ci: f"h{j}.{_G2_COMPS[ci]}")
+        lay.scatter(ins, sg, lambda ci: f"sig.{_G2_COMPS[ci]}")
+    with tracing.span("rlc.miller", group=group):
+        out = vm.execute(lay.program, ins, batch_shape=(lay.rows,), mesh=mesh)
     return out, lay, precheck
 
 
@@ -1453,19 +1464,24 @@ def _final_exp_is_one(f_coeffs: List[int], mesh=None) -> bool:
     host easy part, then the hard part per _rlc_final_mode(). Device
     routes go through the final-exp batcher, so hard parts from flushes
     in flight at the same moment share one pipelined VM execution."""
-    g = _easy_part_flat(f_coeffs)
-    if g is None:
-        return False  # degenerate f: no valid item produces it
-    if _rlc_final_mode() == "host":
-        try:
-            from ..obs import flight
+    with tracing.span("rlc.final_exp", rows=1):
+        with tracing.span("rlc.easy_part"):
+            g = _easy_part_flat(f_coeffs)
+        if g is None:
+            return False  # degenerate f: no valid item produces it
+        tracing.count("final_exps")
+        with tracing.span("rlc.hard_part"):
+            if _rlc_final_mode() == "host":
+                try:
+                    from ..obs import flight
 
-            flight.note("vm", "final_exp_route", route="host", rows=1)
-        except Exception:
-            pass
-        return _hard_part_is_one_oracle(g)
-    gm = np.stack([fq.to_mont_int(c) for c in g])
-    return bool(_FINAL_EXP_BATCHER.run(gm, mesh=mesh))
+                    flight.note("vm", "final_exp_route", route="host",
+                                rows=1)
+                except Exception:
+                    pass
+                return _hard_part_is_one_oracle(g)
+            gm = np.stack([fq.to_mont_int(c) for c in g])
+            return bool(_FINAL_EXP_BATCHER.run(gm, mesh=mesh))
 
 
 def _rlc_chunk(m: int, mesh=None) -> int:
@@ -1570,12 +1586,23 @@ def batch_verify_rlc(items, mesh=None, rng=None) -> np.ndarray:
     (or 1 surviving candidate) degenerates to the plain per-item path
     with no combine at all. Verdicts are bit-identical to
     batch_fast_aggregate_verify / batch_aggregate_verify on every input
-    (up to the 2^-128 bound, which no test will ever see)."""
+    (up to the 2^-128 bound, which no test will ever see).
+
+    Each call appends one ``rlc`` flush record (obs/tracing.py) holding
+    its seconds, its stage spans and the combines, bisections and final
+    exponentiations it ran."""
     items = list(items)
     n = len(items)
     _count_call("batch_verify_rlc", n)
     if n == 0:
         return np.zeros(0, dtype=bool)
+    with tracing.rlc_record(n) as rec, tracing.span(
+            "rlc.flush", flush=rec["id"], items=n):
+        return _batch_verify_rlc(items, mesh, rng)
+
+
+def _batch_verify_rlc(items, mesh, rng) -> np.ndarray:
+    n = len(items)
     verdict = np.zeros(n, dtype=bool)
 
     groups: Dict[Tuple[str, int], List[int]] = {}
@@ -1629,12 +1656,14 @@ def batch_verify_rlc(items, mesh=None, rng=None) -> np.ndarray:
 
     def combine_check(sel: List[int]) -> bool:
         RLC_STATS["combines"] += 1
-        bits = _rlc_scalars(len(sel), rng)
-        sub = fs[np.asarray(sel)]
-        if _rlc_backend() == "jax":
-            coeffs = _rlc_combine_jax(sub, bits)
-        else:
-            coeffs = _rlc_combine_vm(sub, bits, mesh)
+        tracing.count("combines")
+        with tracing.span("rlc.combine", n=len(sel)):
+            bits = _rlc_scalars(len(sel), rng)
+            sub = fs[np.asarray(sel)]
+            if _rlc_backend() == "jax":
+                coeffs = _rlc_combine_jax(sub, bits)
+            else:
+                coeffs = _rlc_combine_vm(sub, bits, mesh)
         return _final_exp_is_one(coeffs, mesh=mesh)
 
     def resolve(sel: List[int]) -> None:
@@ -1646,6 +1675,7 @@ def batch_verify_rlc(items, mesh=None, rng=None) -> np.ndarray:
                 verdict[cand_idx[j]] = True
             return
         RLC_STATS["bisections"] += 1
+        tracing.count("bisections")
         mid = len(sel) // 2
         resolve(sel[:mid])
         resolve(sel[mid:])

@@ -752,6 +752,10 @@ class Program:
     const_regs: Dict[int, int]  # reg -> plain int value
     n_steps: int
     meta: Optional[Dict] = None  # assemble-time schedule stats (vm_analysis)
+    # program kind (a vmlib.BUILDERS key) and K, stamped by
+    # bls_backend._program; they name the program's span and XLA module
+    kind: Optional[str] = None
+    k: int = 0
 
     def init_regs(self, batch_shape: Tuple[int, ...]) -> np.ndarray:
         """Fresh register file with constants loaded (host-side numpy)."""
@@ -903,14 +907,39 @@ def _vm_body(inputs_u32, template, input_regs, output_regs, instr,
     return regs[..., output_regs, :]
 
 
-_vm_run = jax.jit(_vm_body, static_argnums=(5,))
-
-
 import functools as _functools
 
 
-@_functools.lru_cache(maxsize=8)
-def _vm_run_for_mesh(mesh, pallas_mode="0"):
+def _named_body(name: str):
+    """``_vm_body`` under the ``__name__`` ``name``: XLA names the module
+    ``jit_<name>``, so a device trace says which program kind ran."""
+    def body(inputs_u32, template, input_regs, output_regs, instr,
+             pallas_mode="0"):
+        return _vm_body(inputs_u32, template, input_regs, output_regs, instr,
+                        pallas_mode)
+
+    body.__name__ = body.__qualname__ = name
+    return body
+
+
+@_functools.lru_cache(maxsize=None)
+def _vm_run(name: str = "vm_program"):
+    """The single-device jitted runner of the module ``jit_<name>``."""
+    return jax.jit(_named_body(name), static_argnums=(5,))
+
+
+# jit shape signature -> the module name that first compiled it: a second
+# kind with the very same shapes reuses that executable instead of paying
+# a compile of its own under another name
+_SHAPE_OWNER: Dict[tuple, str] = {}
+
+
+def _module_name(kind: Optional[str], sig: tuple) -> str:
+    return _SHAPE_OWNER.setdefault(sig, f"vm_{kind or 'program'}")
+
+
+@_functools.lru_cache(maxsize=64)
+def _vm_run_for_mesh(mesh, pallas_mode="0", name="vm_program"):
     """Jitted VM runner with the leading batch axis sharded over ALL of
     ``mesh``'s axes (the DP axis of SURVEY.md §2.7/P1 — a hierarchical
     host x chip / DCN x ICI mesh flattens onto the one batch dimension) and
@@ -929,7 +958,7 @@ def _vm_run_for_mesh(mesh, pallas_mode="0"):
         batch_sh = NamedSharding(mesh, P(mesh.axis_names))
         repl = NamedSharding(mesh, P())
         return jax.jit(
-            _vm_body,
+            _named_body(name),
             in_shardings=(
                 batch_sh,
                 repl,
@@ -957,8 +986,12 @@ def _vm_run_for_mesh(mesh, pallas_mode="0"):
         check_kw = {"check_vma": False}
     else:
         check_kw = {"check_rep": False}
+    def per_shard(i, t, ir, o, ins):
+        return _vm_body(i, t, ir, o, ins, pallas_mode)
+
+    per_shard.__name__ = per_shard.__qualname__ = name
     body = shard_map_fn(
-        lambda i, t, ir, o, ins: _vm_body(i, t, ir, o, ins, pallas_mode),
+        per_shard,
         mesh=mesh,
         in_specs=(spec_b, repl, repl, repl, tuple(repl for _ in range(7))),
         out_specs=spec_b,
@@ -987,6 +1020,7 @@ def execute(program: Program, inputs: Dict[str, np.ndarray], batch_shape=(),
     ``vm/fused_fallback`` flight event — this entry point never fails for
     lowering reasons."""
     from . import profiling, vm_compile
+    from ..obs import tracing
 
     stacked = program.stack_inputs(inputs, tuple(batch_shape))
     label = (
@@ -996,37 +1030,44 @@ def execute(program: Program, inputs: Dict[str, np.ndarray], batch_shape=(),
     rows = 1
     for d in batch_shape:
         rows *= int(d)
+    token = object()
+    first = _SEEN_LABELS.setdefault(label, token) is token
+    kind = program.kind or "program"
+    if first:
+        tracing.note_first(label, kind)
     path = "interp"
     compile_inclusive = False
-    t0 = time.perf_counter()
-    shape_sig = (tuple(int(d) for d in batch_shape), mesh is not None)
-    with profiling.timed(label):
-        out = None
-        if vm_compile.use_fused(program, shape_sig=shape_sig):
-            try:
-                out, compile_inclusive = vm_compile.run_fused(
-                    program, stacked, mesh=mesh)
-                path = "fused"
-            except Exception as e:
-                vm_compile.note_fallback(program, e)
-                out = None
-        if out is None:
-            template = program.const_template()
-            instr = tuple(jnp.asarray(x) for x in program.instr)
-            out = _execute_device(
-                stacked, template, program.input_regs, program.output_regs,
-                instr, mesh,
-            )
-        # block BEFORE the timer stops, on BOTH backends: jax dispatch is
-        # async (CPU included), and the routing ledger below compares the
-        # two paths' dt against each other — an unblocked dt records
-        # dispatch, not compute, and would poison the measured-winner
-        # ``auto`` route. The fused path already materialized inside
-        # run_fused (inside the try above, so async runtime failures
-        # fall back to the interpreter too); this block is what times
-        # the interpreter path and is a no-op re-block for fused.
-        out.block_until_ready()
-    dt = time.perf_counter() - t0
+    with tracing.span(f"vm.{kind}", k=program.k, rows=rows, first=first):
+        t0 = time.perf_counter()
+        shape_sig = (tuple(int(d) for d in batch_shape), mesh is not None)
+        with profiling.timed(label):
+            out = None
+            if vm_compile.use_fused(program, shape_sig=shape_sig):
+                try:
+                    out, compile_inclusive = vm_compile.run_fused(
+                        program, stacked, mesh=mesh)
+                    path = "fused"
+                except Exception as e:
+                    vm_compile.note_fallback(program, e)
+                    out = None
+            if out is None:
+                template = program.const_template()
+                instr = tuple(jnp.asarray(x) for x in program.instr)
+                out = _execute_device(
+                    stacked, template, program.input_regs,
+                    program.output_regs, instr, mesh, kind=kind,
+                )
+            # block BEFORE the timer stops, on BOTH backends: jax dispatch
+            # is async (CPU included), and the routing ledger below
+            # compares the two paths' dt against each other — an unblocked
+            # dt records dispatch, not compute, and would poison the
+            # measured-winner ``auto`` route. The fused path already
+            # materialized inside run_fused (inside the try above, so async
+            # runtime failures fall back to the interpreter too); this
+            # block is what times the interpreter path and is a no-op
+            # re-block for fused.
+            out.block_until_ready()
+        dt = time.perf_counter() - t0
     # per-program measured ms/row, per backend: the ledger the ``auto``
     # route reads (fused first-shape calls are compile-inclusive and
     # excluded; the stored value is the process-lifetime warm minimum)
@@ -1035,29 +1076,21 @@ def execute(program: Program, inputs: Dict[str, np.ndarray], batch_shape=(),
     # trace export next to the serve pipeline's request spans. Opt-in —
     # the disabled cost is one env read per execute() (device-call scale,
     # not hot-loop scale).
-    from ..obs import tracing
-
     if tracing.trace_enabled():
         tracing.global_tracer().note_execution(
             steps=program.n_steps, regs=program.n_regs,
             batch=tuple(batch_shape), sharded=mesh is not None,
             t0=t0, seconds=dt,
         )
-    # per-device occupancy ledger (obs/devices.py): this execution kept
-    # every participating device busy for dt — the utilization numbers
-    # ROADMAP item 1's shard_map tuning reads. Same cost profile as the
-    # trace hook above: one None check when disabled, device-call scale.
-    from ..obs import devices
-
-    ledger = devices.maybe_ledger()
-    if ledger is not None:
-        ledger.note_execution(mesh, t0, dt,
-                              label=f"vm[steps={program.n_steps}]")
     out = np.asarray(out)
     return {
         name: out[..., i, :]
         for i, name in enumerate(program.output_names)
     }
+
+
+# the vm[...] labels executed so far in this process (a span's ``first``)
+_SEEN_LABELS: Dict[str, object] = {}
 
 
 def _pallas_mode() -> str:
@@ -1067,15 +1100,23 @@ def _pallas_mode() -> str:
     return v if v in ("1", "step") else "0"
 
 
-def _execute_device(stacked, template, input_regs, output_regs, instr, mesh):
+def _execute_device(stacked, template, input_regs, output_regs, instr, mesh,
+                    kind=None):
+    """Run the interpreter on the device (sharded over ``mesh`` when
+    given), under the XLA module named for the program ``kind``."""
+    mode = _pallas_mode()
+    sig = (tuple((np.shape(x), str(x.dtype)) for x in
+                 (stacked, template, input_regs, output_regs) + tuple(instr)),
+           mode, mesh)
+    name = _module_name(kind, sig)
     if mesh is None:
-        return _vm_run(
+        return _vm_run(name)(
             jnp.asarray(stacked),
             jnp.asarray(template),
             jnp.asarray(input_regs),
             jnp.asarray(output_regs),
             instr,
-            _pallas_mode(),
+            mode,
         )
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -1087,4 +1128,4 @@ def _execute_device(stacked, template, input_regs, output_regs, instr, mesh):
         for x in (template, input_regs, output_regs)
     )
     instr_d = tuple(jax.device_put(x, repl) for x in instr)
-    return _vm_run_for_mesh(mesh, _pallas_mode())(stacked_d, *args_d, instr_d)
+    return _vm_run_for_mesh(mesh, mode, name)(stacked_d, *args_d, instr_d)

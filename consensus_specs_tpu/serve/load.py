@@ -21,8 +21,8 @@ Env overrides (CPU-sized defaults; a chip run can scale up):
 serve-bench-mesh`) runs the same load at several mesh device counts —
 each in a fresh child process (`bench.py --mode serve --mesh <d>`),
 because the virtual-device count is frozen at backend init — and emits
-ONE line whose ``mesh`` section carries per-count sigs/sec, per-device
-occupancy lanes, mesh fallbacks, and the scaling efficiency vs the
+ONE line whose ``mesh`` section carries per-count sigs/sec, mesh
+fallbacks, and the scaling efficiency vs the
 single-device run (report-only on CPU virtual devices; the
 ok-state is what tools/bench_compare.py gates round over round).
   SERVE_MESH_DEVICES ("1,2,4,8"), SERVE_MESH_TIMEOUT (s/child, 900)
@@ -254,14 +254,12 @@ def run_serve_bench(target: float = TARGET_PER_CHIP) -> dict:
     # clean slate: the serve line always attaches profiling.summary(), and
     # a prior mode's histograms/gauges in this process (multi-mode bench
     # runs, tests) must not bleed into it; the once-per-process vm-cache
-    # gauges are re-published after the wipe. The device ledger and SLO
-    # tracker reset too — utilization denominators and burn windows start
-    # at THIS run
-    from ..obs import devices, programs as obs_programs, slo
+    # gauges are re-published after the wipe. The SLO tracker resets too
+    # — burn windows start at THIS run
+    from ..obs import programs as obs_programs, slo
 
     profiling.reset()
     obs_programs.export_gauges()
-    devices.reset_global()
     slo.reset_global()
     # baseline checkpoint at run start: the end-of-run slo section's burn
     # windows then measure THIS run's error mass (one evaluate() with an
@@ -414,16 +412,10 @@ def run_serve_bench(target: float = TARGET_PER_CHIP) -> dict:
         )
 
     snap = svc.metrics.snapshot()
-    # fleet-observability sections (ISSUE 7), evaluated BEFORE the profile
-    # snapshot so the device[*]/slo.* gauges they publish ride the
-    # attached profiling.summary() too: per-device occupancy from the
-    # ledger this run's vm.execute calls fed, and the SLO state the
-    # round-over-round gate (tools/bench_compare.py) diffs
-    ledger = devices.maybe_ledger()
-    devices_section = None
-    if ledger is not None:
-        ledger.export_gauges()
-        devices_section = ledger.snapshot()
+    # fleet-observability section, evaluated BEFORE the profile
+    # snapshot so the slo.* gauges it publishes ride the attached
+    # profiling.summary() too: the SLO state the round-over-round gate
+    # (tools/bench_compare.py) diffs
     slo_section = slo.global_tracker().bench_section()
     # SERVED vs VERIFIED: the duplicate-heavy stream is answered mostly by
     # the cache/dedup layer, so served/sec is the serving-plane headline
@@ -485,8 +477,6 @@ def run_serve_bench(target: float = TARGET_PER_CHIP) -> dict:
         result["mesh_devices"] = svc.mesh_devices
         result["mesh_fallbacks"] = snap["mesh_fallbacks"]
         result["mesh_warmup_s"] = round(mesh_warmup_s, 3)
-    if devices_section is not None:
-        result["devices"] = devices_section
     if exposition is not None:
         result["metrics_port"] = exposition.port
         result["metrics_scrape_ok"] = scrape is not None
@@ -515,7 +505,7 @@ def run_serve_mesh_sweep() -> dict:
     injection off (the sweep measures scaling on clean traffic — the
     degradation ladder has its own bench and tests). Returns bench.py's
     result dict; the ``mesh`` section maps device count -> {sigs_per_sec,
-    verified_sigs_per_sec, ok, fallbacks, lanes, efficiency}.
+    verified_sigs_per_sec, ok, fallbacks, efficiency}.
 
     Efficiency (sigs/sec at d devices / (d * single-device sigs/sec)) and
     the 10%-of-single regression check are REPORT-ONLY on CPU virtual
@@ -556,10 +546,6 @@ def run_serve_mesh_sweep() -> dict:
                 .splitlines()[-1:] or ["no parseable output"])[0]
             rows[str(d)] = {"ok": False, "error": str(err)[:300]}
             continue
-        lanes = {}
-        for lane, entry in (parsed.get("devices") or {}).get(
-                "lanes", {}).items():
-            lanes[lane] = entry.get("utilization", 0.0)
         rows[str(d)] = {
             "ok": True,
             "sigs_per_sec": round(float(parsed["value"]), 2),
@@ -567,7 +553,6 @@ def run_serve_mesh_sweep() -> dict:
             "final_exps_per_item": parsed.get("final_exps_per_item", 0.0),
             "fallbacks": parsed.get("mesh_fallbacks", 0),
             "p99_ms": parsed.get("p99_ms", 0.0),
-            "lanes": lanes,
         }
 
     single = rows.get("1", {})

@@ -42,7 +42,12 @@ opt-in via CONSENSUS_SPECS_TPU_TRACE=1 or an explicit ``tracer=``), and
 the counters in metrics.py export through ops/profiling into the
 Prometheus ``/metrics`` endpoint (obs/exposition.py). With tracing off the
 service stores None and every stage skips on one ``is not None`` check —
-no locks, allocations, or syscalls are added to the hot path.
+no locks, allocations, or syscalls are added to the hot path. Always on:
+the ``serve.prep`` / ``serve.await_batch`` / ``serve.device`` program
+spans and one ``serve`` flush record per settled flush
+(``tracing.flush_records()``: its prep and device seconds, the summed
+queue and hand-off waits of its checks, and the ``rlc`` record run
+inside it).
 
 NOTE: construct the service OUTSIDE any active SignatureCollector
 context — the default fallback oracle is captured from the bls
@@ -55,9 +60,9 @@ import threading
 import time
 from collections import deque
 from concurrent.futures import Future
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
-from ..obs import devices, flight, latency, tracing
+from ..obs import flight, latency, tracing
 from ..ops import profiling
 from .cache import ResultCache, check_key
 from .metrics import ServeMetrics
@@ -159,6 +164,20 @@ class _Pending:
         self.deadline = deadline
 
 
+class _Flush:
+    """One prepped micro-batch on its way to the device stage, with the
+    stage times its ``serve`` flush record is made from."""
+
+    __slots__ = ("pends", "fid", "queue_wait_s", "prep_s", "t_prepped")
+
+    def __init__(self, pends, fid, queue_wait_s, prep_s, t_prepped):
+        self.pends = pends
+        self.fid = fid
+        self.queue_wait_s = queue_wait_s  # summed over the checks
+        self.prep_s = prep_s
+        self.t_prepped = t_prepped
+
+
 class _CapturedOracle:
     """The pure-Python per-item fallback, captured eagerly (see module
     NOTE: looking the switchboard up lazily could resolve to a collector's
@@ -226,12 +245,10 @@ class VerificationService:
         # is set AT CONSTRUCTION. Disabled == None: every stage guards on
         # one `is not None` — no new locks or allocations on the hot path.
         self._tracer = tracer if tracer is not None else tracing.maybe_tracer()
-        # flight recorder + device-occupancy ledger (obs/flight.py,
-        # obs/devices.py), captured at construction exactly like the
-        # tracer: disabled == None, every site guards on `is not None` —
-        # no locks or env reads join the hot path when off
+        # flight recorder (obs/flight.py), captured at construction
+        # exactly like the tracer: disabled == None, every site guards on
+        # `is not None` — no locks or env reads join the hot path when off
         self._flight = flight.maybe_recorder()
-        self._devices = devices.maybe_ledger()
         if oracle is None:
             from ..utils import bls
 
@@ -271,7 +288,7 @@ class VerificationService:
         self._closed = False
         # two-stage pipeline: prep(N+1) overlaps device(N) through a
         # one-slot hand-off queue
-        self._handoff: "queue.Queue[Optional[List[_Pending]]]" = queue.Queue(
+        self._handoff: "queue.Queue[Optional[_Flush]]" = queue.Queue(
             maxsize=1
         )
         self._worker = threading.Thread(
@@ -476,35 +493,31 @@ class VerificationService:
         hand it to the device stage. While the device stage verifies
         batch N this loop is already prepping batch N+1."""
         while True:
-            batch = self._collect()
-            if batch is None:
+            collected = self._collect()
+            if collected is None:
                 self._handoff.put(None)  # drain sentinel
                 return
-            t0 = time.perf_counter()
-            try:
-                self._prep(batch)
-            except Exception:
-                # prep is a throughput optimization only: the device
-                # stage's per-item cache misses re-derive (and re-raise)
-                # whatever prep could not produce
-                profiling.record("serve.prep_error", 0.0)
-                if self._flight is not None:
-                    self._flight.note("serve", "prep_error",
-                                      items=len(batch))
-            t1 = time.perf_counter()
+            batch, queue_wait_s = collected
+            fid = tracing.next_flush_id()
+            with tracing.span("serve.prep", flush=fid, n=len(batch)):
+                t0 = time.perf_counter()
+                try:
+                    self._prep(batch)
+                except Exception:
+                    # prep is a throughput optimization only: the device
+                    # stage's per-item cache misses re-derive (and
+                    # re-raise) whatever prep could not produce
+                    profiling.record("serve.prep_error", 0.0)
+                    if self._flight is not None:
+                        self._flight.note("serve", "prep_error",
+                                          items=len(batch))
+                t1 = time.perf_counter()
             self.metrics.note_prep(t1 - t0)
             latency.note_stage("prep", t1 - t0)
-            if self._devices is not None:
-                # the prep stage's host-codec time on the dedicated host
-                # lane: the occupancy timeline then shows the pipeline
-                # overlap (host busy on batch N+1 while a device lane is
-                # busy on batch N)
-                self._devices.note_busy(devices.HOST_LANE, t0, t1,
-                                        label="prep")
             if self._tracer is not None:
                 self._tracer.span_many((p.trace for p in batch), "prep",
                                        t0, t1)
-            self._handoff.put(batch)
+            self._handoff.put(_Flush(batch, fid, queue_wait_s, t1 - t0, t1))
 
     def _prep(self, batch: List[_Pending]) -> None:
         """Warm the backend's host caches for the whole micro-batch with
@@ -529,14 +542,16 @@ class VerificationService:
     def _device_run(self):
         """DEVICE stage: drain prepped batches and run the hard part."""
         while True:
-            batch = self._handoff.get()
-            if batch is None:
+            with tracing.span("serve.await_batch"):
+                flush = self._handoff.get()
+            if flush is None:
                 return
+            batch = flush.pends
             with self._lock:
                 self._staged -= len(batch)
                 self._not_full.notify_all()
             try:
-                self._process(batch)
+                self._process(flush)
             except Exception as e:
                 # belt-and-braces: _process guards each group; whatever
                 # still leaks must not kill the stream — resolve the
@@ -566,14 +581,14 @@ class VerificationService:
             return None
         return earliest - downstream_s - self._deadline_margin_s
 
-    def _collect(self) -> Optional[List[_Pending]]:
+    def _collect(self) -> Optional[Tuple[List[_Pending], float]]:
         """Block for work, then gather one batch: flush when ``max_batch``
         requests are waiting OR ``max_wait_ms`` has passed since the
         OLDEST waiting request was submitted OR — with a slot clock armed
         (ISSUE 12) — the remaining slot budget of the most urgent queued
         item, minus the live downstream p99, is about to be blown,
-        whichever comes first. Returns None when closed and fully
-        drained."""
+        whichever comes first. Returns the batch and its checks' summed
+        queue wait, or None when closed and fully drained."""
         # downstream p99 read OUTSIDE the service lock (it takes the
         # profiling/histogram locks); refreshed once per collect — the
         # number moves at flush cadence, not per wakeup
@@ -606,8 +621,11 @@ class VerificationService:
             self._staged += n
             profiling.set_gauge("serve.queue_depth", len(self._queue))
         now = time.perf_counter()
+        queue_wait_s = 0.0
         for p in batch:
-            latency.note_stage("queue_wait", now - p.t_submit)
+            wait = now - p.t_submit
+            queue_wait_s += wait
+            latency.note_stage("queue_wait", wait)
         if deadline_flush:
             self.metrics.note_deadline_flush(budget_remaining * 1e3)
             if self._flight is not None:
@@ -619,50 +637,67 @@ class VerificationService:
             for p in batch:
                 if p.trace is not None:
                     self._tracer.span(p.trace, "queue_wait", p.t_submit, now)
-        return batch
+        return batch, queue_wait_s
 
-    def _process(self, batch: List[_Pending]) -> None:
+    def _process(self, flush: _Flush) -> None:
+        t_flush = time.perf_counter()
+        batch = flush.pends
         groups = {}
         for p in batch:
             groups.setdefault((p.kind, p.bucket), []).append(p)
         if self._flight is not None:
             self._flight.note("serve", "flush", items=len(batch),
                               groups=len(groups))
-        t_flush = time.perf_counter()
-        results = self._verify_rlc(batch)
-        if results is not None:
-            # ONE combined check decided the whole micro-batch; attribute
-            # the flush time to its (kind, K-bucket) groups by item share
-            # so occupancy/batch accounting stays per-group
-            dt = time.perf_counter() - t_flush
-            for (kind, bucket), pends in groups.items():
-                self.metrics.note_batch(
-                    len(pends), sum(len(p.pubkeys) for p in pends), bucket,
-                    dt * len(pends) / len(batch),
-                )
-            if self._tracer is not None:
-                self._tracer.span_many((p.trace for p in batch), "device",
-                                       t_flush, t_flush + dt)
-            self._settle(batch, results)
-        else:
-            for (kind, bucket), pends in groups.items():
-                t0 = time.perf_counter()
-                results = self._verify_group(kind, pends)
-                t1 = time.perf_counter()
-                self.metrics.note_batch(
-                    len(pends), sum(len(p.pubkeys) for p in pends), bucket,
-                    t1 - t0,
-                )
+        # hand-off wait: from the end of prep until this stage took it
+        handoff_wait_s = (t_flush - flush.t_prepped) * len(batch)
+        with tracing.span("serve.device", flush=flush.fid, n=len(batch)):
+            tracing.take_last_rlc()
+            results = self._verify_rlc(batch)
+            if results is not None:
+                # ONE combined check decided the whole micro-batch;
+                # attribute the flush time to its (kind, K-bucket) groups
+                # by item share so occupancy/batch accounting stays
+                # per-group
+                dt = time.perf_counter() - t_flush
+                for (kind, bucket), pends in groups.items():
+                    self.metrics.note_batch(
+                        len(pends), sum(len(p.pubkeys) for p in pends),
+                        bucket, dt * len(pends) / len(batch),
+                    )
                 if self._tracer is not None:
-                    self._tracer.span_many((p.trace for p in pends),
-                                           "device", t0, t1)
-                self._settle(pends, results)
-        # whole-flush device time (all groups): the prep/device split is
-        # per FLUSH on both sides, so the means share a denominator shape
+                    self._tracer.span_many((p.trace for p in batch),
+                                           "device", t_flush, t_flush + dt)
+                # counted before the verdicts resolve, so that a caller
+                # woken by its last future finds the flush counted
+                self._note_flush(flush, t_flush, handoff_wait_s)
+                self._settle(batch, results)
+            else:
+                for (kind, bucket), pends in groups.items():
+                    t0 = time.perf_counter()
+                    results = self._verify_group(kind, pends)
+                    t1 = time.perf_counter()
+                    self.metrics.note_batch(
+                        len(pends), sum(len(p.pubkeys) for p in pends),
+                        bucket, t1 - t0,
+                    )
+                    if self._tracer is not None:
+                        self._tracer.span_many((p.trace for p in pends),
+                                               "device", t0, t1)
+                    self._settle(pends, results)
+                self._note_flush(flush, t_flush, handoff_wait_s)
+        self.metrics.export_gauges()
+
+    def _note_flush(self, flush: _Flush, t_flush: float,
+                    handoff_wait_s: float) -> None:
+        """Whole-flush device time (all groups): the prep/device split is
+        per FLUSH on both sides, so the means share a denominator shape;
+        and the flush's ``serve`` record."""
         device_s = time.perf_counter() - t_flush
         self.metrics.note_device_flush(device_s)
         latency.note_stage("device", device_s)
-        self.metrics.export_gauges()
+        tracing.note_serve_flush(
+            flush.fid, len(flush.pends), flush.prep_s, device_s,
+            tracing.take_last_rlc(), flush.queue_wait_s, handoff_wait_s)
 
     def _verify_rlc(self, batch: List[_Pending]) -> Optional[List[bool]]:
         """Whole-micro-batch RLC verification (backend.batch_verify_rlc:

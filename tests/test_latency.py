@@ -26,7 +26,6 @@ PK = b"\x02" * 48
 def _clean_slate(monkeypatch):
     monkeypatch.setenv("CONSENSUS_SPECS_TPU_TRACE", "0")
     monkeypatch.setenv("CONSENSUS_SPECS_TPU_FLIGHT", "0")
-    monkeypatch.setenv("CONSENSUS_SPECS_TPU_DEVICES", "0")
     monkeypatch.delenv("CONSENSUS_SPECS_TPU_SLOT_MS", raising=False)
     monkeypatch.delenv("CONSENSUS_SPECS_TPU_SPECULATE", raising=False)
     profiling.reset()

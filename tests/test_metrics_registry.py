@@ -81,7 +81,7 @@ def test_emitted_labels_were_actually_found():
                      "chain.reorgs", "chain.dropped_attestations",
                      "vm.analysis_programs", "vm.analysis_errors",
                      "vm.analysis_hazards", "vm.analysis_max_live",
-                     "hist.families", "device.count", "flight.events",
+                     "hist.families", "flight.dropped", "flight.events",
                      "slo.ok", "bls.vm_cache_pruned_bytes",
                      "scale.final_exps_per_slot", "scale.pubkey_hit_rate"):
         assert expected in found, f"label scan lost {expected}"
@@ -157,12 +157,12 @@ def test_chain_gauge_family_is_complete():
 
 
 def test_fleet_gauge_families_are_complete():
-    # the PR 7 families (mergeable histograms, device ledger, flight
-    # recorder, SLO tracker): every emitted static label is registered
-    # AND every registered label has an emission site — a rename in
-    # either direction fails here instead of orphaning a scrape rule
+    # the fleet families (mergeable histograms, flight recorder, SLO
+    # tracker): every emitted static label is registered AND every
+    # registered label has an emission site — a rename in either
+    # direction fails here instead of orphaning a scrape rule
     emitted = _emitted_labels()
-    for prefix in ("hist.", "device.", "flight.", "slo.", "fleet."):
+    for prefix in ("hist.", "flight.", "slo.", "fleet."):
         family_emitted = {l for l in emitted if l.startswith(prefix)}
         family_registered = {n for n in registry.GAUGES
                              if n.startswith(prefix)}
@@ -171,10 +171,12 @@ def test_fleet_gauge_families_are_complete():
             f"{family_emitted - family_registered}, "
             f"registered-not-emitted={family_registered - family_emitted}"
         )
-    # the dynamic per-device family has a real emission site
-    dev_src = open(os.path.join(_PKG, "obs", "devices.py")).read()
-    assert 'f"device[{lane}]"' in dev_src
-    assert "device[" in registry.DYNAMIC_PREFIXES
+    # the occupancy ledger's families went with it (the profiler trace
+    # and the flush records replaced it): nothing emits or registers them
+    assert not os.path.exists(os.path.join(_PKG, "obs", "devices.py"))
+    assert "device[" not in registry.DYNAMIC_PREFIXES
+    assert not {l for l in set(emitted) | set(registry.GAUGES)
+                if l.startswith("device.")}
 
 
 def test_node_labelled_families_registered():

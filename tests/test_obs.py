@@ -19,7 +19,7 @@ import urllib.request
 
 import pytest
 
-from consensus_specs_tpu.obs import devices, flight, slo
+from consensus_specs_tpu.obs import flight, slo
 from consensus_specs_tpu.obs import programs as obs_programs
 from consensus_specs_tpu.obs import registry, tracing
 from consensus_specs_tpu.obs.exposition import start_exposition
@@ -48,11 +48,10 @@ def _clean_slate(monkeypatch):
     # from zero and leaves tracing disabled
     monkeypatch.setenv("CONSENSUS_SPECS_TPU_TRACE", "0")
     monkeypatch.setenv("CONSENSUS_SPECS_TPU_FLIGHT", "0")
-    monkeypatch.setenv("CONSENSUS_SPECS_TPU_DEVICES", "0")
     profiling.reset()
     tracing.reset_global()
     obs_programs.reset()
-    devices.reset_global()
+    tracing.reset_flush_records()
     flight.reset_global()
     slo.reset_global()
     was = bls.bls_active
@@ -60,7 +59,7 @@ def _clean_slate(monkeypatch):
     yield
     bls.bls_active = was
     tracing.reset_global()
-    devices.reset_global()
+    tracing.reset_flush_records()
     flight.reset_global()
     slo.reset_global()
 

@@ -1,7 +1,6 @@
-"""Fleet observability (ISSUE 7): the per-device occupancy ledger
-(obs/devices.py), the cross-plane flight recorder (obs/flight.py) with its
-fault-triggered JSONL dump, and SLO burn-rate tracking (obs/slo.py) with
-the upgraded /healthz. Everything runs against crypto-free backends so
+"""Fleet observability: the cross-plane flight recorder
+(obs/flight.py) with its fault-triggered JSONL dump, and SLO burn-rate
+tracking (obs/slo.py) with the upgraded /healthz. Everything runs against crypto-free backends so
 tier-1 stays fast; the real-crypto glue is `make serve-trace` /
 `make serve-bench`.
 """
@@ -14,7 +13,7 @@ import urllib.request
 
 import pytest
 
-from consensus_specs_tpu.obs import devices, flight, slo, tracing
+from consensus_specs_tpu.obs import flight, slo, tracing
 from consensus_specs_tpu.obs.exposition import start_exposition
 from consensus_specs_tpu.ops import profiling
 from consensus_specs_tpu.serve import VerificationService
@@ -30,11 +29,10 @@ PK = b"\x01" * 48
 def _clean_slate(monkeypatch):
     monkeypatch.setenv("CONSENSUS_SPECS_TPU_TRACE", "0")
     monkeypatch.setenv("CONSENSUS_SPECS_TPU_FLIGHT", "0")
-    monkeypatch.setenv("CONSENSUS_SPECS_TPU_DEVICES", "0")
     monkeypatch.delenv("CONSENSUS_SPECS_TPU_SLO", raising=False)
     profiling.reset()
     tracing.reset_global()
-    devices.reset_global()
+    tracing.reset_flush_records()
     flight.reset_global()
     slo.reset_global()
     was = bls.bls_active
@@ -42,7 +40,7 @@ def _clean_slate(monkeypatch):
     yield
     bls.bls_active = was
     tracing.reset_global()
-    devices.reset_global()
+    tracing.reset_flush_records()
     flight.reset_global()
     slo.reset_global()
 
@@ -67,89 +65,6 @@ def _svc(backend, **kw):
     kw.setdefault("bucket_fn", lambda k: 8)
     kw.setdefault("oracle", _Oracle())
     return VerificationService(backend=backend, **kw)
-
-
-# -- device occupancy ledger --------------------------------------------------
-
-
-def test_ledger_accumulates_busy_time_per_lane():
-    t = {"now": 100.0}
-    led = devices.DeviceLedger(clock=lambda: t["now"])
-    led.note_busy(0, 100.0, 100.5, label="vm")
-    led.note_busy(0, 100.5, 100.75, label="vm")
-    led.note_busy(devices.HOST_LANE, 100.0, 100.25, label="prep")
-    t["now"] = 101.0  # 1s elapsed
-    util = led.utilization()
-    assert util["0"] == pytest.approx(0.75)
-    assert util["host"] == pytest.approx(0.25)
-    snap = led.snapshot()
-    assert snap["lanes"]["0"]["events"] == 2
-    assert snap["lanes"]["0"]["busy_s"] == pytest.approx(0.75)
-    assert snap["lanes"]["host"]["utilization"] == pytest.approx(0.25)
-    tl = led.timeline()
-    assert ("0", "vm", 100.0, 100.5) in tl
-    assert ("host", "prep", 100.0, 100.25) in tl
-
-
-def test_ledger_note_execution_maps_meshless_runs_to_device_zero():
-    led = devices.DeviceLedger(clock=lambda: 0.0)
-    led.note_execution(None, 1.0, 0.5, label="vm[steps=64]")
-    assert led.snapshot()["lanes"] == {
-        "0": {"busy_s": 0.5, "utilization": 1.0, "events": 1}}
-
-
-def test_ledger_gauges_use_registered_families():
-    from consensus_specs_tpu.obs import registry
-
-    led = devices.DeviceLedger()
-    led.note_busy(0, 0.0, 0.1)
-    led.note_busy(devices.HOST_LANE, 0.0, 0.1)
-    led.export_gauges()
-    summ = profiling.summary()
-    assert summ["device.count"] == {"gauge": 2.0}
-    assert "device[0]" in summ and "device[host]" in summ
-    for label in ("device.count", "device.busy_s", "device[0]",
-                  "device[host]"):
-        assert registry.known(label), label
-
-
-def test_serve_prep_stage_feeds_the_host_lane(monkeypatch):
-    monkeypatch.setenv("CONSENSUS_SPECS_TPU_DEVICES", "1")
-    devices.reset_global()
-    with _svc(RlcVerdictBackend(), max_batch=4, max_wait_ms=5) as svc:
-        futs = [svc.submit("fast_aggregate", [PK], b"m%d" % i, b"ok")
-                for i in range(8)]
-        assert all(f.result(timeout=10) for f in futs)
-    snap = devices.global_ledger().snapshot()
-    assert "host" in snap["lanes"] and snap["lanes"]["host"]["events"] >= 1
-
-
-def test_disabled_ledger_is_a_none_check(monkeypatch):
-    monkeypatch.setenv("CONSENSUS_SPECS_TPU_DEVICES", "0")
-    assert devices.maybe_ledger() is None
-    with _svc(RlcVerdictBackend(), max_batch=1, max_wait_ms=0) as svc:
-        assert svc._devices is None
-        assert svc.submit("fast_aggregate", [PK], b"m", b"ok").result(
-            timeout=10) is True
-
-
-def test_occupancy_lane_rides_the_chrome_trace(monkeypatch, tmp_path):
-    monkeypatch.setenv("CONSENSUS_SPECS_TPU_DEVICES", "1")
-    devices.reset_global()
-    tracer = tracing.global_tracer()
-    led = devices.global_ledger()
-    led.note_busy(0, tracer._t0 + 0.001, tracer._t0 + 0.002, label="vm")
-    led.note_busy(devices.HOST_LANE, tracer._t0, tracer._t0 + 0.001,
-                  label="prep")
-    path = tracing.dump_trace(str(tmp_path / "trace.json"))
-    with open(path) as fh:
-        doc = json.load(fh)
-    lane = [e for e in doc["traceEvents"] if e.get("pid") == 3]
-    assert any(e["ph"] == "M" and e["args"].get("name") == "device-occupancy"
-               for e in lane)
-    xs = [e for e in lane if e["ph"] == "X"]
-    assert {e["args"]["lane"] for e in xs} == {"0", "host"}
-    assert all(e["ts"] >= 0 and e["dur"] > 0 for e in xs)
 
 
 # -- flight recorder ----------------------------------------------------------
@@ -414,12 +329,10 @@ def test_slo_bench_section_shape():
 
 
 def test_fleet_writers_vs_scrape_hammer(monkeypatch):
-    """Histogram writers + flight notes + device intervals racing /metrics
+    """Histogram writers + flight notes + flush records racing /metrics
     and /healthz scrapes: no exceptions, consistent totals."""
     monkeypatch.setenv("CONSENSUS_SPECS_TPU_FLIGHT", "1")
-    monkeypatch.setenv("CONSENSUS_SPECS_TPU_DEVICES", "1")
     flight.reset_global()
-    devices.reset_global()
     errors = []
     stop = threading.Event()
     n_threads, iters = 3, 300
@@ -430,8 +343,9 @@ def test_fleet_writers_vs_scrape_hammer(monkeypatch):
                 profiling.record_latency("serve.submit_to_result",
                                          0.001 * (i % 7 + 1))
                 flight.note("serve", "flush", items=i)
-                devices.global_ledger().note_busy(tid, i * 1e-4,
-                                                  i * 1e-4 + 5e-5)
+                with tracing.rlc_record(tid + 1):
+                    with tracing.span("rlc.prep"):
+                        pass
         except Exception as e:  # pragma: no cover
             errors.append(e)
 
@@ -460,4 +374,9 @@ def test_fleet_writers_vs_scrape_hammer(monkeypatch):
     assert flight.global_recorder().counters()["events"] == n_threads * iters
     lat = profiling.latency_summary()["serve.submit_to_result"]
     assert lat["n"] == n_threads * iters
-    assert len(devices.global_ledger().snapshot()["lanes"]) == n_threads
+    records = tracing.flush_records()
+    assert len(records) == n_threads * iters
+    assert tracing.flush_records_dropped() == 0
+    assert sorted({r["items"] for r in records}) == list(
+        range(1, n_threads + 1))
+    assert len({r["id"] for r in records}) == n_threads * iters
