@@ -430,12 +430,15 @@ clean:
 		*-pid[0-9]*.jsonl
 	find . -name __pycache__ -type d -prune -exec rm -rf {} +
 
-# build the native kernels (csrc/): batched-SHA256 merkleization and the
+# build the native kernels (csrc/): batched-SHA256 merkleization, the
 # VM assembler's scheduling+allocation kernel (ops/vm.py loads it via
 # ctypes when present; the pure-Python bucketed scheduler is the fallback)
+# and the host codec's BLS12-381 field arithmetic (utils/native_bls.py;
+# the raw-int Python path is the fallback)
 native:
 	gcc -O3 -fPIC -shared -o csrc/libsha256_batch.so csrc/sha256_batch.c
 	gcc -O3 -fPIC -shared -o csrc/libvmsched.so csrc/vm_sched.c
+	gcc -O3 -fPIC -shared -o csrc/libbls_host.so csrc/bls_host.c
 
 # regenerate the human-readable per-fork spec document set from specsrc/
 docs:

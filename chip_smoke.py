@@ -37,7 +37,8 @@ MAINNET_VALIDATORS = 1 << 20
 SLOT = 0
 BAD_COMMITTEE = 17
 NATIVE = (("sha256_batch.c", "libsha256_batch.so"),
-          ("vm_sched.c", "libvmsched.so"))
+          ("vm_sched.c", "libvmsched.so"),
+          ("bls_host.c", "libbls_host.so"))
 
 
 class SmokeFailure(Exception):

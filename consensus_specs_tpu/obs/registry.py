@@ -82,6 +82,11 @@ GAUGES: Dict[str, str] = {
                             "broken (reset_prep_state() clears)",
     "bls.prep_serial_fallback_items": "items that degraded to serial "
                                       "per-item host prep",
+    "bls.prep_native_items": "codec items whose host field math ran in "
+                             "the native kernel (csrc/bls_host.c)",
+    "bls.prep_python_items": "codec items whose host field math ran on "
+                             "raw Python ints (the native kernel did not "
+                             "load)",
     "bls.rlc_combines": "RLC combine programs run (process-wide)",
     "bls.rlc_bisections": "failed combined checks that forced a bisection "
                           "split",

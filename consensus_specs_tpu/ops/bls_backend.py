@@ -621,9 +621,10 @@ def _prewarm_worker(args):
 _POOL_BROKEN = False
 
 # prep-plane observability (ISSUE 2 satellite): which path warmed the
-# caches, how many items silently degraded to serial per-item prep, and
-# whether the pool latch is set — exported as ops/profiling gauges and
-# read by the serve plane's metrics snapshot
+# caches, how many items silently degraded to serial per-item prep, whether
+# the pool latch is set, and which host route did the codec's field math
+# (native kernel or raw-int Python, per item) — exported as ops/profiling
+# gauges and read by the serve plane's metrics snapshot
 PREP_STATS = {
     "codec_batches": 0,
     "codec_items": 0,
@@ -631,6 +632,8 @@ PREP_STATS = {
     "pool_items": 0,
     "serial_fallback_items": 0,
     "pool_broken_latches": 0,
+    "native_items": 0,
+    "host_python_items": 0,
 }
 
 
@@ -653,6 +656,20 @@ def _note_serial_fallback(n: int) -> None:
     )
 
 
+def note_codec_route(route: str, n: int) -> None:
+    """``n`` codec items ran on host route ``route`` (ops/codec.host_route;
+    the device route counts nothing here)."""
+    from . import profiling
+
+    if route == "native":
+        PREP_STATS["native_items"] += n
+        profiling.set_gauge("bls.prep_native_items", PREP_STATS["native_items"])
+    elif route == "python":
+        PREP_STATS["host_python_items"] += n
+        profiling.set_gauge("bls.prep_python_items",
+                            PREP_STATS["host_python_items"])
+
+
 def reset_prep_state() -> None:
     """reset_call_counts()-style recovery hook: clear the pool-broken latch
     and the prep counters, so a long-lived service can retry the pool after
@@ -665,6 +682,8 @@ def reset_prep_state() -> None:
 
     profiling.set_gauge("bls.prep_pool_broken", 0.0)
     profiling.set_gauge("bls.prep_serial_fallback_items", 0.0)
+    profiling.set_gauge("bls.prep_native_items", 0.0)
+    profiling.set_gauge("bls.prep_python_items", 0.0)
 
 
 def _codec_enabled() -> bool:
@@ -680,17 +699,18 @@ def _prewarm_batched(msgs, sigs, pks) -> None:
     full cache never silently discards a whole prepped batch."""
     from . import codec
 
+    path = codec.host_route()
     if msgs:
-        with tracing.span("codec.hash_to_g2", n=len(msgs)):
+        with tracing.span("codec.hash_to_g2", n=len(msgs), path=path):
             for m, v in zip(msgs, codec.message_limbs_batch(msgs, DST)):
                 _cache_put(_MSG_CACHE, m, v)
     if sigs:
-        with tracing.span("codec.signatures", n=len(sigs)):
+        with tracing.span("codec.signatures", n=len(sigs), path=path):
             for s, v in zip(sigs, codec.signature_limbs_batch(sigs)):
                 if not isinstance(v, ValueError):
                     _cache_put(_SIG_CACHE, s, v)
     if pks:
-        with tracing.span("codec.pubkeys", n=len(pks)):
+        with tracing.span("codec.pubkeys", n=len(pks), path=path):
             for p, v in zip(pks, codec.pubkey_limbs_batch(pks)):
                 if not isinstance(v, ValueError):
                     _cache_put(_PK_CACHE, p, v)
@@ -700,12 +720,14 @@ def prewarm_host_caches(messages: Sequence[bytes], signatures: Sequence[bytes],
                         pubkeys: Sequence[bytes] = ()):
     """Fill the hash-to-G2, signature-decode, and pubkey caches.
 
-    Default path: the BATCHED input codec (ops/codec.py) — vectorized
-    decompression with shared square-root chains and a Montgomery
-    batch-inversion ladder, VM-program subgroup checks, and native-SHA
-    batched hash-to-G2 — one array-wide pass instead of per-item
-    pure-Python prep (which costs ~29 ms/hash + ~8 ms/decode and would
-    serialize an epoch's ~2k distinct messages into minutes).
+    Default path: the BATCHED input codec (ops/codec.py) — decompression,
+    subgroup checks and native-SHA batched hash-to-G2, one pass per kind
+    with a shared batch-inversion ladder, their field math in the native
+    kernel (csrc/bls_host.c) or, without it, on raw ints — instead of
+    per-item pure-Python prep (which costs ~29 ms/hash + ~8 ms/decode and
+    would serialize an epoch's ~2k distinct messages into minutes).
+    `PREP_STATS` counts the items of each host route (`native_items`,
+    `host_python_items`).
 
     CONSENSUS_SPECS_TPU_BATCH_CODEC=0 (or a codec failure) falls back to
     the legacy process pool (CONSENSUS_SPECS_TPU_HASH_PROCS workers,

@@ -31,9 +31,11 @@ verification:
   point addition, and cofactor clearing — the bulk of the field work —
   are lowered to the `h2g_finish` VM program.
 
-By default the same algorithms run as a class-free raw-int host path
-instead, on every backend — see the "host (CPU-fallback) batched path"
-section below for why and what stays batched there.
+By default the same algorithms run on the host instead, on every
+backend (`host_route`): in the native kernel `csrc/bls_host.c`
+(utils/native_bls.py: 6 x 64-bit Montgomery field arithmetic, one call
+per batch) when it loads, else as the class-free raw-int Python path —
+see the "host batched path" section below. Both are bit-identical.
 `CONSENSUS_SPECS_TPU_CODEC_DEVICE=1` opts into the device placement off
 the TPU; on the TPU it raises (`_use_device`): the route is not brought
 up there.
@@ -51,7 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..utils import bls12_381 as O
-from ..utils import native_sha256
+from ..utils import native_bls, native_sha256
 from ..utils.bls12_381 import P
 from . import fq, vm
 from . import towers as tw
@@ -372,12 +374,15 @@ def _layout(kind: str, n_items: int, mesh):
 
 def g1_subgroup_check_batch(points: np.ndarray, mesh=None) -> np.ndarray:
     """points: (M, 2, L) canonical affine (ON the curve) -> bool (M,).
-    Device: the [r]P complete-addition ladder as a VM program. CPU
-    fallback: the same ladder on raw ints."""
+    Device: the [r]P complete-addition ladder as a VM program. Host: the
+    GLV criterion, natively or on raw ints."""
     m = points.shape[0]
     if m == 0:
         return np.zeros(0, dtype=bool)
-    if not _use_device():
+    route = host_route()
+    if route == "native":
+        return native_bls.g1_subgroup_check(points)
+    if route == "python":
         pts = [
             (fq.from_mont_limbs(points[i, 0]), fq.from_mont_limbs(points[i, 1]))
             for i in range(m)
@@ -398,12 +403,15 @@ def g1_subgroup_check_batch(points: np.ndarray, mesh=None) -> np.ndarray:
 
 def g2_subgroup_check_batch(points: np.ndarray, mesh=None) -> np.ndarray:
     """points: (M, 4, L) canonical affine [x.0, x.1, y.0, y.1] (ON the
-    curve) -> bool (M,). Device: the psi-criterion VM program. CPU
-    fallback: the same criterion on raw ints."""
+    curve) -> bool (M,). Device: the psi-criterion VM program. Host: the
+    same criterion, natively or on raw ints."""
     m = points.shape[0]
     if m == 0:
         return np.zeros(0, dtype=bool)
-    if not _use_device():
+    route = host_route()
+    if route == "native":
+        return native_bls.g2_subgroup_check(points)
+    if route == "python":
         pts = [
             (
                 (fq.from_mont_limbs(points[i, 0]),
@@ -522,7 +530,15 @@ def hash_to_g2_batch(
     n = len(messages)
     if n == 0:
         return np.zeros((0, 4, _L), dtype=np.uint64)
-    if not _use_device():
+    route = host_route()
+    _note_route(route, n)
+    if route == "native":
+        uniform = expand_message_xmd_batch(messages, dst, 4 * O.L_FIELD)
+        out, status = native_bls.hash_to_g2(b"".join(uniform), n)
+        if status:
+            raise ValueError(_H2G_ERRORS[status])
+        return out
+    if route == "python":
         out = np.zeros((n, 4, _L), dtype=np.uint64)
         for i, (x, y) in enumerate(_hash_to_g2_host(messages, dst)):
             out[i, 0] = fq.to_mont_int(x[0])
@@ -540,19 +556,20 @@ def hash_to_g2_batch(
 
 
 # ---------------------------------------------------------------------------
-# host (CPU-fallback) batched path: class-free Python ints
+# host batched path: the native kernel, else class-free Python ints
 # ---------------------------------------------------------------------------
-# The jax field kernels and VM programs above are the serving path on a
-# real accelerator, where wide limb arithmetic is effectively free. On the
-# CPU fallback the same limb math is compute-bound (hundreds of ms per
-# item through XLA:CPU) while CPython's bignum pow/mulmod is microseconds
-# — so the host path runs the SAME algorithms on raw ints, batched where
-# batching actually pays on a CPU: one native SHA-256 call per
-# expand_message_xmd round for the whole batch, one Fermat inversion
-# ladder (int_batch_inverse) shared by every division in a pass, and
-# class-free Jacobian ladders (~3x the oracle's Fq/Fq2-object path, which
-# spends most of its time on operator-dispatch overhead). Outputs are
-# bit-identical to the oracle on both paths.
+# The jax field kernels and VM programs above are not the serving path:
+# through XLA:CPU the wide limb math costs hundreds of ms per item, and
+# XLA's TPU compile of the SSWU kernel never finished. The host path runs
+# the SAME algorithms, batched where batching pays on a CPU: one native
+# SHA-256 call per expand_message_xmd round for the whole batch, one
+# batch-inversion ladder shared by every division in a pass, and Jacobian
+# ladders. Its field arithmetic runs in csrc/bls_host.c when that loads
+# (hash-to-G2 ~11x the raw-int path on a v5e host: one call per batch, no
+# Python objects per field operation, the GIL released); the raw-int Python
+# functions below are the no-compiler fallback and the middle oracle of
+# tests/test_codec_native.py. Outputs are bit-identical to the oracle on
+# every path.
 
 
 def _use_device() -> bool:
@@ -570,6 +587,28 @@ def _use_device() -> bool:
             "minutes on a v5e, PR 21); unset the variable to run the "
             "bit-identical host path.")
     return True
+
+
+def host_route() -> str:
+    """Where the codec's field math runs: "device" (the VM/jax programs,
+    opted into), "native" (csrc/bls_host.c) or "python" (raw ints, when
+    the native library did not load)."""
+    if _use_device():
+        return "device"
+    return "native" if native_bls.available() else "python"
+
+
+def _note_route(route: str, n: int) -> None:
+    from . import bls_backend  # lazy: bls_backend lazily imports codec back
+
+    bls_backend.note_codec_route(route, n)
+
+
+# the native kernel's status codes, as the raw-int path's ValueErrors
+_H2G_ERRORS = {1: "SSWU: no square root found",
+               2: "hash_to_g2: point at infinity"}
+_G1_DECODE_ERRORS = {1: "G1 x out of range", 2: "G1 x not on curve"}
+_G2_DECODE_ERRORS = {1: "G2 x out of range", 2: "G2 x not on curve"}
 
 
 _X_ABS = 0xD201000000010000  # |x|, the BLS parameter magnitude
@@ -915,11 +954,8 @@ def _gprime_t(x):
 
 def _hash_to_g2_host(messages: Sequence[bytes], dst: bytes):
     """Batched hash_to_g2 on raw ints: native batched SHA for the XMD
-    stage, inline sqrts for SSWU (data-dependent, not batchable on a CPU),
-    and ONE int_batch_inverse ladder each for the SSWU 1/tv2 divisions,
-    the iso-map denominators, and the final Jacobian->affine conversion.
-    Returns affine ((x0,x1),(y0,y1)) int pairs, oracle-identical."""
-    n = len(messages)
+    stage, then `_hash_to_g2_draws_host`. Returns affine
+    ((x0,x1),(y0,y1)) int pairs, oracle-identical."""
     us = []  # 2n field draws, msg-major: [m0.u0, m0.u1, m1.u0, ...]
     len_in_bytes = 2 * 2 * O.L_FIELD
     for u in expand_message_xmd_batch(messages, dst, len_in_bytes):
@@ -930,6 +966,15 @@ def _hash_to_g2_host(messages: Sequence[bytes], dst: bytes):
                 int.from_bytes(u[off + O.L_FIELD : off + 2 * O.L_FIELD],
                                "big") % P,
             ))
+    return _hash_to_g2_draws_host(us)
+
+
+def _hash_to_g2_draws_host(us):
+    """hash_to_g2 from 2n field draws (msg-major (c0, c1) int pairs):
+    inline sqrts for SSWU (data-dependent, not batchable on a CPU), and
+    ONE int_batch_inverse ladder each for the SSWU 1/tv2 divisions, the
+    iso-map denominators, and the final Jacobian->affine conversion."""
+    n = len(us) // 2
     # SSWU phase 1: tv1/tv2 for every draw, 1/tv2 through one ladder.
     # Fq2 inverse = conj/norm, norms inverted batch-wide (inv(0) unused:
     # tv2 == 0 lanes take the exceptional x1 and skip the division).
@@ -1051,7 +1096,14 @@ def decompress_g1_batch(blobs: Sequence[bytes]) -> List[object]:
     res, live, raw_bytes, flags_sign = _parse_g1(blobs)
     if not live:
         return res
-    if not _use_device():
+    route = host_route()
+    if route == "native":
+        pts, status = native_bls.g1_decompress(b"".join(raw_bytes), flags_sign)
+        for j, i in enumerate(live):
+            res[i] = (ValueError(_G1_DECODE_ERRORS[status[j]]) if status[j]
+                      else (pts[j, 0], pts[j, 1]))
+        return res
+    if route == "python":
         for i, raw, sign in zip(live, raw_bytes, flags_sign):
             v = _decompress_g1_int(raw, sign)
             res[i] = v if isinstance(v, ValueError) else (
@@ -1122,7 +1174,15 @@ def decompress_g2_batch(blobs: Sequence[bytes]) -> List[object]:
     res, live, raw1, raw0, flags_sign = _parse_g2(blobs)
     if not live:
         return res
-    if not _use_device():
+    route = host_route()
+    if route == "native":
+        pts, status = native_bls.g2_decompress(
+            b"".join(r1 + r0 for r1, r0 in zip(raw1, raw0)), flags_sign)
+        for j, i in enumerate(live):
+            res[i] = (ValueError(_G2_DECODE_ERRORS[status[j]]) if status[j]
+                      else pts[j])
+        return res
+    if route == "python":
         for i, r1, r0, sign in zip(live, raw1, raw0, flags_sign):
             v = _decompress_g2_int(r1, r0, sign)
             res[i] = v if isinstance(v, ValueError) else np.stack(
@@ -1171,6 +1231,7 @@ def decompress_g2_batch(blobs: Sequence[bytes]) -> List[object]:
 def pubkey_limbs_batch(pubkeys: Sequence[bytes], mesh=None) -> List[object]:
     """Batched _pubkey_limbs_compute: per item (x_limbs, y_limbs) or a
     ValueError VALUE (same messages as the per-item oracle path)."""
+    _note_route(host_route(), len(pubkeys))
     res = decompress_g1_batch(pubkeys)
     live = [i for i, v in enumerate(res) if isinstance(v, tuple)]
     for i, v in enumerate(res):
@@ -1188,6 +1249,7 @@ def pubkey_limbs_batch(pubkeys: Sequence[bytes], mesh=None) -> List[object]:
 def signature_limbs_batch(signatures: Sequence[bytes], mesh=None) -> List[object]:
     """Batched _signature_limbs_compute: per item a (4, L) limb stack or a
     ValueError VALUE (decode errors included, uniformly as values)."""
+    _note_route(host_route(), len(signatures))
     res = decompress_g2_batch(signatures)
     live = [i for i, v in enumerate(res) if isinstance(v, np.ndarray)]
     for i, v in enumerate(res):
