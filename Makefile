@@ -293,12 +293,13 @@ soak-smoke:
 # 64-committee shuffling computed columnar, real index-derived pubkeys,
 # per-committee aggregate signatures, the hierarchical aggregate-of-
 # aggregates fold (whole slot -> ONE RLC combine -> ONE final exp,
-# final_exps_per_slot == 1.0), the byte-budgeted decompressed-pubkey
-# plane, a planted bad committee localized by bisection, the strict
+# final_exps_per_slot == 1.0), every key in the device pubkey table
+# gathered by validator index, a planted bad committee localized by
+# bisection, the strict
 # censored_aggregates sim at true 64-committee fan-out, and 2-worker
 # committee-affinity fleet routing. The JSON line's `mainnet` section is
 # state-gated round over round by tools/bench_compare.py ("MAINNET
-# DIVERGED"); attestations/sec, pubkey hit rate, and peak RSS are
+# DIVERGED"); attestations/sec, the table's size and peak RSS are
 # report-only numbers. CONSENSUS_SPECS_TPU_SCALE_* env resizes.
 mainnet-bench:
 	JAX_PLATFORMS=cpu python bench.py --mode mainnet
@@ -306,8 +307,8 @@ mainnet-bench:
 # mainnet-workload CI canary (fleet-smoke's scale sibling): an
 # 8192-validator registry (two full-size committees/slot) through the
 # valid / censored / planted-bad-committee rounds with hierarchical ==
-# flat == host-oracle verdict identity, one-final-exp accounting, the
-# pubkey plane under budget, and committee affinity stable across a
+# flat == host-oracle verdict identity, one-final-exp accounting, keys
+# gathered from the pubkey table, and committee affinity stable across a
 # real 2-worker verdict fleet; journal dumps to scale_flight.jsonl (CI
 # artifact on failure). Crypto-light: summed-sk aggregates over small
 # secret keys keep it CI-fast.

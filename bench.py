@@ -449,8 +449,8 @@ def main():
         # slots over a synthetic million-validator registry —
         # mainnet-preset committee shuffling, hierarchical
         # aggregate-of-aggregates verification folding every committee
-        # of a slot into ONE final exp, the bytes-budgeted pubkey plane
-        # holding decompressed keys under RSS budget, a forced bad
+        # of a slot into ONE final exp, every key in the device pubkey
+        # table gathered by validator index, a forced bad
         # committee localized by bisection, simnet's censored_aggregates
         # at mainnet committee fan-out through the strict convergence
         # gate, and committee-affinity fleet routing. CPU-forced; the

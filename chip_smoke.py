@@ -121,8 +121,9 @@ def _committee_items(args):
 
 
 def slot_items(registry, procs: int = 1):
-    """The slot's committee aggregates as verify-plane items (what
-    ``scale.hierarchy.committee_items`` builds), derived by ``procs``
+    """The slot's committee aggregates as verify-plane items over
+    compressed keys (``scale.hierarchy.bytes_items`` of what
+    ``committee_items`` builds), derived by ``procs``
     spawned processes: deriving 32,768 pubkeys is host work the chip
     would otherwise wait ~40 s for. The workers never touch a device."""
     recipe = {k: getattr(registry, k) for k in (

@@ -6,14 +6,16 @@ million-validator registry: mainnet-preset committee shuffling (64
 committees/slot, ~n/2048 validators each), real index-derived pubkeys,
 per-committee aggregate signatures, hierarchical aggregate-of-
 aggregates verification (per-committee aggregates via the RLC combine,
-committee verdicts folded to ONE final exp per slot), the pubkey plane
-holding the decompressed working set under a byte budget.
+committee verdicts folded to ONE final exp per slot), every validator's
+key in one ``PubkeyTable`` on the device, gathered by validator index.
 
 Sections (the ``mainnet`` dict; ``ok`` flags feed bench_compare's
 "MAINNET DIVERGED" state gate, throughput numbers are report-only):
 
-- ``mainnet[slot_replay]``   — warm-round attestations/sec +
-  final_exps_per_slot + pubkey-plane hit rate + peak RSS vs budget.
+- ``mainnet[slot_replay]``   — attestations/sec over fresh slots (each
+  slot once, as a node sees them; the first pays the compiles) +
+  final_exps_per_slot + the table's keys and bytes + peak RSS vs
+  budget.
 - ``mainnet[bad_committee]`` — a forced bad committee at full fan-out,
   localized exactly by bisection.
 - ``mainnet[censored_sim]``  — simnet's ``censored_aggregates`` at
@@ -44,7 +46,7 @@ def run_mainnet_bench() -> dict:
     from ..obs import latency
     from ..ops import bls_backend, profiling
     from ..scale import hierarchy, routing
-    from ..scale.pubkeys import PubkeyPlane, peak_rss_bytes
+    from ..scale.pubkeys import PubkeyTable, peak_rss_bytes
     from ..scale.registry import Registry
 
     profiling.reset()
@@ -71,41 +73,27 @@ def run_mainnet_bench() -> dict:
     committee_size = len(committees[0][0])
 
     t0 = time.perf_counter()
+    table = PubkeyTable.build(reg.pubkey_column())
+    table_s = time.perf_counter() - t0
+
+    t0 = time.perf_counter()
     slot_items = [hierarchy.committee_items(reg, slot=s)
                   for s in range(n_slots)]
     derive_s = time.perf_counter() - t0
 
-    plane = PubkeyPlane()
-
-    # -- mainnet[slot_replay]: cold round warms, warm round is timed ------
-    cold_s = 0.0
-    cold_reports = []
-    for s, items in enumerate(slot_items):
-        rep = hierarchy.verify_slot(items, slot=s, plane=plane)
-        cold_reports.append(rep)
-        cold_s += rep.verify_s
-    plane_hits0, plane_misses0 = plane.hits, plane.misses
-
-    warm_reports = []
-    warm_s = 0.0
-    for s, items in enumerate(slot_items):
-        rep = hierarchy.verify_slot(items, slot=s, plane=plane)
-        warm_reports.append(rep)
-        warm_s += rep.verify_s
-    atts = sum(r.attestations for r in warm_reports)
-    atts_per_sec = atts / warm_s if warm_s > 0 else 0.0
-    warm_hits = plane.hits - plane_hits0
-    warm_misses = plane.misses - plane_misses0
-    warm_hit_rate = (warm_hits / (warm_hits + warm_misses)
-                     if (warm_hits + warm_misses) else 0.0)
-    final_exps_per_slot = (sum(r.final_exps for r in warm_reports)
-                           / len(warm_reports))
+    # -- mainnet[slot_replay]: every slot once, as a node sees them -------
+    reports = [hierarchy.verify_slot(items, slot=s, table=table)
+               for s, items in enumerate(slot_items)]
+    verify_s = sum(r.verify_s for r in reports)
+    atts = sum(r.attestations for r in reports)
+    atts_per_sec = atts / verify_s if verify_s > 0 else 0.0
+    final_exps_per_slot = (sum(r.final_exps for r in reports)
+                           / len(reports))
     peak_rss_mb = peak_rss_bytes() / (1 << 20)
 
-    replay_ok = (all(r.all_valid for r in cold_reports + warm_reports)
+    replay_ok = (all(r.all_valid for r in reports)
                  and final_exps_per_slot == 1.0
-                 and warm_hit_rate == 1.0
-                 and plane.bytes <= plane.budget_bytes
+                 and int(table.valid[:n].sum()) == n
                  and peak_rss_mb <= rss_budget_mb)
     all_ok &= replay_ok
     sections["slot_replay"] = {
@@ -116,12 +104,11 @@ def run_mainnet_bench() -> dict:
         "committee_size": committee_size,
         "attestations_per_slot": atts // n_slots,
         "atts_per_sec": round(atts_per_sec, 1),
-        "verify_s_per_slot": round(warm_s / n_slots, 3),
-        "cold_verify_s_per_slot": round(cold_s / n_slots, 3),
+        "verify_s_per_slot": round(verify_s / n_slots, 3),
         "final_exps_per_slot": round(final_exps_per_slot, 3),
-        "pubkey_hit_rate": round(warm_hit_rate, 4),
-        "pubkey_plane_mb": round(plane.bytes / (1 << 20), 1),
-        "pubkey_budget_mb": round(plane.budget_bytes / (1 << 20), 1),
+        "pubkey_table_keys": table.n,
+        "pubkey_table_mb": round(table.nbytes / (1 << 20), 1),
+        "pubkey_table_build_s": round(table_s, 3),
         "peak_rss_mb": round(peak_rss_mb, 1),
         "rss_budget_mb": rss_budget_mb,
         "registry_shuffle_s": round(shuffle_s, 3),
@@ -132,7 +119,7 @@ def run_mainnet_bench() -> dict:
     bad_ci = per_slot // 2
     items_b = list(slot_items[0])
     items_b[bad_ci] = hierarchy.corrupt_item(items_b[bad_ci])
-    rep_b = hierarchy.verify_slot(items_b, slot=0, plane=plane)
+    rep_b = hierarchy.verify_slot(items_b, slot=0, table=table)
     bad_ok = (rep_b.bad_committees == [bad_ci] and rep_b.bisections >= 1)
     all_ok &= bad_ok
     sections["bad_committee"] = {
@@ -200,7 +187,7 @@ def run_mainnet_bench() -> dict:
         }
 
     return dict(
-        metric="mainnet attestations/sec (hierarchical slot fold, warm)",
+        metric="mainnet attestations/sec (hierarchical slot fold, fresh slots)",
         value=sections["slot_replay"]["atts_per_sec"],
         vs_baseline=sections["slot_replay"]["final_exps_per_slot"],
         unit="attestations/sec",
@@ -210,7 +197,7 @@ def run_mainnet_bench() -> dict:
         ok=bool(all_ok),
         atts_per_sec=sections["slot_replay"]["atts_per_sec"],
         final_exps_per_slot=sections["slot_replay"]["final_exps_per_slot"],
-        pubkey_hit_rate=sections["slot_replay"]["pubkey_hit_rate"],
+        pubkey_table_keys=sections["slot_replay"]["pubkey_table_keys"],
         peak_rss_mb=sections["slot_replay"]["peak_rss_mb"],
         mainnet=sections,
         rlc_stats=dict(bls_backend.RLC_STATS),

@@ -230,22 +230,13 @@ GAUGES: Dict[str, str] = {
     "scale.registry_validators": "validators registered in the "
                                  "synthetic mainnet registry (columnar; "
                                  "never materialized per-validator)",
-    "scale.pubkey_cache_hits": "pubkey-plane lookups served from the "
-                               "bytes-budgeted LRU of decompressed G1 "
-                               "keys",
-    "scale.pubkey_cache_misses": "pubkey-plane lookups that paid "
-                                 "batched G1 decompression through the "
-                                 "vectorized codec path",
-    "scale.pubkey_cache_bytes": "decompressed-key bytes currently "
-                                "resident in the pubkey plane (held "
-                                "under CONSENSUS_SPECS_TPU_SCALE_"
-                                "PK_BUDGET_MB)",
-    "scale.pubkey_cache_evictions": "LRU entries evicted (and "
-                                    "un-mirrored from the backend host "
-                                    "cache) to stay under the byte "
-                                    "budget",
-    "scale.pubkey_hit_rate": "pubkey-plane hits / (hits + misses) over "
-                             "the process lifetime",
+    "scale.pubkey_table_keys": "validators whose keys the pubkey "
+                               "table holds, by index (the whole "
+                               "registry)",
+    "scale.pubkey_table_bytes": "bytes of the pubkey table's device "
+                                "array ((x, y) Montgomery limbs, 120 a "
+                                "key, capacity rounded up to 65,536 "
+                                "keys)",
     "scale.final_exps_per_slot": "final exponentiations the last "
                                  "hierarchical slot fold paid (1 = the "
                                  "whole slot shared one RLC root)",

@@ -32,6 +32,8 @@ import os
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..obs import tracing
@@ -490,9 +492,15 @@ def _pubkey_limbs_compute(pk: bytes):
     return fq.to_mont_int(aff[0].n), fq.to_mont_int(aff[1].n)
 
 
+def _pubkey_limbs_decode(pk: bytes):
+    """A cache miss of ``_pubkey_limbs``: one key decoded on the host."""
+    tracing.count("host_key_decodes")
+    return _pubkey_limbs_compute(pk)
+
+
 def _pubkey_limbs(pk: bytes) -> Tuple[np.ndarray, np.ndarray]:
     """Cached: validator pubkeys repeat across every slot of an epoch."""
-    return _cached(_PK_CACHE, pk, _pubkey_limbs_compute)
+    return _cached(_PK_CACHE, pk, _pubkey_limbs_decode)
 
 
 _SIG_CACHE: Dict[bytes, object] = {}
@@ -710,6 +718,7 @@ def _prewarm_batched(msgs, sigs, pks) -> None:
                 if not isinstance(v, ValueError):
                     _cache_put(_SIG_CACHE, s, v)
     if pks:
+        tracing.count("host_key_decodes", len(pks))
         with tracing.span("codec.pubkeys", n=len(pks), path=path):
             for p, v in zip(pks, codec.pubkey_limbs_batch(pks)):
                 if not isinstance(v, ValueError):
@@ -763,6 +772,7 @@ def _prewarm_pool(msgs, sigs, pks) -> None:
     work = [("msg", m) for m in msgs if m not in _MSG_CACHE]
     work += [("sig", s) for s in sigs if s not in _SIG_CACHE]
     work += [("pk", p) for p in pks if p not in _PK_CACHE]
+    tracing.count("host_key_decodes", sum(kind == "pk" for kind, _ in work))
     if len(work) < 16:
         # pool spawn overhead would exceed the serial recompute; these
         # items degrade to per-item prep in the verify loop — count them
@@ -1265,6 +1275,106 @@ def _miller_fast_aggregate(
     return out, lay, precheck
 
 
+def _index_column(column, table) -> Optional[np.ndarray]:
+    """A committee's validator indices as int64, or None where the item
+    must be False: an empty column, an index outside the table, or a key
+    that failed KeyValidate."""
+    col = np.asarray(column)
+    if col.ndim != 1 or not len(col) or col.dtype.kind not in "iu":
+        return None
+    col = col.astype(np.int64)
+    if col.min() < 0 or col.max() >= table.n or not table.valid[col].all():
+        return None
+    return col
+
+
+def pubkey_gather(table, idx):
+    """Committee keys from the table, on the device: ``table`` (capacity,
+    2, L) uint32 [x, y] canonical Montgomery, ``idx`` (rows, fold, K) int32
+    validator indices (-1: a padding lane). Returns the lanes' projective
+    (x, y, z) in uint32: the key with z = 1, or
+    infinity's (0, 1, 0) on a padding lane, stacked (rows, fold * K * 3,
+    L) in the order fold, lane, coordinate. Jitted, its XLA module is
+    ``jit_pubkey_gather``."""
+    live = (idx >= 0)[..., None, None]
+    xy = jnp.take(table, jnp.maximum(idx, 0), axis=0)
+    one = jnp.asarray(_ONE_LIMBS, dtype=jnp.uint32)
+    zero = jnp.zeros_like(one)
+    x = jnp.where(live, xy[..., 0:1, :], zero)
+    y = jnp.where(live, xy[..., 1:2, :], one)
+    z = jnp.where(live, one, zero)
+    lanes = jnp.concatenate([x, y, z], axis=-2)
+    return lanes.reshape(idx.shape[0], -1, lanes.shape[-1])
+
+
+_PUBKEY_GATHER = jax.jit(pubkey_gather)
+
+
+def _gather_keys(table, idx: np.ndarray):
+    out = _PUBKEY_GATHER(table, idx)
+    out.block_until_ready()
+    return out
+
+
+def _miller_fast_aggregate_indexed(
+    index_sets, messages, signatures, table
+) -> Tuple[Optional[dict], "_FoldLayout", np.ndarray]:
+    """The index path of the PROG A stage, with _miller_fast_aggregate's
+    contract: each item's keys are rows of ``table`` (a
+    ``scale.pubkeys.PubkeyTable``), gathered by validator index into the
+    program's key lanes on the device (``pubkey_gather``), so the host
+    decodes and stages no key. Host prep is left with the messages, the
+    signatures and the index columns. One device: the table has no mesh
+    layout."""
+    n = len(index_sets)
+    cols = [_index_column(c, table) for c in index_sets]
+    k = _k_bucket(max([1] + [len(c) for c in index_sets]))
+    group = f"fast_aggregate_indexed/{k}"
+    with tracing.span("rlc.prep", group=group, n=n):
+        L = fq.NUM_LIMBS
+        lay = _FoldLayout("miller_product", k, n, None)
+        nb = lay.nb
+        prewarm_host_caches(
+            [bytes(m) for m, c in zip(messages, cols) if c is not None],
+            [bytes(s) for s, c in zip(signatures, cols) if c is not None],
+        )
+        precheck = np.zeros(nb, dtype=bool)
+        idx = np.full((nb, k), -1, dtype=np.int32)
+        hm = np.zeros((nb, 4, L), dtype=np.uint64)
+        hm[:] = _G2GEN_LIMBS
+        sg = np.zeros((nb, 4, L), dtype=np.uint64)
+        sg[:] = _G2GEN_LIMBS
+        for i, (col, msg, sig) in enumerate(zip(cols, messages, signatures)):
+            if col is None:
+                continue
+            try:
+                s = _signature_limbs(bytes(sig))
+                h = _message_limbs(bytes(msg))
+            except Exception:
+                continue
+            idx[i, :len(col)] = col
+            hm[i] = h
+            sg[i] = s
+            precheck[i] = True
+
+        if not precheck.any():
+            return None, lay, precheck
+
+        keys = int((idx >= 0).sum())
+        with tracing.span("pubkeys.gather", group=group, n=keys):
+            lanes = _gather_keys(table.limbs, lay.views(idx))
+        tracing.count("keys_gathered", keys)
+        names = [f"{_ns(lay.fold, t)}pk{j}.{c}" for t in range(lay.fold)
+                 for j in range(k) for c in "xyz"]
+        ins = {}
+        lay.scatter(ins, hm, lambda ci: f"h.{_G2_COMPS[ci]}")
+        lay.scatter(ins, sg, lambda ci: f"sig.{_G2_COMPS[ci]}")
+    with tracing.span("rlc.miller", group=group):
+        out = vm.execute(lay.program, ins, batch_shape=(lay.rows,),
+                         device_inputs=(names, lanes))
+    return out, lay, precheck
+
+
 def batch_fast_aggregate_verify(
     pubkey_sets: Sequence[Sequence[bytes]],
     messages: Sequence[bytes],
@@ -1578,7 +1688,7 @@ def _rlc_combine_jax(fs: np.ndarray, bits: np.ndarray) -> List[int]:
     return [fq.from_mont_limbs(c[j]) for j in range(12)]
 
 
-def batch_verify_rlc(items, mesh=None, rng=None) -> np.ndarray:
+def batch_verify_rlc(items, mesh=None, rng=None, table=None) -> np.ndarray:
     """N independent verifications decided by random-linear-combination:
     check prod_i f_i^{r_i} == 1 (post final exp) for fresh random nonzero
     128-bit scalars r_i, so the whole micro-batch pays ONE easy part and
@@ -1587,7 +1697,12 @@ def batch_verify_rlc(items, mesh=None, rng=None) -> np.ndarray:
 
     ``items``: sequence of (kind, pubkeys, messages, signature) with kind
     'fast_aggregate' (one message) or 'aggregate' (per-key messages) —
-    the serve plane's micro-batch shape. Items are grouped by
+    the serve plane's micro-batch shape — or 'fast_aggregate_indexed',
+    whose second field is a column of validator indices into ``table``
+    (a ``scale.pubkeys.PubkeyTable``, whose keys are gathered on the
+    device; an empty column, an index outside the table or a key that
+    failed KeyValidate gives False; one device only, no ``mesh``).
+    Items are grouped by
     (kind, K-bucket) for PROG A exactly like SignatureCollector.flush,
     and the Miller outputs feed the combine program as raw loose limbs
     (no per-item host canonicalization or easy part).
@@ -1620,17 +1735,26 @@ def batch_verify_rlc(items, mesh=None, rng=None) -> np.ndarray:
         return np.zeros(0, dtype=bool)
     with tracing.rlc_record(n) as rec, tracing.span(
             "rlc.flush", flush=rec["id"], items=n):
-        return _batch_verify_rlc(items, mesh, rng)
+        return _batch_verify_rlc(items, mesh, rng, table)
 
 
-def _batch_verify_rlc(items, mesh, rng) -> np.ndarray:
+_KINDS = ("fast_aggregate", "aggregate", "fast_aggregate_indexed")
+
+
+def _batch_verify_rlc(items, mesh, rng, table) -> np.ndarray:
     n = len(items)
     verdict = np.zeros(n, dtype=bool)
 
     groups: Dict[Tuple[str, int], List[int]] = {}
     for i, (kind, pks, _msgs, _sig) in enumerate(items):
-        if kind not in ("fast_aggregate", "aggregate"):
+        if kind not in _KINDS:
             raise ValueError(f"unknown check kind {kind!r}")
+        if kind == "fast_aggregate_indexed" and table is None:
+            raise ValueError("fast_aggregate_indexed items need table=")
+        if kind == "fast_aggregate_indexed" and mesh is not None:
+            raise ValueError(
+                "fast_aggregate_indexed items run on one device: the pubkey "
+                "table has no mesh layout; verify them without mesh=")
         groups.setdefault((kind, _k_bucket(max(1, len(pks)))), []).append(i)
 
     # PROG A per (kind, bucket) group; gather surviving candidates' Miller
@@ -1645,6 +1769,11 @@ def _batch_verify_rlc(items, mesh, rng) -> np.ndarray:
                 [it[1] for it in sub], [it[2] for it in sub],
                 [it[3] for it in sub], mesh,
             )
+        elif kind == "fast_aggregate_indexed":
+            out, lay, precheck = _miller_fast_aggregate_indexed(
+                [it[1] for it in sub], [it[2] for it in sub],
+                [it[3] for it in sub], table,
+            )
         else:
             out, lay, precheck = _miller_aggregate(
                 [it[1] for it in sub], [it[2] for it in sub],
@@ -1656,7 +1785,7 @@ def _batch_verify_rlc(items, mesh, rng) -> np.ndarray:
             if not precheck[pos]:
                 continue
             r, ns = lay.split(pos)
-            if kind == "fast_aggregate" and (
+            if kind != "aggregate" and (
                 fq.from_mont_limbs(out[f"{ns}aggz"][r]) == 0
             ):
                 continue  # aggregate pubkey is infinity: False, no crypto

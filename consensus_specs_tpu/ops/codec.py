@@ -1246,6 +1246,40 @@ def pubkey_limbs_batch(pubkeys: Sequence[bytes], mesh=None) -> List[object]:
     return res
 
 
+def pubkey_table_limbs(encoded: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """KeyValidate of an (n, 48) uint8 array of compressed keys, for a
+    table by validator index: ((n, 2, L) uint32 canonical Montgomery [x, y],
+    (n,) bool). A key that ``pubkey_limbs_batch`` would reject (a bad
+    encoding, infinity, x off the curve, a point outside G1) reads False
+    with zero limbs. Vectorized flag parsing and one native call per pass;
+    the caller counts the route (this may run on several threads)."""
+    n = encoded.shape[0]
+    limbs = np.zeros((n, 2, _L), dtype=np.uint32)
+    valid = np.zeros(n, dtype=bool)
+    flags = encoded[:, 0]
+    live = np.flatnonzero((flags & O.FLAG_COMPRESSED != 0)
+                          & (flags & O.FLAG_INFINITY == 0))
+    if not len(live):
+        return limbs, valid
+    if host_route() == "native":
+        raw = encoded[live]
+        raw[:, 0] &= 0x1F
+        pts, status = native_bls.g1_decompress(
+            raw.tobytes(), flags[live] & O.FLAG_SIGN != 0)
+        on = status == 0
+    else:
+        res = decompress_g1_batch([encoded[i].tobytes() for i in live])
+        on = np.array([isinstance(v, tuple) for v in res], dtype=bool)
+        pts = np.zeros((len(live), 2, _L), dtype=np.uint64)
+        for j in np.flatnonzero(on):
+            pts[j] = np.stack(res[j])
+    ok = np.zeros(len(live), dtype=bool)
+    ok[on] = g1_subgroup_check_batch(pts[on])
+    limbs[live[ok]] = pts[ok]
+    valid[live[ok]] = True
+    return limbs, valid
+
+
 def signature_limbs_batch(signatures: Sequence[bytes], mesh=None) -> List[object]:
     """Batched _signature_limbs_compute: per item a (4, L) limb stack or a
     ValueError VALUE (decode errors included, uniformly as values)."""

@@ -779,14 +779,17 @@ class Program:
             t[reg] = fq.to_mont_int(value)
         return t
 
-    def stack_inputs(self, values: Dict[str, np.ndarray], batch_shape) -> np.ndarray:
+    def stack_inputs(self, values: Dict[str, np.ndarray], batch_shape,
+                     names: Optional[List[str]] = None) -> np.ndarray:
         """Stack named inputs into (batch..., n_inputs, L) uint32 in
-        input_names order. Program inputs are canonical Montgomery residues
-        (limbs < 2^28), so the u32 transfer encoding is exact — and half
-        the bytes over the host->device link."""
-        n_in = len(self.input_names)
+        input_names order (or in the order of ``names``, a subset).
+        Program inputs are canonical Montgomery residues (limbs < 2^28), so
+        the u32 transfer encoding is exact — and half the bytes over the
+        host->device link."""
+        names = self.input_names if names is None else names
+        n_in = len(names)
         out = np.zeros(tuple(batch_shape) + (n_in, fq.NUM_LIMBS), dtype=np.uint32)
-        for idx, name in enumerate(self.input_names):
+        for idx, name in enumerate(names):
             v = np.asarray(values[name], dtype=np.uint64)
             if v.size and int(v.max()) >> fq.LIMB_BITS:
                 raise ValueError(
@@ -796,6 +799,34 @@ class Program:
                 )
             out[..., idx, :] = v
         return out
+
+    def place_inputs(self, values: Dict[str, np.ndarray], device_inputs,
+                     batch_shape):
+        """The (batch..., n_inputs, L) uint32 input stack on the device:
+        ``device_inputs`` is (names, array), the array (batch..., len(names),
+        L) uint32 already on the device; every other input comes from the
+        host's ``values`` (canonical, as for ``stack_inputs``)."""
+        names, dev = device_inputs
+        on_device = set(names)
+        host_names = [n for n in self.input_names if n not in on_device]
+        if len(host_names) + len(names) != len(self.input_names):
+            raise ValueError("device inputs must be distinct program inputs")
+        host = self.stack_inputs(values, batch_shape, host_names)
+        pos = {n: i for i, n in enumerate(self.input_names)}
+        return vm_place_inputs(
+            jnp.asarray(host), dev,
+            jnp.asarray([pos[n] for n in host_names], dtype=jnp.int32),
+            jnp.asarray([pos[n] for n in names], dtype=jnp.int32))
+
+
+@jax.jit
+def vm_place_inputs(host, dev, host_pos, dev_pos):
+    """The input stack from its host-stacked and device parts (XLA module
+    ``jit_vm_place_inputs``)."""
+    n_in = host_pos.shape[0] + dev_pos.shape[0]
+    out = jnp.zeros(host.shape[:-2] + (n_in, host.shape[-1]), jnp.uint32)
+    out = out.at[..., host_pos, :].set(host)
+    return out.at[..., dev_pos, :].set(dev)
 
 
 # MP + 1 in limb form: the additive shift of the borrowless subtract
@@ -1001,12 +1032,15 @@ def _vm_run_for_mesh(mesh, pallas_mode="0", name="vm_program"):
 
 
 def execute(program: Program, inputs: Dict[str, np.ndarray], batch_shape=(),
-            mesh=None) -> Dict[str, np.ndarray]:
+            mesh=None, device_inputs=None) -> Dict[str, np.ndarray]:
     """Run an assembled program. Input arrays must be canonical Montgomery
     limb arrays of shape batch_shape + (NUM_LIMBS,). Returns named outputs
     (loose, bounded < 2^382). With ``mesh``, the leading batch axis is
     sharded over ALL the mesh's axes (batch_shape[0] must divide by the
-    total device count).
+    total device count). ``device_inputs`` (names, array): inputs already
+    on the device, as a batch_shape + (len(names), NUM_LIMBS) uint32 array
+    (the pubkey table's gathered keys); ``inputs`` then holds the rest.
+    The program and its executable are the same either way.
 
     Execution backend (CONSENSUS_SPECS_TPU_VM_EXEC): ``interp`` runs the
     lax.scan interpreter below; ``fused`` runs the straight-line lowering
@@ -1022,7 +1056,11 @@ def execute(program: Program, inputs: Dict[str, np.ndarray], batch_shape=(),
     from . import profiling, vm_compile
     from ..obs import tracing
 
-    stacked = program.stack_inputs(inputs, tuple(batch_shape))
+    if device_inputs is None:
+        stacked = program.stack_inputs(inputs, tuple(batch_shape))
+    else:
+        stacked = program.place_inputs(inputs, device_inputs,
+                                       tuple(batch_shape))
     label = (
         f"vm[steps={program.n_steps},regs={program.n_regs},"
         f"batch={tuple(batch_shape)},sharded={mesh is not None}]"

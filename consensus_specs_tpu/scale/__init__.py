@@ -8,9 +8,11 @@ plane at production scale:
   real index-derived pubkeys and mainnet-preset committee shuffling
   (vectorized swap-or-not, bit-identical to ``spec.compute_committee``),
   emitted lazily as columnar numpy state.
-- ``pubkeys.py``   — memory-bounded pubkey plane: batched G1
-  decompression through ``ops/codec.py`` feeding a bytes-budgeted LRU
-  over decompressed keys (``scale.pubkey_*`` gauges).
+- ``pubkeys.py``   — the pubkey table: every validator's key by index,
+  KeyValidated once through ``ops/codec.py``'s batched native
+  decompression and held on the device (126 MB at 1,048,576 keys), from
+  which the RLC engine gathers a committee's keys by validator index
+  (``scale.pubkey_table_*`` gauges).
 - ``hierarchy.py`` — per-committee aggregates verified via the RLC
   combine, committee verdicts folded up a slot-level tree so the
   ``_FinalExpBatcher`` keeps cost at ONE final-exp execution per slot,

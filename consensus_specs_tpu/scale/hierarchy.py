@@ -16,7 +16,11 @@ exact per-committee finalization at the leaves.
 fold + bisection engine every other plane uses — bit-identical
 verdicts to the flat per-committee path) with the slot-level
 accounting the mainnet workload reports: final-exps-per-slot,
-bisection path, localized bad committees, pubkey-plane warmth.
+bisection path, localized bad committees. A slot's checks are
+``fast_aggregate_indexed`` items: each committee's validator-index
+column, its message and its aggregate signature; the keys come from the
+whole registry's ``PubkeyTable`` (scale/pubkeys.py), gathered on the
+device.
 """
 import time
 from dataclasses import dataclass, field
@@ -24,7 +28,8 @@ from typing import List, Sequence, Tuple
 
 import numpy as np
 
-CommitteeItem = Tuple[str, Sequence[bytes], object, bytes]
+# (kind, validator indices or compressed keys, message(s), signature)
+CommitteeItem = Tuple[str, Sequence, object, bytes]
 
 
 @dataclass
@@ -41,8 +46,6 @@ class SlotReport:
     final_exps: int
     final_exp_windows: int
     verify_s: float
-    pubkey_hits: int = 0
-    pubkey_misses: int = 0
     extra: dict = field(default_factory=dict)
 
     @property
@@ -56,37 +59,40 @@ class SlotReport:
 
 def committee_items(registry, slot: int,
                     participation: float = 1.0) -> List[CommitteeItem]:
-    """The slot's full committee fan-out as backend-shaped items."""
+    """The slot's full committee fan-out as index items
+    (``fast_aggregate_indexed``: the participants' validator indices)."""
     items: List[CommitteeItem] = []
     for ci in range(registry.committees_per_slot()):
-        pks, msg, sig = registry.aggregate(slot, ci,
-                                           participation=participation)
-        items.append(("fast_aggregate", pks, msg, sig))
+        members, msg, sig = registry.aggregate_members(
+            slot, ci, participation=participation)
+        items.append(("fast_aggregate_indexed", members, msg, sig))
     return items
 
 
+def bytes_items(items: Sequence[CommitteeItem],
+                registry) -> List[CommitteeItem]:
+    """Index items as ``fast_aggregate`` items over the registry's
+    compressed keys: the form the flat and oracle paths take."""
+    return [("fast_aggregate", registry.pubkeys(cols), msg, sig)
+            if kind == "fast_aggregate_indexed"
+            else (kind, cols, msg, sig) for kind, cols, msg, sig in items]
+
+
 def verify_slot(items: Sequence[CommitteeItem], *, slot: int = 0,
-                plane=None, mesh=None, rng=None) -> SlotReport:
+                table=None, mesh=None, rng=None, backend=None) -> SlotReport:
     """Hierarchically verify one slot's committee aggregates.
 
-    ``plane`` (a ``PubkeyPlane``) is warmed with the slot's full pubkey
-    column first — batched decompression, byte-budgeted residency — so
-    the backend's host prep runs entirely from warm columnar state.
-    Verdict semantics are ``batch_verify_rlc``'s: bit-identical to the
-    flat per-committee path on every input."""
+    ``table`` (a ``PubkeyTable``) holds the keys of the index items'
+    validators; ``backend`` is the engine (``ops.bls_backend`` by
+    default). Verdict semantics are ``batch_verify_rlc``'s: bit-identical
+    to the flat per-committee path on every input."""
     from ..ops import bls_backend, profiling
 
     items = list(items)
-    hits = misses = 0
-    if plane is not None:
-        flat: List[bytes] = []
-        for _, pks, _, _ in items:
-            flat.extend(bytes(pk) for pk in pks)
-        hits, misses = plane.warm(flat)
-
+    engine = bls_backend if backend is None else backend
     before = dict(bls_backend.RLC_STATS)
     t0 = time.perf_counter()
-    verdicts = bls_backend.batch_verify_rlc(items, mesh=mesh, rng=rng)
+    verdicts = engine.batch_verify_rlc(items, mesh=mesh, rng=rng, table=table)
     verify_s = time.perf_counter() - t0
     after = bls_backend.RLC_STATS
 
@@ -102,8 +108,6 @@ def verify_slot(items: Sequence[CommitteeItem], *, slot: int = 0,
         final_exp_windows=(after["final_exp_windows"]
                            - before["final_exp_windows"]),
         verify_s=verify_s,
-        pubkey_hits=hits,
-        pubkey_misses=misses,
     )
     profiling.set_gauge("scale.final_exps_per_slot",
                         report.final_exps_per_slot)

@@ -1,35 +1,32 @@
-"""Memory-bounded pubkey plane: batched decompression + bytes-budgeted LRU.
+"""The validator pubkey table: every registered key, by validator index,
+held on the device.
 
-A mainnet registry is ~1M compressed pubkeys; decompressed Montgomery
-limb columns are ~13x larger, so "decompress everything once" is a
-multi-GB resident set. This plane holds the DECOMPRESSED working set
-under an explicit byte budget: committee misses go through the
-``ops/codec.py`` vectorized G1 decompression (+ subgroup check) in one
-batch, land in an LRU ordered dict accounted in bytes, and are mirrored
-into ``bls_backend._PK_CACHE`` so the verify path's host prep finds
-every key warm. Eviction pops BOTH sides — the budget is a real bound
-on decompressed-key memory, not a suggestion.
+A beacon node's pubkey cache holds the whole registry (1,048,576 keys on
+today's mainnet), and the swap-or-not shuffle gives each slot attesters
+that no other slot of the epoch has, so every key is touched once an
+epoch: a cache smaller than the registry misses on most of a slot. The
+table has no budget and no eviction. Its size is the registry's: each
+key is KeyValidated once, through the codec's batched decompression and
+subgroup check (``codec.pubkey_table_limbs``, spread over the host's
+cores: the native calls release the interpreter lock), and stored as
+(x, y) canonical Montgomery limbs, 120 bytes a key, in one (capacity, 2,
+L) uint32 device array beside a host validity mask. The RLC engine's
+index path (``bls_backend.batch_verify_rlc`` items
+``("fast_aggregate_indexed", indices, message, signature)``) gathers a
+committee's keys from it on the device.
 
-Gauges (``scale.pubkey_*``): hits, misses, bytes, evictions, hit rate.
+Gauges: ``scale.pubkey_table_keys``, ``scale.pubkey_table_bytes``.
 """
 import os
-from collections import OrderedDict
-from typing import List, Sequence, Tuple
+from concurrent.futures import ThreadPoolExecutor
+from typing import Sequence, Union
 
-BUDGET_ENV = "CONSENSUS_SPECS_TPU_SCALE_PK_BUDGET_MB"
-_DEFAULT_BUDGET_MB = 256
+import numpy as np
 
-# conservative per-entry overhead: dict slot + key bytes + tuple + two
-# ndarray headers (the limb payload itself is counted exactly)
-_ENTRY_OVERHEAD = 256
-
-
-def default_budget_bytes() -> int:
-    try:
-        mb = float(os.environ.get(BUDGET_ENV, "") or _DEFAULT_BUDGET_MB)
-    except ValueError:
-        mb = _DEFAULT_BUDGET_MB
-    return max(1, int(mb * (1 << 20)))
+# the device array grows in whole blocks, so that a few deposits do not
+# change its shape (each shape compiles the gather once)
+_BLOCK = 1 << 16
+_CHUNK = 8192  # keys a decode task
 
 
 def rss_bytes() -> int:
@@ -54,125 +51,119 @@ def peak_rss_bytes() -> int:
     return rss_bytes()
 
 
-class PubkeyPlane:
-    """Bytes-budgeted LRU over decompressed G1 pubkeys.
+Keys = Union[np.ndarray, Sequence[bytes]]
 
-    ``warm(pubkeys)`` batch-decompresses the misses through the codec
-    vectorized path and returns (hits, misses) for the call. Entries
-    are (x_limbs, y_limbs) Montgomery columns — the exact value
-    ``bls_backend._PK_CACHE`` stores, which this plane keeps mirrored
-    for every key it holds so the serve/verify host prep never pays a
-    per-item decompression for a committee the plane warmed.
-    """
 
-    def __init__(self, budget_bytes: int = None, mirror_backend: bool = True):
-        self.budget_bytes = (default_budget_bytes()
-                             if budget_bytes is None else int(budget_bytes))
-        if self.budget_bytes <= 0:
-            raise ValueError("pubkey-plane budget must be positive")
-        self.mirror_backend = mirror_backend
-        self._lru: "OrderedDict[bytes, Tuple]" = OrderedDict()
-        self.bytes = 0
-        self.hits = 0
-        self.misses = 0
-        self.evictions = 0
-        self.rejected = 0  # invalid encodings (never cached)
+def _encoded(keys: Keys) -> np.ndarray:
+    """(n, 48) uint8 from compressed keys; a key that is not 48 bytes
+    becomes a row without the compression flag, which KeyValidate
+    rejects."""
+    if isinstance(keys, np.ndarray):
+        if keys.dtype != np.uint8 or keys.ndim != 2 or keys.shape[1] != 48:
+            raise ValueError("encoded keys must be an (n, 48) uint8 array")
+        return keys
+    keys = [bytes(k) for k in keys]
+    if all(len(k) == 48 for k in keys):
+        return np.frombuffer(b"".join(keys), dtype=np.uint8).reshape(-1, 48)
+    out = np.zeros((len(keys), 48), dtype=np.uint8)
+    for i, k in enumerate(keys):
+        if len(k) == 48:
+            out[i] = np.frombuffer(k, dtype=np.uint8)
+    return out
+
+
+def _capacity(n: int) -> int:
+    return max(_BLOCK, -(-n // _BLOCK) * _BLOCK)
+
+
+class PubkeyTable:
+    """The registry's keys by validator index. ``limbs`` is the (capacity,
+    2, L) uint32 device array (rows past ``n`` are zero), ``valid`` the
+    host's (capacity,) KeyValidate mask (False past ``n``)."""
+
+    def __init__(self, limbs, valid: np.ndarray, n: int):
+        self.limbs = limbs
+        self.valid = valid
+        self.n = int(n)
+        self._export_gauges()
 
     def __len__(self) -> int:
-        return len(self._lru)
+        return self.n
 
-    def __contains__(self, pubkey: bytes) -> bool:
-        return bytes(pubkey) in self._lru
+    @property
+    def nbytes(self) -> int:
+        """Bytes of the device array."""
+        return int(self.limbs.nbytes)
 
-    @staticmethod
-    def _entry_bytes(key: bytes, value) -> int:
-        x, y = value
-        return len(key) + int(x.nbytes) + int(y.nbytes) + _ENTRY_OVERHEAD
+    @classmethod
+    def build(cls, compressed_keys: Keys) -> "PubkeyTable":
+        """Decompress and KeyValidate every key (validator ``i`` is
+        ``compressed_keys[i]``) and place the table on the device."""
+        import jax
 
-    def _backend_cache(self):
-        from ..ops import bls_backend
+        encoded = _encoded(compressed_keys)
+        n = encoded.shape[0]
+        host, valid = _decode(encoded, _capacity(n))
+        return cls(jax.device_put(host), valid, n)
 
-        return bls_backend
+    def extend(self, new_keys: Keys) -> None:
+        """Append keys (deposits) as validators ``n, n + 1, ...``."""
+        import jax
+        import jax.numpy as jnp
 
-    def _evict_to_budget(self) -> None:
-        backend = self._backend_cache() if self.mirror_backend else None
-        while self.bytes > self.budget_bytes and self._lru:
-            key, value = self._lru.popitem(last=False)
-            self.bytes -= self._entry_bytes(key, value)
-            self.evictions += 1
-            if backend is not None:
-                backend._PK_CACHE.pop(key, None)
-
-    def _insert(self, key: bytes, value) -> None:
-        if key in self._lru:
+        encoded = _encoded(new_keys)
+        m = encoded.shape[0]
+        if not m:
             return
-        self._lru[key] = value
-        self.bytes += self._entry_bytes(key, value)
-        if self.mirror_backend:
-            backend = self._backend_cache()
-            backend._cache_put(backend._PK_CACHE, key, value)
-        self._evict_to_budget()
-
-    def warm(self, pubkeys: Sequence[bytes]) -> Tuple[int, int]:
-        """Ensure every (valid, deduplicated) key is decompressed and
-        resident; misses pay ONE vectorized codec batch. Returns the
-        (hits, misses) this call observed."""
-        seen = set()
-        order: List[bytes] = []
-        for pk in pubkeys:
-            pk = bytes(pk)
-            if pk not in seen:
-                seen.add(pk)
-                order.append(pk)
-        miss_keys: List[bytes] = []
-        hits = 0
-        for pk in order:
-            value = self._lru.get(pk)
-            if value is not None:
-                self._lru.move_to_end(pk)  # refresh recency
-                hits += 1
-                if self.mirror_backend:
-                    backend = self._backend_cache()
-                    if pk not in backend._PK_CACHE:
-                        backend._cache_put(backend._PK_CACHE, pk, value)
-            else:
-                miss_keys.append(pk)
-        if miss_keys:
-            from ..ops import codec
-
-            values = codec.pubkey_limbs_batch(miss_keys)
-            for pk, value in zip(miss_keys, values):
-                if isinstance(value, ValueError):
-                    self.rejected += 1
-                    continue
-                self._insert(pk, tuple(value))
-        self.hits += hits
-        self.misses += len(miss_keys)
+        host, valid = _decode(encoded, m)
+        lo, hi = self.n, self.n + m
+        cap = self.limbs.shape[0]
+        if hi > cap:
+            grown = _capacity(hi)
+            self.limbs = jnp.concatenate([self.limbs, jnp.zeros(
+                (grown - cap,) + self.limbs.shape[1:], dtype=jnp.uint32)])
+            self.valid = np.concatenate([self.valid,
+                                         np.zeros(grown - cap, dtype=bool)])
+        self.limbs = jax.jit(_put_rows)(self.limbs, jnp.asarray(host), lo)
+        self.valid[lo:hi] = valid
+        self.n = hi
         self._export_gauges()
-        return hits, len(miss_keys)
-
-    def get(self, pubkey: bytes):
-        """Decompressed (x, y) limb columns, warming on miss."""
-        pk = bytes(pubkey)
-        value = self._lru.get(pk)
-        if value is not None:
-            self._lru.move_to_end(pk)
-            self.hits += 1
-            self._export_gauges()
-            return value
-        self.warm([pk])
-        return self._lru.get(pk)
-
-    def hit_rate(self) -> float:
-        total = self.hits + self.misses
-        return (self.hits / total) if total else 0.0
 
     def _export_gauges(self) -> None:
         from ..ops import profiling
 
-        profiling.set_gauge("scale.pubkey_cache_hits", float(self.hits))
-        profiling.set_gauge("scale.pubkey_cache_misses", float(self.misses))
-        profiling.set_gauge("scale.pubkey_cache_bytes", float(self.bytes))
-        profiling.set_gauge("scale.pubkey_cache_evictions",
-                            float(self.evictions))
-        profiling.set_gauge("scale.pubkey_hit_rate", self.hit_rate())
+        profiling.set_gauge("scale.pubkey_table_keys", float(self.n))
+        profiling.set_gauge("scale.pubkey_table_bytes", float(self.nbytes))
+
+
+def _put_rows(limbs, rows, lo):
+    from jax import lax
+
+    return lax.dynamic_update_slice(limbs, rows, (lo, 0, 0))
+
+
+def _decode(encoded: np.ndarray, capacity: int):
+    """(capacity, 2, L) uint32 host limbs and (capacity,) validity of the
+    keys in ``encoded``, decoded in chunks over the host's cores."""
+    from ..obs import tracing
+    from ..ops import codec, fq
+
+    n = encoded.shape[0]
+    host = np.zeros((capacity, 2, fq.NUM_LIMBS), dtype=np.uint32)
+    valid = np.zeros(capacity, dtype=bool)
+    bounds = [(lo, min(lo + _CHUNK, n)) for lo in range(0, n, _CHUNK)]
+    route = codec.host_route()
+    # only the native kernel runs outside the interpreter lock
+    threads = (os.cpu_count() or 1) if route == "native" else 1
+    threads = max(1, min(threads, len(bounds)))
+
+    def one(span):
+        lo, hi = span
+        host[lo:hi], valid[lo:hi] = codec.pubkey_table_limbs(encoded[lo:hi])
+
+    with tracing.span("pubkeys.table_build", n=n, threads=threads):
+        with ThreadPoolExecutor(threads) as pool:
+            for f in [pool.submit(one, b) for b in bounds]:
+                f.result()
+    codec._note_route(route, n)
+    return host, valid

@@ -140,6 +140,40 @@ class Registry:
         """Compressed pubkeys for a committee's index column."""
         return [self.pubkey(int(i)) for i in indices]
 
+    def pubkey_column(self, lo: int = 0, hi: Optional[int] = None
+                      ) -> List[bytes]:
+        """Compressed pubkeys of validators ``lo .. hi - 1`` (the whole
+        registry by default), for the pubkey table. Consecutive secret keys
+        differ by 2^16, so after one scalar multiplication each key is the
+        last plus 2^16 G: one Jacobian addition a key and one batch
+        inversion for the column, where ``pubkey`` pays a scalar
+        multiplication a key. Equal to ``pubkeys(range(lo, hi))``."""
+        from ..ops import codec
+        from ..utils import bls12_381 as O
+        from ..utils.bls12_381 import P
+
+        hi = self.n_validators if hi is None else int(hi)
+        if not (0 <= lo <= hi <= self.n_validators):
+            raise IndexError(f"validator range [{lo}, {hi}) out of range")
+        if lo == hi:
+            return []
+        x0, y0 = O.ec_to_affine(O.ec_mul(O.G1_GEN, self.secret_key(lo)))
+        sx, sy = O.ec_to_affine(O.ec_mul(O.G1_GEN, 1 << 16))
+        step = (sx.n, sy.n, 1)
+        pts = [(x0.n, y0.n, 1)]
+        for _ in range(lo + 1, hi):
+            pts.append(codec._j1_add(pts[-1], step))
+        zinv = codec.int_batch_inverse([p[2] for p in pts])
+        out = []
+        for (X, Y, _), zi in zip(pts, zinv):
+            zi2 = zi * zi % P
+            x, y = X * zi2 % P, Y * zi2 * zi % P
+            enc = bytearray(x.to_bytes(48, "big"))
+            enc[0] |= O.FLAG_COMPRESSED | (O.FLAG_SIGN if y > (P - 1) // 2
+                                           else 0)
+            out.append(bytes(enc))
+        return out
+
     def iter_pubkeys(self, batch: int = 1024,
                      limit: Optional[int] = None
                      ) -> Iterator[Tuple[np.ndarray, List[bytes]]]:
@@ -226,16 +260,17 @@ class Registry:
                     + int(slot).to_bytes(8, "little")
                     + int(index).to_bytes(8, "little"))
 
-    def aggregate(self, slot: int, index: int,
-                  participation: float = 1.0) -> Tuple[List[bytes],
-                                                       bytes, bytes]:
-        """(pubkeys, message, aggregate signature) for one committee's
-        aggregate attestation. ``participation`` < 1 drops the TAIL of
-        the committee from the cover (a censored/partial aggregate —
-        still a VALID signature over the participating subset, which is
-        exactly what censorship looks like on the wire). The aggregate
-        signature is built as one sign by the summed secret key — the
-        same group element as aggregating per-validator signatures."""
+    def aggregate_members(self, slot: int, index: int,
+                          participation: float = 1.0
+                          ) -> Tuple[np.ndarray, bytes, bytes]:
+        """(participants' validator indices, message, aggregate signature)
+        for one committee's aggregate attestation. ``participation`` < 1
+        drops the TAIL of the committee from the cover (a censored/partial
+        aggregate — still a VALID signature over the participating subset,
+        which is exactly what censorship looks like on the wire). The
+        aggregate signature is built as one sign by the summed secret key
+        — the same group element as aggregating per-validator
+        signatures."""
         from ..utils import bls
         from ..utils.bls12_381 import R
 
@@ -245,4 +280,13 @@ class Registry:
         sks = [self.secret_key(int(i)) for i in members]
         message = self.attestation_message(slot, index)
         signature = bls.Sign(sum(sks) % R, message)
+        return members, message, signature
+
+    def aggregate(self, slot: int, index: int,
+                  participation: float = 1.0) -> Tuple[List[bytes],
+                                                       bytes, bytes]:
+        """``aggregate_members`` with the participants' compressed
+        pubkeys in place of their indices."""
+        members, message, signature = self.aggregate_members(
+            slot, index, participation)
         return self.pubkeys(members), message, signature

@@ -4,8 +4,10 @@ mainnet sibling).
 A small-but-mainnet-preset slot: the committee count comes from the
 REAL mainnet formula (get_committee_count_per_slot over the registry),
 only the validator count is reduced so the smoke fits a CI runner.
-Three traffic rounds over the same slot, each verified three ways —
-hierarchical (RLC slot fold), flat (per-committee finalization), and
+The whole registry's keys go into one ``PubkeyTable`` first. Three
+traffic rounds over the same slot, each verified three ways —
+hierarchical (RLC slot fold, keys gathered from the table by validator
+index), flat (per-committee finalization over compressed keys), and
 the pure-Python host oracle — with all three verdict vectors required
 bit-identical:
 
@@ -61,11 +63,12 @@ def main() -> int:
                  committees_per_slot=per_slot, fanout=fanout,
                  digest=reg.digest(sample=64))
 
-        plane = pubkeys.PubkeyPlane()
+        table = pubkeys.PubkeyTable.build(reg.pubkey_column())
 
         def identity(tag, items, report):
-            flat = hierarchy.verify_slot_flat(items)
-            oracle = hierarchy.verify_slot_oracle(items)
+            as_bytes = hierarchy.bytes_items(items, reg)
+            flat = hierarchy.verify_slot_flat(as_bytes)
+            oracle = hierarchy.verify_slot_oracle(as_bytes)
             hier = report.verdicts.tolist()
             rec.note("scale", "smoke_verdicts", round=tag, hier=hier,
                      flat=flat.tolist(), oracle=oracle.tolist(),
@@ -79,7 +82,7 @@ def main() -> int:
 
         # -- round 1: valid slot, ONE final exp for the whole fold ----------
         items = hierarchy.committee_items(reg, slot=0)
-        report = hierarchy.verify_slot(items, slot=0, plane=plane)
+        report = hierarchy.verify_slot(items, slot=0, table=table)
         hier = identity("valid", items, report)
         assert all(hier), f"valid slot rejected: {hier}"
         assert report.combines == 1 and report.bisections == 0, (
@@ -88,9 +91,6 @@ def main() -> int:
         assert report.final_exps_per_slot == 1.0, (
             f"final_exps_per_slot {report.final_exps_per_slot} != 1")
         assert report.attestations == fanout
-        assert plane.bytes <= plane.budget_bytes, (
-            f"pubkey plane over budget: {plane.bytes} > "
-            f"{plane.budget_bytes}")
         print(f"mainnet-smoke: valid slot OK — {per_slot} committees, "
               f"{report.attestations} attestations, "
               f"final_exps_per_slot={report.final_exps_per_slot:.0f}, "
@@ -99,10 +99,10 @@ def main() -> int:
         # -- round 2: censored aggregate — subset cover still verifies ------
         censored_ci, participation = 0, 0.75
         items_c = list(hierarchy.committee_items(reg, slot=0))
-        pks, msg, sig = reg.aggregate(0, censored_ci,
-                                      participation=participation)
-        items_c[censored_ci] = ("fast_aggregate", pks, msg, sig)
-        report_c = hierarchy.verify_slot(items_c, slot=0, plane=plane)
+        members, msg, sig = reg.aggregate_members(
+            0, censored_ci, participation=participation)
+        items_c[censored_ci] = ("fast_aggregate_indexed", members, msg, sig)
+        report_c = hierarchy.verify_slot(items_c, slot=0, table=table)
         hier_c = identity("censored", items_c, report_c)
         assert all(hier_c), f"uncensored cover rejected: {hier_c}"
         censored = fanout - report_c.attestations
@@ -117,7 +117,7 @@ def main() -> int:
         bad_ci = per_slot - 1
         items_b = list(hierarchy.committee_items(reg, slot=0))
         items_b[bad_ci] = hierarchy.corrupt_item(items_b[bad_ci])
-        report_b = hierarchy.verify_slot(items_b, slot=0, plane=plane)
+        report_b = hierarchy.verify_slot(items_b, slot=0, table=table)
         hier_b = identity("bad_committee", items_b, report_b)
         assert report_b.bad_committees == [bad_ci], (
             f"bisection localized {report_b.bad_committees}, "

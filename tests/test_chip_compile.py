@@ -3,8 +3,9 @@
 The TPU compiler is installed here and compiles for a chip that is
 described, not attached: what it refuses (VMEM, tiling, Mosaic lowering)
 is found without chip time. Covers the folded Miller program at the
-K=512 bucket, the RLC combine chunk, the hard-part row, and both Pallas
-kernels at the widths the VM step feeds them — with x64 on, as the VM
+K=512 bucket, the RLC combine chunk, the hard-part row, the index
+path's key gather and input placement, and both Pallas kernels at the
+widths the VM step feeds them — with x64 on, as the VM
 runs. Nothing executes, so nothing here is a chip result.
 
 The topology is described inside a fixture (never at import): only one
@@ -105,3 +106,23 @@ def test_pallas_fused_step_kernel_compiles_for_v5e(one_chip):
     compiled = jax.jit(pallas_step._fused_call(mm, ml, False)).lower(
         mul, mul, lin, lin, lin).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pubkey_gather_and_input_placement_compile_for_v5e(one_chip):
+    """The index path's two modules at a 1,048,576-key table and one
+    slot's 32 rows: the gather reads the table at its compact size."""
+    program, fold = bls_backend._program("miller_product", 512)
+    k = 512
+    table = _sds((1 << 20, 2, fq.NUM_LIMBS), jnp.uint32, one_chip)
+    idx = _sds((ROWS, fold, k), jnp.int32, one_chip)
+    gather = jax.jit(bls_backend.pubkey_gather).lower(table, idx).compile()
+    mem = gather.memory_analysis()
+    if mem is not None:  # (x, y) x 15 limbs x 4 bytes a key, no padding
+        assert mem.argument_size_in_bytes < 1.1 * (1 << 20) * 120
+    n_dev = fold * k * 3
+    n_host = len(program.input_names) - n_dev
+    jax.jit(vm.vm_place_inputs).lower(
+        _sds((ROWS, n_host, fq.NUM_LIMBS), jnp.uint32, one_chip),
+        _sds((ROWS, n_dev, fq.NUM_LIMBS), jnp.uint32, one_chip),
+        _sds((n_host,), jnp.int32, one_chip),
+        _sds((n_dev,), jnp.int32, one_chip)).compile()

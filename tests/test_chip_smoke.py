@@ -73,7 +73,8 @@ def test_slot_items_from_workers_match_committee_items(tiny_slot):
 
     reg, items = tiny_slot
     assert chip_smoke.slot_items(reg, procs=2) == items
-    assert items == hierarchy.committee_items(reg, chip_smoke.SLOT)
+    assert items == hierarchy.bytes_items(
+        hierarchy.committee_items(reg, chip_smoke.SLOT), reg)
 
 
 def test_switchboard_phase_agrees_with_oracle():

@@ -83,7 +83,7 @@ def test_emitted_labels_were_actually_found():
                      "vm.analysis_hazards", "vm.analysis_max_live",
                      "hist.families", "flight.dropped", "flight.events",
                      "slo.ok", "bls.vm_cache_pruned_bytes",
-                     "scale.final_exps_per_slot", "scale.pubkey_hit_rate"):
+                     "scale.final_exps_per_slot", "scale.pubkey_table_keys"):
         assert expected in found, f"label scan lost {expected}"
 
 
@@ -126,10 +126,10 @@ def test_merkle_gauge_family_is_complete():
 
 def test_scale_gauge_family_is_complete():
     # the mainnet workload plane (ISSUE 20): every scale.* gauge the
-    # registry / pubkey plane / hierarchy fold / fleet routing emit must
+    # registry / pubkey table / hierarchy fold / fleet routing emit must
     # be registered and every registered scale.* gauge must have an
-    # emission site — the million-validator replay's numbers (pubkey hit
-    # rate, final exps per slot, affinity moves) can never silently
+    # emission site — the million-validator replay's numbers (table
+    # size, final exps per slot, affinity moves) can never silently
     # orphan the README table or a scrape rule
     emitted = {label for label in _emitted_labels()
                if label.startswith("scale.")}

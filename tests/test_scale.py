@@ -1,14 +1,18 @@
 """Tier-1 coverage for the mainnet-scale workload plane (ISSUE 20):
 registry determinism + spec-shuffle equivalence, lazy iteration memory
 bounds, committee-affinity routing, and the hierarchical verify
-path's accounting. Crypto is kept to a handful of tiny keys so the
-whole module stays inside the tier-1 budget; the pubkey-plane LRU
-has its own module, test_scale_pubkeys.py."""
+path's accounting and its index path (keys gathered by validator index
+from the pubkey table) against the bytes path and the oracle. Crypto is
+kept to a handful of tiny keys so the whole module stays inside the
+tier-1 budget; the pubkey table has its own module,
+test_scale_pubkeys.py."""
 import hashlib
 import tracemalloc
 
+import numpy as np
 import pytest
 
+from consensus_specs_tpu.obs import tracing
 from consensus_specs_tpu.scale import hierarchy, pubkeys, registry, routing
 from consensus_specs_tpu.scale.registry import Registry, shuffle_batch
 
@@ -176,27 +180,121 @@ def test_verify_slot_accounting_and_bad_committee_localization():
     reg = Registry(64, seed=13, slots_per_epoch=8, target_size=2,
                    shuffle_rounds=4)
     assert reg.committees_per_slot() == 4
+    table = pubkeys.PubkeyTable.build(reg.pubkey_column())
     items = hierarchy.committee_items(reg, slot=3)
+    assert {it[0] for it in items} == {"fast_aggregate_indexed"}
     bad_ci = 2
     items[bad_ci] = hierarchy.corrupt_item(items[bad_ci])
 
-    plane = pubkeys.PubkeyPlane(budget_bytes=1 << 30, mirror_backend=True)
-    report = hierarchy.verify_slot(items, slot=3, plane=plane)
+    report = hierarchy.verify_slot(items, slot=3, table=table)
     assert report.committees == 4
     assert report.attestations == sum(len(it[1]) for it in items)
     assert report.bad_committees == [bad_ci]
     assert report.bisections >= 1  # the slot root failed and split
-    assert report.pubkey_misses > 0 and report.pubkey_hits == 0
 
-    flat = hierarchy.verify_slot_flat(items)
-    oracle = hierarchy.verify_slot_oracle(items)
+    as_bytes = hierarchy.bytes_items(items, reg)
+    flat = hierarchy.verify_slot_flat(as_bytes)
+    oracle = hierarchy.verify_slot_oracle(as_bytes)
     assert report.verdicts.tolist() == flat.tolist() == oracle.tolist()
 
-    # all-valid slot: ONE combine, ONE final exp, no bisection; the
-    # pubkey plane serves the whole slot from residency
+    # all-valid slot: ONE combine, ONE final exp, no bisection, and no
+    # key decoded on the host
     good = hierarchy.committee_items(reg, slot=3)
-    report2 = hierarchy.verify_slot(good, slot=3, plane=plane)
+    report2 = hierarchy.verify_slot(good, slot=3, table=table)
     assert report2.all_valid and not report2.bad_committees
     assert report2.combines == 1 and report2.bisections == 0
     assert report2.final_exps_per_slot == 1.0
-    assert report2.pubkey_hits > 0 and report2.pubkey_misses == 0
+    record = tracing.flush_records()[-1]
+    assert record["host_key_decodes"] == 0
+    assert record["keys_gathered"] == report2.attestations
+
+
+@pytest.fixture(scope="module")
+def indexed_slot():
+    """Eight committees of four over a 256-validator registry in which
+    one validator of another slot holds a key outside G1, and one slot's
+    checks: valid, partial, corrupted, an index outside the table, an
+    empty column, a committee that also covers the bad key, then two
+    valid."""
+    reg = Registry(256, seed=17, slots_per_epoch=8, target_size=4,
+                   shuffle_rounds=4)
+    items = hierarchy.committee_items(reg, slot=5)
+    assert len(items) == 8
+    bad = min(set(range(256)) - {int(i) for it in items for i in it[1]})
+    keys = reg.pubkey_column()
+    keys[bad] = _off_subgroup_key()
+    table = pubkeys.PubkeyTable.build(keys)
+    members, msg, sig = reg.aggregate_members(5, 1, participation=0.5)
+    items[1] = ("fast_aggregate_indexed", members, msg, sig)
+    items[2] = hierarchy.corrupt_item(items[2])
+    kind, cols, msg, sig = items[3]
+    items[3] = (kind, np.append(cols, 256), msg, sig)
+    items[4] = (kind, np.zeros(0, dtype=np.uint64), items[4][2], items[4][3])
+    kind, cols, msg, sig = items[5]
+    items[5] = (kind, np.append(cols, bad), msg, sig)
+    return reg, keys, table, items
+
+
+def _off_subgroup_key():
+    from consensus_specs_tpu.utils import bls12_381 as O
+
+    x = 1
+    while True:
+        y = O.fq_sqrt((x ** 3 + 4) % O.P)
+        if y is not None and not O.is_in_g1_subgroup(
+                O.ec_from_affine((O.Fq(x), O.Fq(y)))):
+            return O.g1_to_bytes((O.Fq(x), O.Fq(y)))
+        x += 1
+
+
+def test_index_path_equals_bytes_path_and_oracle(indexed_slot):
+    from consensus_specs_tpu.ops import bls_backend
+
+    reg, keys, table, items = indexed_slot
+    report = hierarchy.verify_slot(items, slot=5, table=table)
+    want = [True, True, False, False, False, False, True, True]
+    assert report.verdicts.tolist() == want
+    assert report.bad_committees == [2, 3, 4, 5]
+    # the same checks over compressed keys (the index outside the table
+    # has no key to give: it is left out of the bytes batch)
+    as_bytes = [("fast_aggregate", [keys[int(i)] for i in cols], msg, sig)
+                for _, cols, msg, sig in items[:3] + items[4:]]
+    byte_path = bls_backend.batch_verify_rlc(as_bytes)
+    oracle = hierarchy.verify_slot_oracle(as_bytes)
+    assert byte_path.tolist() == oracle.tolist() == want[:3] + want[4:]
+
+
+def test_index_path_traces_the_gather(indexed_slot):
+    from consensus_specs_tpu.ops import bls_backend
+
+    _, keys, table, items = indexed_slot
+    good = [items[0], items[6], items[7]]
+    bls_backend.batch_verify_rlc(good, table=table)
+    record = tracing.flush_records()[-1]
+    assert record["kind"] == "rlc" and record["items"] == 3
+    assert record["spans"]["pubkeys.gather"] > 0
+    assert record["spans"]["rlc.prep"] >= record["spans"]["pubkeys.gather"]
+    assert record["keys_gathered"] == sum(len(it[1]) for it in good)
+    assert record["host_key_decodes"] == 0
+    # the bytes path counts its cold keys as host decodes
+    cold = [("fast_aggregate", [keys[int(i)] for i in it[1]], it[2], it[3])
+            for it in good]
+    for _, pks, _, _ in cold:
+        for pk in pks:
+            bls_backend._PK_CACHE.pop(pk, None)
+    bls_backend.batch_verify_rlc(cold)
+    record = tracing.flush_records()[-1]
+    assert record["host_key_decodes"] == record["items"] * 4
+    assert record["keys_gathered"] == 0
+
+
+def test_index_path_refuses_a_mesh_or_a_missing_table(indexed_slot):
+    from consensus_specs_tpu.ops import bls_backend
+    from consensus_specs_tpu.utils.jax_env import get_mesh
+
+    _, _, table, items = indexed_slot
+    with pytest.raises(ValueError, match="table="):
+        bls_backend.batch_verify_rlc(items[:2])
+    with pytest.raises(ValueError, match="one device"):
+        bls_backend.batch_verify_rlc(items[:2], table=table,
+                                     mesh=get_mesh("2"))
