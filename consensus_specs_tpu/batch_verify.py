@@ -222,16 +222,18 @@ class SignatureCollector:
         With ``service`` (a serve.VerificationService), the unique checks
         ride the streaming plane — micro-batched with whatever else the
         service is carrying, cached, deduped against other submitters.
-        Otherwise checks are grouped by (kind, K-bucket) so each device
-        batch pads to its own committee-size bucket (ops/bls_backend.py
-        _K_BUCKETS). With ``mesh``, each bucket's batch axis is sharded
-        over the mesh (SURVEY §2.7/P1 — the committee axis is the DP
-        axis).
+        Otherwise, without ``rlc``, checks are grouped by (kind,
+        K-bucket) so each device batch pads to its own committee-size
+        bucket (ops/bls_backend.py _K_BUCKETS). With ``mesh``, each batch
+        axis is sharded over the mesh (SURVEY §2.7/P1 — the committee axis
+        is the DP axis).
 
         ``rlc=True`` resolves the whole span through the backend's
         random-linear-combination path (``batch_verify_rlc``): ONE final
         exponentiation for all recorded checks instead of one per check,
-        with bisection recovering exact per-item verdicts on failure —
+        and one Miller program for all fast_aggregate checks instead of
+        one per bucket (aggregate checks keep their buckets), with
+        bisection recovering exact per-item verdicts on failure —
         the epoch-replay bench opts in via CONSENSUS_SPECS_TPU_RLC. Kept
         opt-in here (unlike the serve plane's default-on) so correctness
         cross-checks against flush_oracle() keep exercising the per-item

@@ -645,7 +645,8 @@ def flush_records() -> List[Dict]:
     """Every retained flush record, oldest first. An ``rlc`` record:
     ``id``, ``items``, ``seconds`` (the whole ``batch_verify_rlc`` call),
     ``spans`` (span name -> summed inclusive seconds inside the call),
-    ``combines``, ``bisections``, ``final_exps``, ``keys_gathered`` (keys
+    ``combines``, ``bisections``, ``final_exps``, ``miller_launches``
+    (PROG A programs executed), ``keys_gathered`` (keys
     gathered from the pubkey table on the device), ``host_key_decodes``
     (keys decompressed on the host) and ``first`` (each ``vm[...]`` label
     it executed first in this process, mapped to the program kind). A ``serve``
@@ -674,8 +675,8 @@ def rlc_record(items: int):
     to the ring when the flush ends, raising or not. Yields the record."""
     rec = {"kind": "rlc", "id": next_flush_id(), "items": int(items),
            "seconds": 0.0, "spans": {}, "combines": 0, "bisections": 0,
-           "final_exps": 0, "keys_gathered": 0, "host_key_decodes": 0,
-           "first": {}}
+           "final_exps": 0, "miller_launches": 0, "keys_gathered": 0,
+           "host_key_decodes": 0, "first": {}}
     _local.record = rec
     t0 = time.perf_counter()
     try:

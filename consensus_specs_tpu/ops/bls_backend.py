@@ -1207,6 +1207,21 @@ def reset_rlc_stats() -> None:
     _export_rlc_gauges()
 
 
+@functools.lru_cache(maxsize=32)
+def _filler_stack(k: int, fold: int, rows: int) -> np.ndarray:
+    """The miller_product input stack, (rows, fold * (3k + 8), L) uint32,
+    with every item at its filler values. The program's inputs run item
+    by item: ``k`` key lanes (x, y, z each; infinity is 0:1:0), then the
+    message and the signature (the G2 generator). Read-only: a flush
+    copies it and writes its live items over the copy."""
+    item = np.zeros((3 * k + 8, fq.NUM_LIMBS), dtype=np.uint32)
+    item[1:3 * k:3] = _ONE_LIMBS
+    item[3 * k:] = np.concatenate([_G2GEN_LIMBS, _G2GEN_LIMBS])
+    st = np.tile(item, (rows, fold, 1))
+    st.flags.writeable = False
+    return st
+
+
 def _miller_fast_aggregate(
     pubkey_sets, messages, signatures, mesh=None
 ) -> Tuple[Optional[dict], "_FoldLayout", np.ndarray]:
@@ -1214,7 +1229,9 @@ def _miller_fast_aggregate(
     aggregate-and-Miller program. Returns (out, lay, precheck); ``out`` is
     None when no item survived host prep (then only precheck matters).
     Split out so the RLC combine path (batch_verify_rlc) can share the
-    Miller stage and swap just the finalization."""
+    Miller stage and swap just the finalization. The program's K is the
+    largest set's bucket; smaller sets leave their spare key lanes at
+    infinity, which adds aggregation lanes but no Miller loop."""
     n = len(pubkey_sets)
     max_k = max((len(pks) for pks in pubkey_sets), default=1)
     k = _k_bucket(max(1, max_k))
@@ -1222,26 +1239,18 @@ def _miller_fast_aggregate(
     with tracing.span("rlc.prep", group=group, n=n):
         L = fq.NUM_LIMBS
         lay = _FoldLayout("miller_product", k, n, mesh)
-        nb = lay.nb
         prewarm_host_caches(
             [bytes(m) for m in messages],
             [bytes(s) for s in signatures],
             [bytes(pk) for pks in pubkey_sets for pk in pks],
         )
 
-        # stacked staging arrays (vectorized — the per-name dict assignment
-        # loop was ~1.5 s of host time at epoch scale); inactive-lane
-        # fillers: infinity pubkeys (0:1:0), generator G2 points
-        precheck = np.zeros(nb, dtype=bool)
-        pk_x = np.zeros((nb, k, L), dtype=np.uint64)
-        pk_y = np.zeros((nb, k, L), dtype=np.uint64)
-        pk_y[:] = _INF_G1[1]
-        pk_z = np.zeros((nb, k, L), dtype=np.uint64)
-        hm = np.zeros((nb, 4, L), dtype=np.uint64)
-        hm[:] = _G2GEN_LIMBS
-        sg = np.zeros((nb, 4, L), dtype=np.uint64)
-        sg[:] = _G2GEN_LIMBS
-
+        # the input stack itself: each live item overwrites its own key
+        # lanes, message and signature in a copy of the filler stack, so
+        # no per-name array or stacking pass scales with K
+        precheck = np.zeros(lay.nb, dtype=bool)
+        stacked = _filler_stack(k, lay.fold, lay.rows).copy()
+        slots = stacked.reshape(lay.nb, 3 * k + 8, L)
         for i, (pks, msg, sig) in enumerate(
             zip(pubkey_sets, messages, signatures)
         ):
@@ -1254,24 +1263,20 @@ def _miller_fast_aggregate(
             except Exception:
                 continue
             m = len(enc)
-            pk_x[i, :m] = [e[0] for e in enc]
-            pk_y[i, :m] = [e[1] for e in enc]
-            pk_z[i, :m] = _ONE_LIMBS
-            hm[i] = h
-            sg[i] = s
+            lanes = slots[i, :3 * m].reshape(m, 3, L)
+            lanes[:, 0] = [e[0] for e in enc]
+            lanes[:, 1] = [e[1] for e in enc]
+            lanes[:, 2] = _ONE_LIMBS
+            slots[i, 3 * k:3 * k + 4] = h
+            slots[i, 3 * k + 4:] = s
             precheck[i] = True
 
         if not precheck.any():
             return None, lay, precheck
-
-        ins = {}
-        lay.scatter(ins, pk_x, lambda j: f"pk{j}.x")
-        lay.scatter(ins, pk_y, lambda j: f"pk{j}.y")
-        lay.scatter(ins, pk_z, lambda j: f"pk{j}.z")
-        lay.scatter(ins, hm, lambda ci: f"h.{_G2_COMPS[ci]}")
-        lay.scatter(ins, sg, lambda ci: f"sig.{_G2_COMPS[ci]}")
     with tracing.span("rlc.miller", group=group):
-        out = vm.execute(lay.program, ins, batch_shape=(lay.rows,), mesh=mesh)
+        tracing.count("miller_launches")
+        out = vm.execute(lay.program, stacked, batch_shape=(lay.rows,),
+                         mesh=mesh)
     return out, lay, precheck
 
 
@@ -1370,6 +1375,7 @@ def _miller_fast_aggregate_indexed(
         lay.scatter(ins, hm, lambda ci: f"h.{_G2_COMPS[ci]}")
         lay.scatter(ins, sg, lambda ci: f"sig.{_G2_COMPS[ci]}")
     with tracing.span("rlc.miller", group=group):
+        tracing.count("miller_launches")
         out = vm.execute(lay.program, ins, batch_shape=(lay.rows,),
                          device_inputs=(names, lanes))
     return out, lay, precheck
@@ -1460,6 +1466,7 @@ def _miller_aggregate(
         lay.scatter(ins, hm, lambda j, ci: f"h{j}.{_G2_COMPS[ci]}")
         lay.scatter(ins, sg, lambda ci: f"sig.{_G2_COMPS[ci]}")
     with tracing.span("rlc.miller", group=group):
+        tracing.count("miller_launches")
         out = vm.execute(lay.program, ins, batch_shape=(lay.rows,), mesh=mesh)
     return out, lay, precheck
 
@@ -1702,10 +1709,12 @@ def batch_verify_rlc(items, mesh=None, rng=None, table=None) -> np.ndarray:
     (a ``scale.pubkeys.PubkeyTable``, whose keys are gathered on the
     device; an empty column, an index outside the table or a key that
     failed KeyValidate gives False; one device only, no ``mesh``).
-    Items are grouped by
-    (kind, K-bucket) for PROG A exactly like SignatureCollector.flush,
-    and the Miller outputs feed the combine program as raw loose limbs
-    (no per-item host canonicalization or easy part).
+    PROG A runs once per kind for the two fast_aggregate kinds, at the
+    bucket of the kind's largest K: their K counts key lanes, which pad
+    with infinity at no extra Miller loop. 'aggregate' items, whose K
+    counts pairings, run once per K-bucket. The Miller outputs feed the
+    combine program as raw loose limbs (no per-item host
+    canonicalization or easy part).
 
     Soundness (Schwartz-Zippel): the final-exp images f_i^E live in the
     order-r subgroup, r prime ~2^255. The combined check is
@@ -1727,7 +1736,7 @@ def batch_verify_rlc(items, mesh=None, rng=None, table=None) -> np.ndarray:
 
     Each call appends one ``rlc`` flush record (obs/tracing.py) holding
     its seconds, its stage spans and the combines, bisections and final
-    exponentiations it ran."""
+    exponentiations it ran, and ``miller_launches``: its PROG A runs."""
     items = list(items)
     n = len(items)
     _count_call("batch_verify_rlc", n)
@@ -1745,6 +1754,7 @@ def _batch_verify_rlc(items, mesh, rng, table) -> np.ndarray:
     n = len(items)
     verdict = np.zeros(n, dtype=bool)
 
+    # one PROG A group per kind; 'aggregate' also splits by K-bucket
     groups: Dict[Tuple[str, int], List[int]] = {}
     for i, (kind, pks, _msgs, _sig) in enumerate(items):
         if kind not in _KINDS:
@@ -1755,9 +1765,11 @@ def _batch_verify_rlc(items, mesh, rng, table) -> np.ndarray:
             raise ValueError(
                 "fast_aggregate_indexed items run on one device: the pubkey "
                 "table has no mesh layout; verify them without mesh=")
-        groups.setdefault((kind, _k_bucket(max(1, len(pks)))), []).append(i)
+        bucket = _k_bucket(max(1, len(pks)))
+        key = (kind, bucket if kind == "aggregate" else 0)
+        groups.setdefault(key, []).append(i)
 
-    # PROG A per (kind, bucket) group; gather surviving candidates' Miller
+    # PROG A per group; gather surviving candidates' Miller
     # outputs as raw limb rows (host precheck / infinite-aggregate
     # failures are False without any finalization work)
     cand_idx: List[int] = []

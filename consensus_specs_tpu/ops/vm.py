@@ -26,7 +26,7 @@ multiplies, so lazy reduction is handled statically at assembly time.
 import os
 import time
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -800,6 +800,19 @@ class Program:
             out[..., idx, :] = v
         return out
 
+    def check_stack(self, stacked: np.ndarray, batch_shape) -> np.ndarray:
+        """An input stack built by the caller, in input_names order: its
+        shape and the canonical bound that ``stack_inputs`` enforces."""
+        want = tuple(batch_shape) + (len(self.input_names), fq.NUM_LIMBS)
+        if stacked.shape != want or stacked.dtype != np.uint32:
+            raise ValueError(f"input stack is {stacked.dtype}{stacked.shape}, "
+                             f"program wants uint32{want}")
+        if stacked.size and int(stacked.max()) >> fq.LIMB_BITS:
+            raise ValueError(
+                f"input stack has limbs >= 2^{fq.LIMB_BITS}: program "
+                "inputs must be canonical Montgomery residues")
+        return stacked
+
     def place_inputs(self, values: Dict[str, np.ndarray], device_inputs,
                      batch_shape):
         """The (batch..., n_inputs, L) uint32 input stack on the device:
@@ -1031,15 +1044,19 @@ def _vm_run_for_mesh(mesh, pallas_mode="0", name="vm_program"):
     return jax.jit(body)
 
 
-def execute(program: Program, inputs: Dict[str, np.ndarray], batch_shape=(),
+def execute(program: Program,
+            inputs: Union[Dict[str, np.ndarray], np.ndarray], batch_shape=(),
             mesh=None, device_inputs=None) -> Dict[str, np.ndarray]:
     """Run an assembled program. Input arrays must be canonical Montgomery
-    limb arrays of shape batch_shape + (NUM_LIMBS,). Returns named outputs
-    (loose, bounded < 2^382). With ``mesh``, the leading batch axis is
-    sharded over ALL the mesh's axes (batch_shape[0] must divide by the
-    total device count). ``device_inputs`` (names, array): inputs already
-    on the device, as a batch_shape + (len(names), NUM_LIMBS) uint32 array
-    (the pubkey table's gathered keys); ``inputs`` then holds the rest.
+    limb arrays of shape batch_shape + (NUM_LIMBS,), or ``inputs`` is the
+    input stack itself (a batch_shape + (n_inputs, NUM_LIMBS) uint32 array
+    in input_names order, as ``stack_inputs`` builds it). Returns named
+    outputs (loose, bounded < 2^382). With ``mesh``, the leading batch
+    axis is sharded over ALL the mesh's axes (batch_shape[0] must divide
+    by the total device count). ``device_inputs`` (names, array): inputs
+    already on the device, as a batch_shape + (len(names), NUM_LIMBS)
+    uint32 array (the pubkey table's gathered keys); ``inputs`` then holds
+    the rest.
     The program and its executable are the same either way.
 
     Execution backend (CONSENSUS_SPECS_TPU_VM_EXEC): ``interp`` runs the
@@ -1056,7 +1073,9 @@ def execute(program: Program, inputs: Dict[str, np.ndarray], batch_shape=(),
     from . import profiling, vm_compile
     from ..obs import tracing
 
-    if device_inputs is None:
+    if isinstance(inputs, np.ndarray):
+        stacked = program.check_stack(inputs, batch_shape)
+    elif device_inputs is None:
         stacked = program.stack_inputs(inputs, tuple(batch_shape))
     else:
         stacked = program.place_inputs(inputs, device_inputs,

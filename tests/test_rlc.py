@@ -16,6 +16,7 @@ import random
 import numpy as np
 import pytest
 
+from consensus_specs_tpu.obs import tracing
 from consensus_specs_tpu.ops import bls_backend as bb
 from consensus_specs_tpu.ops import fq
 from consensus_specs_tpu.utils import bls
@@ -135,6 +136,73 @@ def test_rlc_mixed_kinds_one_combine(monkeypatch):
     # both kinds' Miller outputs merged into ONE combined check
     assert bb.RLC_STATS["combines"] - before["combines"] == 1
     assert bb.RLC_STATS["final_exps"] - before["final_exps"] == 1
+
+
+# -- one Miller launch per kind ----------------------------------------------
+
+
+def _mixed_k_committees():
+    """fast_aggregate items of K 1, 2, 4 and 2, the K=4 one signed over
+    another message."""
+    return [_committee(51, k=1), _committee(52, k=2),
+            _committee(53, k=4, good=False), _committee(54, k=2)]
+
+
+def _alone(verify, items) -> list:
+    """Each item verified by ``verify`` in a batch of its own."""
+    return [bool(verify([it[1]], [it[2]], [it[3]])[0]) for it in items]
+
+
+def test_rlc_fast_aggregate_mixed_k_one_miller_launch(monkeypatch):
+    """Items of three K-buckets share one PROG A launch at the largest
+    bucket; the bad one is found by bisection and every verdict equals
+    the per-item path's."""
+    monkeypatch.setenv("CONSENSUS_SPECS_TPU_RLC_CHUNK", "2")
+    items = _mixed_k_committees()
+    got = bb.batch_verify_rlc(items, rng=random.Random(5))
+    record = tracing.flush_records()[-1]
+    assert record["miller_launches"] == 1
+    assert record["bisections"] >= 1
+    assert got.tolist() == _alone(bb.batch_fast_aggregate_verify, items)
+    assert got.tolist() == [True, True, False, True]
+
+
+def test_rlc_indexed_mixed_committee_sizes_one_miller_launch(monkeypatch):
+    """The index path's committees of sizes 1, 2, 4 and 2 (one signed over
+    another message) in one PROG A launch, with the bytes path's per-item
+    verdicts."""
+    from consensus_specs_tpu.scale.pubkeys import PubkeyTable
+
+    monkeypatch.setenv("CONSENSUS_SPECS_TPU_RLC_CHUNK", "2")
+    items = _mixed_k_committees()
+    keys = [pk for it in items for pk in it[1]]
+    table = PubkeyTable.build(keys)
+    indexed, start = [], 0
+    for _, pks, msg, sig in items:
+        cols = np.arange(start, start + len(pks), dtype=np.int64)
+        indexed.append(("fast_aggregate_indexed", cols, msg, sig))
+        start += len(pks)
+    got = bb.batch_verify_rlc(indexed, rng=random.Random(6), table=table)
+    record = tracing.flush_records()[-1]
+    assert record["miller_launches"] == 1
+    assert record["bisections"] >= 1
+    assert record["keys_gathered"] == len(keys)
+    assert got.tolist() == _alone(bb.batch_fast_aggregate_verify, items)
+
+
+def test_rlc_aggregate_kind_keeps_one_launch_per_bucket(monkeypatch):
+    """'aggregate' K counts pairings, so its buckets stay apart: K 1 and
+    K 2 items run two PROG A launches, beside one for a fast_aggregate."""
+    monkeypatch.setenv("CONSENSUS_SPECS_TPU_RLC_CHUNK", "2")
+    aggs = [_aggregate_item(55, k=1), _aggregate_item(56, k=2),
+            _aggregate_item(57, k=2, good=False)]
+    items = aggs + [_committee(58, k=1)]
+    got = bb.batch_verify_rlc(items, rng=random.Random(7))
+    record = tracing.flush_records()[-1]
+    assert record["miller_launches"] == 3
+    assert got.tolist() == (_alone(bb.batch_aggregate_verify, aggs)
+                            + [True])
+    assert got.tolist() == [True, True, False, True]
 
 
 def test_rlc_empty_and_bad_kind():

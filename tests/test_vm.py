@@ -33,6 +33,32 @@ def test_alu_chain():
     assert got == (((av * bv + cv - dv) * 2 * av) - bv) % O.P
 
 
+def test_execute_takes_a_prebuilt_input_stack():
+    """A caller-built input stack runs like the named inputs it stacks;
+    a wrong shape or a non-canonical limb is refused."""
+    import numpy as np
+
+    prog = vm.Prog()
+    a, b = prog.inp("a"), prog.inp("b")
+    prog.out(a * b - a, "r")
+    pr = prog.assemble(**BUCKET)
+    vals = [(rng.randrange(O.P), rng.randrange(O.P)) for _ in range(2)]
+    ins = {n: np.stack([fq.to_mont_int(v[i]) for v in vals])
+           for i, n in enumerate("ab")}
+    stacked = pr.stack_inputs(ins, (2,))
+    by_name = vm.execute(pr, ins, batch_shape=(2,))["r"]
+    by_stack = vm.execute(pr, stacked, batch_shape=(2,))["r"]
+    assert np.array_equal(by_name, by_stack)
+    assert [fq.from_mont_limbs(r) for r in by_stack] == [
+        (x * y - x) % O.P for x, y in vals]
+    with pytest.raises(ValueError, match="program wants"):
+        vm.execute(pr, stacked, batch_shape=(1, 2))
+    bad = stacked.copy()
+    bad[1, 0, 3] = 1 << fq.LIMB_BITS
+    with pytest.raises(ValueError, match="canonical"):
+        vm.execute(pr, bad, batch_shape=(2,))
+
+
 def test_auto_compress_long_chains():
     # force magnitudes past the lazy-reduction bounds: deep add/sub chains
     prog = vm.Prog()
