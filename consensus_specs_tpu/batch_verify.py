@@ -233,8 +233,7 @@ class SignatureCollector:
         exponentiation for all recorded checks instead of one per check,
         and one Miller program for all fast_aggregate checks instead of
         one per bucket (aggregate checks keep their buckets), with
-        bisection recovering exact per-item verdicts on failure —
-        the epoch-replay bench opts in via CONSENSUS_SPECS_TPU_RLC. Kept
+        bisection recovering exact per-item verdicts on failure. Kept
         opt-in here (unlike the serve plane's default-on) so correctness
         cross-checks against flush_oracle() keep exercising the per-item
         device path."""

@@ -602,9 +602,9 @@ class HeadService:
 
     def import_block_unchecked(self, block, state=None,
                                resolve: bool = False) -> None:
-        """Replay/bench ingress: register a block WITHOUT running the state
-        transition (the synthetic fork replays in ``bench/head_replay.py``
-        build trees whose states are crafted, not computed). Never use on
+        """Replay ingress: register a block WITHOUT running the state
+        transition (the simnet's nodes, ``sim/node.py``, import trees
+        whose states are crafted, not computed). Never use on
         a live store — ``on_block`` is the validated path. ``resolve``
         additionally retries the deferred gossip this arrival can resolve
         and sweeps (a block arrival on the validated path always does);

@@ -24,13 +24,14 @@ store (``obs/timeseries.py``) then samples into history:
   its dependencies);
 - **rollback_rate** — speculative batches reverted this slot;
 - **unexplained_reorgs** — cumulative reorgs observed OUTSIDE windows
-  the caller declared disruption for (``expect_reorgs=``): the soak's
-  "zero unexplained reorgs" gate reads this.
+  the caller declared disruption for (``expect_reorgs=``): the
+  "zero unexplained reorgs" bound of ``evaluate_gate`` reads this.
 
 ``observe_slot`` is cheap (counter reads + two dict sums), so calling it
 every simulated slot for thousands of slots is free relative to the
-slot's own processing. ``summary()`` + ``evaluate_gate()`` produce the
-"HEALTH DIVERGED" state ``tools/bench_compare.py`` gates on.
+slot's own processing (``sim/runner.run_scenario``'s ``slot_hook`` is
+the per-slot sampling point). ``summary()`` + ``evaluate_gate()`` turn a
+horizon into a pass/fail verdict with reasons.
 """
 from collections import deque
 from typing import Dict, List, Optional
@@ -51,7 +52,7 @@ GAUGE_LABELS = (
     "health.unexplained_reorgs",
 )
 
-# gate defaults (the soak's acceptance thresholds; scenarios with
+# gate defaults (long-horizon acceptance thresholds; scenarios with
 # declared disruption pass explicit bounds)
 DEFAULT_PARTICIPATION_FLOOR = 0.60
 DEFAULT_FINALITY_LAG_MAX_SLOTS = 64

@@ -3,8 +3,8 @@ routing outcomes, and apply-batch latency.
 
 Counters live on the owning :class:`HeadService`; the derived values
 export through ``ops/profiling`` (the ``chain.*`` family in
-``obs/registry.py``) so ``/metrics`` scrapes and bench JSON lines carry
-the chain numbers the same way they carry the serve plane's.
+``obs/registry.py``) so ``/metrics`` scrapes carry the chain numbers
+the same way they carry the serve plane's.
 
 Multi-instance runs (the ``sim/`` plane drives N ``HeadService``
 instances in one process) pass ``node=``: every label then exports in

@@ -13,8 +13,8 @@ The mode knob (``CONSENSUS_SPECS_TPU_MERKLE``):
 - ``auto``   (default) — native batching wherever the shared library is
   available, byte-identical to the python path by construction.
 - ``native`` — demand the native path; a missing library still falls
-  back to hashlib per call but counts ``merkle.fallbacks`` so the bench
-  gate can see the degradation.
+  back to hashlib per call but counts ``merkle.fallbacks`` so the
+  degradation shows.
 - ``python`` — the pure-hashlib differential oracle: no native calls, no
   cross-element plane. ``CONSENSUS_SPECS_TPU_MERKLE_DIFF=1`` makes the
   SSZ facade (``utils/ssz/ssz_impl.hash_tree_root``) re-derive every
@@ -92,8 +92,8 @@ def configure(mode: Optional[str] = None) -> None:
 
 @contextlib.contextmanager
 def forced_mode(mode: str):
-    """Scoped mode override — the differential oracle and the bench's
-    python-baseline passes run under ``forced_mode("python")``."""
+    """Scoped mode override — the differential oracle runs under
+    ``forced_mode("python")``."""
     if mode not in _VALID_MODES:
         raise ValueError(f"{MODE_ENV} mode {mode!r} not in {_VALID_MODES}")
     _forced.append(mode)

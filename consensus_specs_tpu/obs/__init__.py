@@ -7,8 +7,8 @@ it exports three surfaces:
                   finalize + the chain plane's validate/sig_wait/apply/
                   sweep) in a bounded ring with slow-request exemplar
                   pinning, plus VM execution events; Chrome trace-event
-                  export (``dump_trace`` / ``bench.py --mode serve
-                  --trace``) composing the flight-recorder lane. Opt-in
+                  export (``dump_trace``) composing the flight-recorder
+                  lane. Opt-in
                   ``CONSENSUS_SPECS_TPU_TRACE=1``. Always on: program
                   spans (``span``) on the profiler's clock and the ring
                   of ``rlc``/``serve`` flush records (``flush_records``).
@@ -26,8 +26,7 @@ it exports three surfaces:
 - ``flight``    — cross-plane flight recorder (bounded ring journal of
                   serve/chain/vm events, JSONL dump on fault/demand).
 - ``slo``       — declared latency objectives + multi-window burn rates
-                  over the histograms; feeds ``/healthz`` and the bench
-                  JSON ``slo`` sections ``bench_compare`` gates — plus
+                  over the histograms; feeds ``/healthz`` — plus
                   the fleet ``ShedPolicy`` (burn rates -> shed/drain
                   decisions, ISSUE 11).
 - ``snapshot``  — the cross-process wire format: a worker's whole obs

@@ -21,8 +21,7 @@ Routes:
                   its aggregator's exact cross-worker merge.
 
 Explicitly opt-in: nothing in the serve plane binds a port unless
-``start_exposition`` is called (the serve bench does it when
-``SERVE_METRICS_PORT`` is set). ``port=0`` binds an ephemeral port; read
+``start_exposition`` is called. ``port=0`` binds an ephemeral port; read
 it back from ``server.port``. Scrapes read shared accumulators under the
 same locks the writers use — a scrape can delay a writer by microseconds
 but never corrupt it, and a handler exception answers 500, never kills

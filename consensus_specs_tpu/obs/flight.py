@@ -15,8 +15,8 @@ journals small structured events into one process-wide ring —
 On a fault (the serve plane reaching the sequential-oracle rung, or any
 belt-and-braces exception) the ring auto-dumps to JSONL — the post-mortem
 exists even when nobody was watching — and on demand via the
-``/flightdump`` endpoint (obs/exposition.py) or ``bench.py --mode serve
---flight out.jsonl``. ``chrome_events`` converts the journal into instant
+``/flightdump`` endpoint (obs/exposition.py) or ``FlightRecorder.dump``.
+``chrome_events`` converts the journal into instant
 events on the existing Chrome trace timeline (pid 4), so the black box
 and the span view line up on one clock.
 

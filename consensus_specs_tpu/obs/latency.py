@@ -23,8 +23,7 @@ moved the fork-choice head:
   head update that reflects the vote (the SPECULATIVE head update when
   ``chain/head_service.py`` speculates, since that is when ``get_head``
   really starts answering with the new vote) — feeds the declared
-  ``gossip_to_head_p99`` per-slot SLO in ``obs/slo.py`` and the
-  ``bench.py --mode latency`` scenario matrix.
+  ``gossip_to_head_p99`` per-slot SLO in ``obs/slo.py``.
 - **the control input**: ``downstream_p99_s()`` reads the live p99 of the
   stages a queued item still has ahead of it (prep/device/finalize) —
   what the serve plane's deadline-aware flush scheduler
@@ -136,8 +135,8 @@ def downstream_p99_s(stages: Tuple[str, ...] = DOWNSTREAM_STAGES,
 
 
 def snapshot() -> Dict[str, Dict]:
-    """The latency families' summary dicts (stage + end-to-end), for
-    bench JSON lines: ``{label: {count/n/mean_ms/max_ms/p50/p95/p99}}``."""
+    """The latency families' summary dicts (stage + end-to-end):
+    ``{label: {count/n/mean_ms/max_ms/p50/p95/p99}}``."""
     out: Dict[str, Dict] = {}
     for label, h in profiling.latency_histograms().items():
         if label == GOSSIP_TO_HEAD_LABEL or label.startswith("latency["):

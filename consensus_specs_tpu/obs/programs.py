@@ -54,8 +54,8 @@ def export_gauges() -> None:
     """(Re-)publish the vm-cache gauges into profiling. Needed beyond
     note_assembly because ``profiling.reset()`` clears gauges while the
     lru_cache on ``_program`` means note_assembly fires only ONCE per
-    (kind, k, fold) per process — a multi-mode bench run calls this after
-    each reset so the epoch stage's profile still carries the counters."""
+    (kind, k, fold) per process — a caller that resets profiling
+    mid-process calls this after each reset so the counters survive."""
     with _lock:
         hits, misses = CACHE_STATS["disk_hits"], CACHE_STATS["disk_misses"]
     if hits or misses:

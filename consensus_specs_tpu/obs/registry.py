@@ -11,12 +11,11 @@ can never silently orphan a dashboard or a scrape rule.
 
 ``render_prometheus()`` is the pull side of the exposition plane
 (``obs/exposition.py`` serves it at ``/metrics``): it reads
-``profiling.summary()`` — the same snapshot every bench JSON line attaches
-— and renders Prometheus text format 0.0.4. Registered names become
-first-class metric families; dynamic labels (the per-shape VM execution
-timings, ``vm[steps=...,regs=...,batch=...]``) map onto ONE family with the
-full label string as a ``label`` label, so high-cardinality shapes never
-mint unbounded metric names.
+``profiling.summary()`` and renders Prometheus text format 0.0.4.
+Registered names become first-class metric families; dynamic labels (the
+per-shape VM execution timings, ``vm[steps=...,regs=...,batch=...]``)
+map onto ONE family with the full label string as a ``label`` label, so
+high-cardinality shapes never mint unbounded metric names.
 """
 import re
 from typing import Dict, Iterable
@@ -211,8 +210,8 @@ GAUGES: Dict[str, str] = {
                              "dependencies)",
     "health.rollback_rate": "speculative batches reverted this slot",
     "health.unexplained_reorgs": "cumulative reorgs observed outside "
-                                 "declared disruption windows (the "
-                                 "soak gate requires 0)",
+                                 "declared disruption windows "
+                                 "(chain/health.evaluate_gate requires 0)",
     "timeseries.samples": "fixed-interval samples the time-series "
                           "store has recorded since process start",
     "timeseries.points": "points currently retained across every "
@@ -221,7 +220,7 @@ GAUGES: Dict[str, str] = {
     "timeseries.evicted": "points dropped by ring eviction (the "
                           "coarser levels still cover the horizon)",
     "process.rss_bytes": "resident set size of this process "
-                         "(/proc/self/statm; the soak's memory-leak "
+                         "(/proc/self/statm; the long-horizon memory-leak "
                          "detector, per worker on the fleet surface)",
     "process.cpu_s": "user+system CPU seconds consumed by this "
                      "process (resource.getrusage)",

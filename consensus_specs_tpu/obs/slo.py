@@ -17,10 +17,8 @@ two numbers an operator pages on:
 
 Surfaces: ``slo.ok`` / ``slo.violations`` / ``slo.worst_burn_rate``
 gauges on ``/metrics``; the upgraded ``/healthz`` body (liveness AND
-objective state, obs/exposition.py); the ``slo`` section in the serve and
-head bench JSON lines, which ``tools/bench_compare.py`` gates round over
-round alongside throughput — a PR that regresses the tail past its
-objective fails CI like a throughput regression does.
+objective state, obs/exposition.py); ``SloTracker.bench_section()``, the
+compact per-objective state a run can report next to its throughput.
 
 Objectives are env-tunable without code: ``CONSENSUS_SPECS_TPU_SLO`` is a
 comma list of ``key=value_ms`` overrides (``serve_p99_ms``,
@@ -37,27 +35,25 @@ SLO_ENV = "CONSENSUS_SPECS_TPU_SLO"
 
 # (name, latency label, quantile, default threshold ms) — the declared
 # objectives. Thresholds are deliberately loose for the 2-core CPU
-# container (a real deployment overrides by env): the stock serve bench
+# container (a real deployment overrides by env): a cold CPU serve run
 # pays first-flush XLA compiles + an injected backend failure inside its
 # tail, measured ~12.5 s p99 cold — the default must hold THAT run green
-# so a violation means a regression, not a cold cache. What the gate
-# protects is the ROUND-OVER-ROUND objective state, not the absolute
-# number.
+# so a violation means a regression, not a cold cache.
 _DEFAULTS: Tuple[Tuple[str, str, float, float], ...] = (
     ("serve_p99", "serve.submit_to_result", 99.0, 30_000.0),
     ("chain_p99", "chain.apply_batch", 99.0, 2_000.0),
     # the per-slot end-to-end objective (ISSUE 12): 99% of gossip items
     # must move the head within one sub-second budget. The crypto-free
-    # simnet/latency-bench paths that feed latency.gossip_to_head land in
+    # simnet/latency paths that feed latency.gossip_to_head land in
     # the low tens of ms on this container; 1000 ms is the "sub-second
     # finality" claim itself, with rollback/deferral churn headroom — a
     # violation under the latency_skew / lossy_links adversarial runs
-    # means a regression, not noise (gated by tools/bench_compare.py).
+    # means a regression, not noise.
     ("gossip_to_head_p99", "latency.gossip_to_head", 99.0, 1_000.0),
 )
 
 # fast + slow burn windows (seconds): the classic multi-window pair,
-# container-scaled so a bench run spans several fast windows
+# container-scaled so a minutes-long run spans several fast windows
 WINDOWS: Tuple[float, ...] = (60.0, 300.0)
 
 
@@ -212,10 +208,9 @@ class SloTracker:
         }
 
     def bench_section(self) -> Dict[str, Dict]:
-        """The ``slo`` section of a bench JSON line — compact per-objective
-        state ``bench_compare`` can diff round over round (``margin`` is
-        the gated number: objective / attained, > 1 == meeting with room;
-        absent when the objective saw no traffic this run)."""
+        """Compact per-objective state for a run's report (``margin`` is
+        objective / attained, > 1 == meeting with room; absent when the
+        objective saw no traffic this run)."""
         evaluated = self.evaluate()
         section = {}
         for name, e in evaluated.items():
@@ -341,8 +336,7 @@ _global: Optional[SloTracker] = None
 
 
 def global_tracker() -> SloTracker:
-    """The process tracker (/healthz evaluates it on every probe; the
-    serve/head benches read their ``slo`` sections from it)."""
+    """The process tracker (/healthz evaluates it on every probe)."""
     global _global
     with _global_lock:
         if _global is None:
@@ -351,8 +345,8 @@ def global_tracker() -> SloTracker:
 
 
 def reset_global() -> None:
-    """Fresh tracker + objectives (tests, multi-mode bench runs — also
-    re-reads the env overrides)."""
+    """Fresh tracker + objectives (tests — also re-reads the env
+    overrides)."""
     global _global
     with _global_lock:
         _global = None

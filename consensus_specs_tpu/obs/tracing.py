@@ -20,8 +20,8 @@ Export is Chrome trace-event JSON (chrome://tracing or Perfetto's "Open
 trace file"): pipeline spans on pid 1 (one row per request), VM program
 executions on pid 2, plus the per-program registry (``obs/programs.py``:
 steps, register-file size, assembly time, ``.vm_cache/`` hit/miss) under
-the top-level ``programRegistry`` key. ``bench.py --mode serve --trace
-out.json`` wires the whole thing end to end.
+the top-level ``programRegistry`` key. ``dump_trace(path)`` writes the
+global tracer's export.
 
 Program spans and flush records (always on, see the section at the end):
 ``span(name, **args)`` marks a stage of the RLC flush or of the service

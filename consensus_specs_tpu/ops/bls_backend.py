@@ -131,9 +131,8 @@ def _fold_for(kind: str, k: int, n_items: int = 1 << 30) -> int:
 
 
 def _vm_cache_dir() -> str:
-    # CONSENSUS_SPECS_TPU_VM_CACHE overrides the repo-local default —
-    # the cold-start bench children point it (and the XLA cache) at
-    # fresh temp dirs so BOTH arms measure a genuinely fresh runner
+    # CONSENSUS_SPECS_TPU_VM_CACHE overrides the repo-local default (a
+    # fresh temp dir measures a genuinely fresh runner)
     d = os.environ.get("CONSENSUS_SPECS_TPU_VM_CACHE") or os.path.join(
         os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
         ".vm_cache",
@@ -1022,10 +1021,10 @@ def _run_hard_part(g_flat_batch: np.ndarray, mesh=None,
     """(N, 12, L) unitary g limb batch -> (N,) bool (res == 1). Counts N
     rows (padding included) against RLC_STATS['final_exps'] — the
     amortization ledger behind the serve plane's final-exps-per-item.
-    ``kind`` overrides the variant route (_hard_part_kind) — the finalexp
-    bench races all three on identical rows; ``fold`` pins the fold
-    factor (the bench's same-program backend race needs the interpreter
-    on the fold-1 shape the fused lowering runs)."""
+    ``kind`` overrides the variant route (_hard_part_kind) — a variant
+    race runs all three on identical rows; ``fold`` pins the fold
+    factor (a same-program backend race needs the interpreter on the
+    fold-1 shape the fused lowering runs)."""
     n = g_flat_batch.shape[0]
     RLC_STATS["final_exps"] += n
     if kind is None:
@@ -1153,7 +1152,7 @@ _FINAL_EXP_BATCHER = _FinalExpBatcher()
 
 # entry-point instrumentation: batch calls + per-item verifications, used
 # by the serve plane's dedup assertions ("every duplicate verified exactly
-# once") and attached to serve-bench JSON lines
+# once")
 CALL_COUNTS = {
     "batch_fast_aggregate_verify": 0,
     "batch_aggregate_verify": 0,
@@ -1176,7 +1175,7 @@ def reset_call_counts() -> None:
 # combined checks forced a bisection split, and how many hard-part
 # evaluations (device rows, padding included, + host-oracle hard parts)
 # the process has paid — final_exps / items is the amortization headline
-# the serve bench reports as final-exps-per-item
+# (the service's final_exps_per_item)
 RLC_STATS = {
     "combines": 0,
     "bisections": 0,

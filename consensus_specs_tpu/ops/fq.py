@@ -120,9 +120,9 @@ def mont_mul(a, b):
 
 
 def mont_mul_u64(a, b):
-    """The jnp uint64 lowering of mont_mul, reachable directly so the
-    Pallas A/B (bench/pallas_ab.py) can baseline against it even when the
-    Pallas dispatch is switched on.
+    """The jnp uint64 lowering of mont_mul, reachable directly so a
+    Pallas A/B can baseline against it even when the Pallas dispatch is
+    switched on.
 
     Vectorized SOS: the schoolbook product and each reduction step are
     whole-vector ops (broadcast multiply + statically-padded shift + add) so

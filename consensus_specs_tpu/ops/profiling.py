@@ -15,9 +15,8 @@ per-kernel timing and an XLA trace hook).
   statistical weight.
 - ``set_gauge(...)`` publishes point-in-time values (queue depth, cache
   hit rate, batch occupancy) from the serve plane.
-- ``summary()``/``snapshot()``/``report()`` expose all three; bench.py
-  attaches the summary to its JSON line when CONSENSUS_SPECS_TPU_PROFILE=1
-  (the serve bench mode attaches it always).
+- ``summary()``/``snapshot()``/``report()`` expose all three; the
+  ``/metrics`` renderer reads ``summary()``.
 - ``latency_histograms()`` hands detached histogram copies to the
   Prometheus renderer (full ``_bucket``/``_sum``/``_count`` exposition)
   and the SLO burn-rate tracker (``obs/slo.py``).
@@ -163,9 +162,8 @@ def snapshot() -> Dict[str, Dict[str, float]]:
 def reset() -> None:
     """Clear ALL THREE accumulator families — per-label stats, latency
     histograms, gauges — so a post-reset run is indistinguishable from a
-    fresh process (multi-mode bench runs reset between modes; histogram
-    bucketing is deterministic, so reruns are comparable by
-    construction)."""
+    fresh process (histogram bucketing is deterministic, so reruns are
+    comparable by construction)."""
     with _lock:
         _stats.clear()
         _lat.clear()

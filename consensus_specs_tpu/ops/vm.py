@@ -1065,8 +1065,8 @@ def execute(program: Program,
     outputs); ``auto`` (default) takes fused only when its artifact is
     already compiled in-process for THIS batch shape and its measured
     ms/row beats the interpreter's — auto never pays the cold fused
-    trace+compile bill mid-call (``warm_fused``/a pinned-``fused`` call/
-    the vmexec bench are what compile shapes). A
+    trace+compile bill mid-call (``warm_fused`` or a pinned-``fused``
+    call is what compiles shapes). A
     fused trace/compile/run failure falls back to the interpreter with a
     ``vm/fused_fallback`` flight event — this entry point never fails for
     lowering reasons."""

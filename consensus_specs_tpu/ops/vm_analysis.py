@@ -65,7 +65,7 @@ COST_MODEL_REGS = 600.0
 # gather/scatter), plus per-level stack/slice glue and per-chunk jit
 # dispatch. Constants fit to the measured g2_subgroup fold-1 warm row
 # (955 levels / 3417 muls / 5733 lins -> ~46 ms at chunk 24 on the
-# 2-core container; `make vmexec-bench` re-measures): the per-LEVEL term
+# 2-core container): the per-LEVEL term
 # dominates at fold 1 (XLA op-launch overhead of the straight-line
 # graphs), the per-mul SIMD work takes over on folded/wide programs.
 FUSED_COST_US_PER_MUL = 1.7

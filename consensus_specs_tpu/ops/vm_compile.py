@@ -38,8 +38,8 @@ level-chunk *shapes* stamped out dozens of times, so the lowering now:
 
 Measured on the 2-core container: g2_subgroup fold-1 (955 levels) goes
 from 40 per-chunk compiles to 7 distinct structures (25 of 35 chunks
-riding scan runs); `make vmexec-bench`'s cold cells race the two modes
-(``CONSENSUS_SPECS_TPU_VM_DEDUP=0`` pins the per-chunk baseline).
+riding scan runs); ``CONSENSUS_SPECS_TPU_VM_DEDUP=0`` pins the per-chunk
+baseline.
 
 Outputs stay BIT-IDENTICAL to the interpreter: the per-op integer
 functions (Montgomery reduce / carry add / borrowless sub) are the same
@@ -148,8 +148,8 @@ def chunk_steps() -> int:
 
 def dedup_enabled() -> bool:
     """CONSENSUS_SPECS_TPU_VM_DEDUP: structural chunk dedup (default on;
-    `0` pins the PR 13 one-compile-per-chunk baseline the cold bench
-    races against). Anything else warns once and keeps the default."""
+    `0` pins the PR 13 one-compile-per-chunk baseline). Anything else
+    warns once and keeps the default."""
     raw = os.environ.get("CONSENSUS_SPECS_TPU_VM_DEDUP")
     if raw is None or raw in ("1", ""):
         return True
@@ -742,7 +742,7 @@ def _seed_stats_from_plan(program, plan: Dict) -> None:
     """Adopt the plan's persisted warm-ms/row measurements into the
     in-process ledger (keeping any better number this process measured) —
     this is what lets a FRESH process's ``auto`` route serve the winner a
-    past bench proved (once a shape is warmed) instead of re-measuring
+    past process measured (once a shape is warmed) instead of re-measuring
     the interpreter per process."""
     meas = plan.get("measured")
     if not isinstance(meas, dict):
@@ -845,8 +845,8 @@ def _bg_enqueue(program, batch: tuple) -> None:
 
 
 def bg_warm_drain(timeout: float = 60.0) -> bool:
-    """Wait until the background-warm queue is empty and idle (tests and
-    the cold bench use this; serving code never blocks on it)."""
+    """Wait until the background-warm queue is empty and idle (tests use
+    this; serving code never blocks on it)."""
     deadline = time.monotonic() + timeout
     with _BG_LOCK:
         while _BG_QUEUE or _BG_PENDING:
@@ -876,7 +876,7 @@ def use_fused(program, mode: str = None, shape_sig: tuple = None) -> bool:
     The shape condition is what keeps ``auto`` from ever paying the
     cold trace+compile bill in the middle of a serving call or a test:
     the bill is only ever paid by an explicit ``warm_fused``, a
-    pinned-``fused`` call, the vmexec bench — or, under
+    pinned-``fused`` call — or, under
     ``CONSENSUS_SPECS_TPU_VM_WARM_BG=1``, the background-warm thread a
     not-yet-compiled winner shape enqueues here: the call itself stays
     on the interpreter and auto flips to fused once the warm lands
@@ -954,8 +954,7 @@ def warm_fused(program, batch_shape=()) -> float:
     (sequential AOT across compile units — see ``FusedProgram.warm``)
     and return the trace+compile wall seconds (0.0 when already compiled
     in-process; structure entries already compiled — by any program —
-    count as ``vm/structural_hit`` and cost nothing). The vmexec bench
-    reports this number next to each warm ms/row cell; ``auto`` serves
+    count as ``vm/structural_hit`` and cost nothing). ``auto`` serves
     fused for a shape only after a call like this has compiled it."""
     fp = fused_program(program)
     dt = fp.warm(tuple(int(d) for d in batch_shape))

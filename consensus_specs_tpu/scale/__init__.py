@@ -24,6 +24,5 @@ plane at production scale:
   slot verified hierarchically == flat == host oracle over
   valid/corrupted/censored traffic, bad committee localized.
 
-Benchmarked end-to-end by ``bench.py --mode mainnet``
-(``consensus_specs_tpu/bench/mainnet.py``).
+Measured on the chip by the ``slot1m.fold`` cell of ``benchmark/``.
 """

@@ -82,9 +82,8 @@ def attesters_per_slot(n_validators: int,
     """Validators attesting in ONE slot when every registered validator
     is active: the full committee fan-out covers the registry once per
     epoch, so each slot touches n/SLOTS_PER_EPOCH of it. This is the
-    real per-block state-delta size the merkle bench's incremental
-    re-root model uses (mainnet shape: 1M validators -> 32768 touched
-    per slot)."""
+    real per-block state-delta size an incremental re-root pays
+    (mainnet shape: 1M validators -> 32768 touched per slot)."""
     return max(1, min(n_validators, n_validators // slots_per_epoch))
 
 
@@ -190,9 +189,9 @@ class Registry:
     def digest(self, sample: Optional[int] = None) -> str:
         """Streamed registry digest: sha256 over the header and the
         compressed pubkeys of either every validator (small registries,
-        tests) or a deterministic evenly-spaced ``sample`` (the 1M
-        bench — full derivation would be the one thing lazy emission
-        exists to avoid)."""
+        tests) or a deterministic evenly-spaced ``sample`` (at 1M keys
+        full derivation would be the one thing lazy emission exists to
+        avoid)."""
         h = hashlib.sha256()
         h.update(b"scale-registry-digest")
         h.update(self.n_validators.to_bytes(8, "little"))

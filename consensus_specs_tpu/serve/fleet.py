@@ -632,7 +632,7 @@ class FleetRouter:
         return handle
 
     def start_control(self, interval_s: float = 1.0) -> None:
-        """Background control loop (bench/production mode; tests and the
+        """Background control loop (production mode; tests and the
         smoke call ``control_tick`` explicitly for determinism)."""
         if self._control_thread is not None:
             return

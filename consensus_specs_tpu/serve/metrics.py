@@ -2,9 +2,9 @@
 rate, and submit->result latency percentiles.
 
 Everything is exported through ``ops/profiling`` (gauges +
-``record_latency``) so ``profiling.summary()`` — and therefore every
-bench JSON line that attaches it — carries the serving SLO numbers
-without bench needing to know the service's internals.
+``record_latency``) so ``profiling.summary()`` — and therefore
+``/metrics`` — carries the serving SLO numbers without a reader needing
+to know the service's internals.
 """
 import sys
 import threading
@@ -104,8 +104,8 @@ class ServeMetrics:
         self.device_s = 0.0
         # RLC amortization baseline: the backend's combine/bisection/
         # final-exp counters are process-global, so snapshot() reports
-        # THIS service's deltas (final-exps-per-item is the headline the
-        # serve bench gates on). Backend not imported yet == counters at
+        # THIS service's deltas (final-exps-per-item is the amortization
+        # headline). Backend not imported yet == counters at
         # zero, so the empty baseline is exact, not an approximation.
         mod = _backend_module()
         self._rlc_base = dict(mod.RLC_STATS) if mod is not None else {}
@@ -255,8 +255,7 @@ class ServeMetrics:
             # final exponentiations per SERVED request (non-eager submits:
             # everything the crypto plane answered, cache hits included —
             # the RLC combine AND the dedup layer both amortize, and this
-            # is the number that shows it; < 0.2 at steady state is the
-            # serve-bench acceptance bar)
+            # is the number that shows it; < 0.2 at steady state)
             served = self.submits - self.eager
             final_exps_per_item = (
                 rlc_stats.get("final_exps", 0) / served if served > 0 else 0.0

@@ -84,8 +84,8 @@ class SlotClock:
     ticks every ``slot_s`` seconds; ``slot_end(t)`` is the absolute
     perf-counter time the slot containing ``t`` closes — the latency
     budget a gossip item born at ``t`` has to reach the head. One clock
-    can be shared by many services (reads only; the bench shares one grid
-    across all simnet nodes, which is what a real network does)."""
+    can be shared by many services (reads only; the simnet shares one
+    grid across all its nodes, which is what a real network does)."""
 
     __slots__ = ("slot_s", "origin", "_clock")
 

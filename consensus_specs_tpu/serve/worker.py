@@ -9,11 +9,11 @@ its own XLA client, its own result cache, and its own observability
 state, which it ships home as `obs/snapshot.py` wire snapshots for exact
 fleet-wide merging.
 
-Protocol: newline-delimited JSON over stdin/stdout (the pipe pair the
-`bench.py` serve-mesh child sweep seeded, promoted to a long-lived
-duplex). Binary fields travel as hex. Requests carry an ``id`` the reply
-echoes; ``submit`` replies arrive in COMPLETION order (the service
-resolves futures as flushes finish), everything else answers in line.
+Protocol: newline-delimited JSON over stdin/stdout, one long-lived
+duplex per worker. Binary fields travel as hex. Requests carry an
+``id`` the reply echoes; ``submit`` replies arrive in COMPLETION order
+(the service resolves futures as flushes finish), everything else
+answers in line.
 
   parent -> worker                      worker -> parent
   ----------------                      ----------------
@@ -183,7 +183,7 @@ def _warm_committees(k: int, n: int, seed: int = 9901):
 
 def _warm(k: int, sizes) -> None:
     """Pay the XLA/VM compiles for the given flush sizes outside any
-    timed window (the serve bench's mesh warm-up, worker-side)."""
+    timed window."""
     from ..ops import bls_backend
 
     sizes = sorted({int(s) for s in sizes if int(s) > 0}, reverse=True)

@@ -12,8 +12,8 @@ queue drains, every honest node's ``get_head`` must be bit-identical to
 
 Entry points: ``run_scenario`` (one scenario, strict gate),
 ``SCENARIOS`` (the named scenario library), ``build_world`` (the shared
-spec + crafted genesis), and ``bench.py --mode sim`` /
-``make sim-bench`` for the full matrix.
+spec + crafted genesis); ``make sim-smoke`` runs one scenario through
+the strict gate.
 """
 from .fabric import EventQueue, Fabric, Message, PartitionWindow
 from .node import SimNode

@@ -13,7 +13,7 @@ Each node owns the REAL production stack, not a mock of it:
 - its own node-labelled observability: ``chain[<name>].*`` /
   ``serve[<name>].*`` metric families and a per-node
   :class:`~consensus_specs_tpu.obs.flight.FlightRecorder` journaling on
-  the SIMULATED clock — the per-node black boxes ``make sim-bench``
+  the SIMULATED clock — the per-node black boxes ``make sim-smoke``
   dumps and CI uploads on failure.
 
 The node's clock only moves forward, driven by the runner as events
@@ -41,10 +41,10 @@ class SimNode:
 
     ``service_kwargs`` / ``head_kwargs`` override the node's
     VerificationService / HeadService construction knobs — the latency
-    bench (``bench.py --mode latency``) uses them to A/B the classic
-    size-OR-deadline flush against the slot-budget scheduler
-    (``slot_clock=``) and to arm speculative head application
-    (``speculative=True``) without touching the scenario scripts."""
+    smoke and tests use them to swap the classic size-OR-deadline flush
+    for the slot-budget scheduler (``slot_clock=``) and to arm
+    speculative head application (``speculative=True``) without touching
+    the scenario scripts."""
 
     def __init__(self, index: int, spec, anchor_state, anchor_block,
                  shared_state, *, honest: bool = True, sim_clock=None,

@@ -54,9 +54,7 @@ __all__ = [
 ]
 
 # env knobs (documented in the README env reference)
-NODES_ENV = "CONSENSUS_SPECS_TPU_SIM_NODES"
 SEED_ENV = "CONSENSUS_SPECS_TPU_SIM_SEED"
-SCENARIOS_ENV = "CONSENSUS_SPECS_TPU_SIM_SCENARIOS"
 FLIGHT_DIR_ENV = "CONSENSUS_SPECS_TPU_SIM_FLIGHT_DIR"
 EVENTS_ENV = "CONSENSUS_SPECS_TPU_SIM_EVENTS"
 
@@ -80,7 +78,7 @@ class ScenarioReport:
     last_heal_s: float = 0.0          # sim time of the last heal (0: none)
     # first head agreement at-or-after the last heal, minus the heal time
     # (no partitions: time to the first agreement at all) — the recovery
-    # latency `make sim-bench` reports and bench_compare tracks
+    # latency
     heal_to_convergence_s: float = 0.0
     sim_end_s: float = 0.0
     wall_s: float = 0.0
@@ -334,20 +332,21 @@ def run_scenario(scenario: Scenario, *, spec=None, anchor_state=None,
                  light_clients: Optional[int] = None,
                  slot_hook=None) -> ScenarioReport:
     """Run one scenario end to end and gate it. ``strict`` raises
-    :class:`SimDivergence` on any convergence failure; bench mode passes
-    ``strict=False`` and reads ``report.converged``/``report.error``.
+    :class:`SimDivergence` on any convergence failure; ``strict=False``
+    leaves the verdict in ``report.converged``/``report.error``.
     ``flight_dir`` dumps one JSONL flight journal per node (always on
     failure paths when set — the CI artifact). ``service_kwargs`` /
     ``head_kwargs`` override every node's VerificationService /
-    HeadService knobs (the latency bench's deadline-flush and
-    speculative-apply A/B runs) — the scenario script and the gate are
+    HeadService knobs (the latency runs' deadline-flush and
+    speculative-apply arms) — the scenario script and the gate are
     untouched by either. ``light_clients`` overrides the scenario's
     read-only light-client count (they fetch proofs OUTSIDE the event
     queue, so the determinism digest is unchanged). ``slot_hook``
     (ISSUE 19) is called as ``slot_hook(slot, sim_nodes)`` once per
-    simulated slot boundary in slot order — the soak's per-slot health
-    ledger sampling point. Pure reads only: the hook runs outside the
-    event queue and must not publish, so the digest is unchanged."""
+    simulated slot boundary in slot order — the per-slot sampling point
+    of a ``chain/health.HealthLedger``. Pure reads only: the hook runs
+    outside the event queue and must not publish, so the digest is
+    unchanged."""
     from ..utils import bls
 
     if spec is None:

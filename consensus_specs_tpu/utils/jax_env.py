@@ -1,7 +1,7 @@
 """Explicit CPU runs + the verify plane's mesh provider.
 
 :func:`force_cpu` is for tests and for runs that ask for the CPU on
-purpose (the CPU-only bench modes, ``__graft_entry__``'s virtual-device
+purpose (the CPU smokes, ``__graft_entry__``'s virtual-device
 dryrun): it pins ``JAX_PLATFORMS=cpu``, optionally with n virtual host
 devices. Everything else runs on the device JAX finds.
 
@@ -71,7 +71,7 @@ def get_mesh(spec: Optional[str] = None):
     - ``<n>`` — an n-device mesh. On a CPU platform with jax NOT yet
       imported, :func:`force_cpu` requests n VIRTUAL host devices first
       (``xla_force_host_platform_device_count`` is read once, at backend
-      init — so the mesh-bench/smoke entry points call this before any
+      init — so the mesh smoke entry point calls this before any
       device op; an already-initialized process just uses what exists,
       it never clears live backends). Counts clamp to the power-of-two
       floor of what is actually available (the batch rows pad to the

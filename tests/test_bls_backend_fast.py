@@ -2,7 +2,13 @@
 real verify + one bucket edge, small programs only — so the flagship
 correctness path is exercised on every plain `pytest tests/` run, not just
 under --run-slow. The deep/wide cases live in test_bls_backend_tpu.py."""
+import os
+import subprocess
+import sys
+
 from consensus_specs_tpu.utils import bls
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_single_verify_and_k2_bucket():
@@ -439,3 +445,33 @@ def test_prune_evicts_stale_fingerprint_entries(tmp_path):
     out = prune_vm_cache(max_age_days=0, max_bytes=0, cache_dir=d,
                          evict_stale=False)
     assert out["evicted"] == 0
+
+
+# -- where the persistent compile cache goes ---------------------------------
+#
+# ops/__init__.py is the one place that sets it; each case imports the
+# backend in a fresh process, since the placement happens at import.
+
+
+def _cache_dir_in_fresh_process(env_updates, drop=()):
+    env = {k: v for k, v in os.environ.items() if k not in drop}
+    env.update(env_updates, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         "import jax, consensus_specs_tpu.ops.bls_backend; "
+         "print(jax.config.jax_compilation_cache_dir)"],
+        cwd=_REPO, env=env, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return proc.stdout.strip().splitlines()[-1]
+
+
+def test_compile_cache_honours_jax_compilation_cache_dir(tmp_path):
+    want = str(tmp_path / "outside_cache")
+    assert _cache_dir_in_fresh_process(
+        {"JAX_COMPILATION_CACHE_DIR": want}) == want
+
+
+def test_compile_cache_defaults_to_repo_jax_cache():
+    got = _cache_dir_in_fresh_process(
+        {}, drop=("JAX_COMPILATION_CACHE_DIR",))
+    assert got == os.path.join(_REPO, ".jax_cache")

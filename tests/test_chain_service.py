@@ -1,6 +1,6 @@
 """HeadService integration: real spec histories, serve-plane signature
 routing, deferred/dropped gossip, chain metrics + exposition, tracing
-spans, and the head-bench glue.
+spans, and the gossip fault-plan helpers.
 
 The differential claim here runs on REAL histories (blocks built through
 the actual state transition, attestations from real committees) with the
@@ -387,29 +387,6 @@ def test_batch_spans_traced(spec, genesis_state):
     assert done, "no chain_apply trace finished"
     names = done[-1].span_names()
     assert set(CHAIN_STAGES) <= names
-
-
-# -- bench glue ---------------------------------------------------------------
-
-
-def test_head_replay_bench_smoke(spec, monkeypatch):
-    """A miniature `bench.py --mode head` run end to end: heads asserted
-    equal at the sample points, fault plan exercised, JSON-able result."""
-    monkeypatch.setenv("HEAD_TREE_SIZES", "24")
-    monkeypatch.setenv("HEAD_EPOCHS", "2")
-    monkeypatch.setenv("HEAD_EVENTS_PER_EPOCH", "12")
-    monkeypatch.setenv("HEAD_BATCH", "6")
-    monkeypatch.setenv("HEAD_QUERY_ROUNDS", "8")
-    monkeypatch.delenv("SERVE_METRICS_PORT", raising=False)
-    from consensus_specs_tpu.bench.head_replay import run_head_bench
-
-    result = run_head_bench()
-    assert result["mode"] == "head"
-    assert result["trees"][0]["heads_match"] is True
-    assert result["trees"][0]["spec_queries"] > 0
-    assert result["value"] > 0
-    assert f"head[{result['blocks']}]" in result["per_mode_best"]
-    json.dumps(result)  # the line bench.py prints must be serializable
 
 
 def test_gossip_fault_plan_shape():

@@ -204,23 +204,3 @@ def test_gate_verdicts_and_reasons():
     assert not evaluate_gate(recovered, finality_lag_max_slots=64)["ok"]
     # empty horizon is never a pass
     assert not evaluate_gate(aggregate_summaries([]))["ok"]
-
-
-def test_soak_scenario_shapes_the_horizon():
-    """The soak's scenario keeps the zero-unexplained-reorg gate a real
-    claim: the canonical chain must be fork-free by construction, every
-    partition window must respect the epoch boundary invariant, and the
-    horizon must cover >= 1000 slots at the acceptance epoch count."""
-    from consensus_specs_tpu.bench.soak import WARMUP_EPOCHS, soak_scenario
-
-    sc = soak_scenario(128)
-    spe = 8
-    assert sc.fork_rate == 0.0
-    assert sc.epochs == 128 and sc.name == "telemetry_soak"
-    assert sc.epochs * spe - 1 >= 1000 + WARMUP_EPOCHS * spe
-    assert sc.partitions, "soak without disruption proves nothing"
-    for w in sc.partitions:
-        epoch = int(w.form_slot) // spe
-        assert w.form_slot == epoch * spe + 2
-        assert w.heal_slot == (epoch + 1) * spe + 1
-        assert len(w.groups) == 2

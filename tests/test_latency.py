@@ -6,8 +6,7 @@ the fleet's merged scrape carrying the end-to-end histogram.
 
 Everything here runs crypto-free (verdict-style backends, simnet's
 VerdictBackend, verdict-mode fleet workers) so tier-1 stays fast; the
-real-crypto serve path is covered by tests/test_serve.py and the full
-matrix by `make latency-bench`.
+real-crypto serve path is covered by tests/test_serve.py.
 """
 import time
 

@@ -1,6 +1,6 @@
 """Light-client proof plane (ISSUE 16): artifact construction +
 verification, the content-addressed ``ProofService`` front, the simnet
-``light_client`` node kind, and the proofs bench section shape.
+``light_client`` node kind.
 
 Tier-1 budget: everything here is crypto-free (VerdictBackend verdicts,
 SHA-256-only Merkle checks) except the two tests that pin the REAL
@@ -386,33 +386,3 @@ def test_scenario_report_carries_light_client_evidence():
         assert snap["verified"] > 0 and snap["failures"] == 0
     # the dict form ships per_client for the matrix report
     assert report.to_dict()["per_client"] == report.per_client
-
-
-# -- the bench section shape -------------------------------------------------
-
-
-def test_proofs_bench_emits_gated_section(monkeypatch, world):
-    """A tiny verdict-backend replay: the JSON line must carry the
-    `proofs` section bench_compare state-gates, with verified True, the
-    (N - R)/N steady-state hit rate, and a p99 from the proof_serve
-    stage. The warm phase still runs the full spec verification (one
-    real pairing per slot)."""
-    from consensus_specs_tpu.bench.proofs import run_proofs_bench
-
-    monkeypatch.setenv("CONSENSUS_SPECS_TPU_PROOF_CLIENTS", "64")
-    monkeypatch.setenv("CONSENSUS_SPECS_TPU_PROOF_SLOTS", "2")
-    monkeypatch.setenv("CONSENSUS_SPECS_TPU_PROOF_WORKERS", "2")
-    monkeypatch.setenv("CONSENSUS_SPECS_TPU_PROOF_BACKEND", "verdict")
-    result = run_proofs_bench()
-    assert result["mode"] == "proofs" and result["platform"] == "cpu"
-    assert result["verified"] is True
-    assert result["checked_requests"] == 64
-    row = result["proofs"]["clients=64"]
-    assert row["verified"] is True
-    # serves = 64 client fetches + one warm request per slot; only the
-    # 2 slot-first builds miss
-    assert row["hit_rate"] == pytest.approx((66 - 2) / 66)
-    assert row["proofs_per_sec"] > 0 and row["p99_ms"] >= 0
-    assert result["per_mode_best"] == {
-        "proofs[clients=64]": row["proofs_per_sec"]}
-    assert result["service"]["builds"] == 2

@@ -396,17 +396,6 @@ def test_serve_narrow_flush_stays_single_device():
     assert svc.mesh_devices == 4
 
 
-def test_mesh_sweep_line_parser():
-    """The sweep driver takes the LAST parseable JSON line of a serve
-    child (children emit progress noise before the final line)."""
-    from consensus_specs_tpu.serve.load import _parse_last_json_line
-
-    out = b'warming up...\n{"value": 1}\nnoise\n{"value": 2, "mode": "serve"}\n'
-    assert _parse_last_json_line(out) == {"value": 2, "mode": "serve"}
-    assert _parse_last_json_line(b"no json here\n") is None
-    assert _parse_last_json_line(b"") is None
-
-
 def test_serve_single_device_mesh_collapses_to_unsharded():
     """A 1-device mesh is the unsharded path — the service must not pay
     sharded dispatch for it."""

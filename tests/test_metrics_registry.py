@@ -39,7 +39,6 @@ def _py_sources():
         for fn in filenames:
             if fn.endswith(".py"):
                 yield os.path.join(dirpath, fn)
-    yield os.path.join(_ROOT, "bench.py")
 
 
 def _emitted_labels():

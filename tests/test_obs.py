@@ -5,8 +5,7 @@ concurrent writers-vs-readers safety, the per-program VM registry, and
 the profiling satellites (dynamic ENABLED, full reset).
 
 Everything here runs against crypto-free backends so tier-1 stays fast;
-the real-crypto serve path is covered by tests/test_serve.py and the
-trace/endpoint glue by `make serve-trace`.
+the real-crypto serve path is covered by tests/test_serve.py.
 """
 import json
 import os
@@ -594,7 +593,7 @@ def test_program_registry_and_vm_cache_gauges():
     assert summ["bls.vm_cache_misses"] == {"gauge": 1.0}
     # profiling.reset() wipes gauges, and note_assembly fires only once
     # per program per process (lru_cache) — export_gauges() re-publishes
-    # so a multi-mode bench's later stages still carry the counters
+    # so a run after the reset still carries the counters
     profiling.reset()
     assert "bls.vm_cache_hits" not in profiling.summary()
     obs_programs.export_gauges()
@@ -689,53 +688,6 @@ def test_profiling_snapshot_carries_observation_counts():
     fam = profiling.snapshot()["serve.submit_to_result"]
     assert fam["n"] == 37 and fam["count"] == 37
     assert {"p50_ms", "p95_ms", "p99_ms"} <= set(fam)
-
-
-# -- bench --trace glue -----------------------------------------------------
-
-
-def test_bench_serve_trace_flag_writes_chrome_json(tmp_path, monkeypatch,
-                                                   capsys):
-    """`bench.py --mode serve --trace out.json` enables tracing before the
-    load runs, dumps the global tracer, and attaches the path to the JSON
-    line — glued here with a stub load so no crypto/compiles are paid."""
-    import importlib.util
-
-    bench_path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "bench.py")
-    spec = importlib.util.spec_from_file_location("bench_trace_glue",
-                                                  bench_path)
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    import consensus_specs_tpu.serve.load as load_mod
-    import consensus_specs_tpu.utils.jax_env as jax_env
-
-    def fake_serve_bench():
-        # the real run constructs the service AFTER main() set the env;
-        # mirror that and push one batch through the traced pipeline
-        tracing.reset_global()
-        with _svc(RlcBackend(), max_batch=2, max_wait_ms=10_000) as svc:
-            a = svc.submit("fast_aggregate", [PK], b"m1", b"s-ok")
-            b = svc.submit("fast_aggregate", [PK], b"m2", b"s-ok")
-            assert a.result(timeout=10) and b.result(timeout=10)
-        return {"value": 1.0, "vs_baseline": 0.0, "mode": "serve"}
-
-    monkeypatch.setattr(load_mod, "run_serve_bench", fake_serve_bench)
-    monkeypatch.setattr(jax_env, "force_cpu", lambda *a, **k: None)
-    out = tmp_path / "trace.json"
-    monkeypatch.setattr(sys, "argv",
-                        ["bench.py", "--mode", "serve", "--trace", str(out)])
-    bench.main()
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert line["trace"] == str(out)
-    assert line["trace_requests"] == 2
-    with open(out) as fh:
-        doc = json.load(fh)
-    names = {e["name"] for e in doc["traceEvents"] if e.get("ph") == "X"}
-    assert set(STAGES) <= names  # all five stages for >= 1 request
-    assert "programRegistry" in doc
 
 
 if __name__ == "__main__" and "--regen-golden" in sys.argv:

@@ -1,8 +1,8 @@
 """Fleet observability: the cross-plane flight recorder
 (obs/flight.py) with its fault-triggered JSONL dump, and SLO burn-rate
-tracking (obs/slo.py) with the upgraded /healthz. Everything runs against crypto-free backends so
-tier-1 stays fast; the real-crypto glue is `make serve-trace` /
-`make serve-bench`.
+tracking (obs/slo.py) with the upgraded /healthz. Everything runs
+against crypto-free backends so tier-1 stays fast; the real-crypto
+serve path is covered by tests/test_serve.py.
 """
 import json
 import os
@@ -297,7 +297,7 @@ def test_healthz_reports_slo_state(monkeypatch):
 
 
 def test_slo_bench_flow_reports_nonzero_burn(monkeypatch):
-    """The bench path (reset -> baseline evaluate -> run -> section):
+    """The report path (reset -> baseline evaluate -> run -> section):
     violations during the run must show up as burn, not the structural
     0.0 a single end-of-run evaluate would produce with no baseline."""
     monkeypatch.setenv("CONSENSUS_SPECS_TPU_SLO", "serve_p99_ms=50")

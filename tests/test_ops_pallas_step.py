@@ -3,7 +3,7 @@ one kernel doing both ALU units on a 14-bit uint32 register file must be
 bit-identical to the default u64 scan path (ops/vm.py _vm_step).
 
 Runs in interpret mode on CPU (Mosaic compilation needs real hardware;
-the on-hardware A/B rides the bench child's probe stage — TPU_NOTES.md).
+chip_smoke.py holds the Pallas modes bit-identical on the chip).
 """
 import numpy as np
 

@@ -28,7 +28,7 @@ def test_registry_digest_is_seed_deterministic():
     c = Registry(24, seed=8).digest()
     assert a == b
     assert a != c
-    # sampled digests are deterministic too (the 1M bench's form)
+    # sampled digests are deterministic too (the form a 1M registry uses)
     assert (Registry(24, seed=7).digest(sample=5)
             == Registry(24, seed=7).digest(sample=5))
 

@@ -169,6 +169,38 @@ def test_flight_journals_per_node(world, tmp_path):
     assert all(0.0 <= e["t"] <= report.sim_end_s for e in events)
 
 
+def test_slot_hook_fires_once_per_slot_outside_the_digest(world):
+    """``slot_hook`` fires once per simulated slot boundary, in order,
+    quiet stretches included, with every node's HeadService readable —
+    a per-node health ledger is the reader it exists for. It runs
+    outside the event queue, so the run is the same run without it."""
+    from consensus_specs_tpu.chain.health import HealthLedger
+
+    spec, anchor_state, anchor_block = world
+    kw = dict(spec=spec, anchor_state=anchor_state,
+              anchor_block=anchor_block, seed=7)
+    plain = run_scenario(get_scenario("partition_heal"), **kw)
+    slots, ledgers = [], {}
+
+    def hook(slot, sim_nodes):
+        slots.append(slot)
+        for node in sim_nodes:
+            if node.name not in ledgers:
+                ledgers[node.name] = HealthLedger(node.head, node=node.name)
+            ledgers[node.name].observe_slot(slot=slot, expect_reorgs=True)
+
+    hooked = run_scenario(get_scenario("partition_heal"), slot_hook=hook,
+                          **kw)
+    assert hooked.digest == plain.digest and hooked.head == plain.head
+    sps = int(spec.config.SECONDS_PER_SLOT)
+    assert slots == list(range(1, int(hooked.sim_end_s // sps) + 1))
+    assert set(ledgers) == set(hooked.per_node)
+    for ledger in ledgers.values():
+        summary = ledger.summary()
+        assert summary["slots_observed"] == len(slots)
+        assert summary["unexplained_reorgs"] == 0
+
+
 # -- fault-plan dataclass (serve/load.py satellite) ---------------------------
 
 

@@ -210,7 +210,7 @@ def test_hard_part_variants_recover_depth():
     # (real per-level widths + per-level/per-chunk glue, no register-file
     # traffic) must beat the 280 µs/step interpreter model on the
     # pipelined frobenius fold-8 shape — the static-model statement of
-    # the measured fused win `make vmexec-bench` re-checks dynamically
+    # the measured fused win
     assert frob8["cost"]["predicted_fused_row_s"] > 0
     assert (frob8["cost"]["predicted_fused_row_s"]
             < frob8["cost"]["predicted_row_s"])

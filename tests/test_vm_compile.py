@@ -475,8 +475,8 @@ def test_superop_off_still_identical(monkeypatch):
 
 def test_dedup_off_pins_per_chunk_baseline(monkeypatch):
     """CONSENSUS_SPECS_TPU_VM_DEDUP=0 is the PR 13 one-compile-per-chunk
-    baseline the cold bench races: every chunk its own structure, no
-    super-ops, identity unchanged."""
+    baseline: every chunk its own structure, no super-ops, identity
+    unchanged."""
     prog = _periodic_prog(iters=8)
     assembled = prog.assemble(**BUCKET)
     ints, arrs = _rand_inputs(prog)
@@ -634,6 +634,17 @@ def test_no_warning_when_native_kernel_present(monkeypatch, capsys):
     vm._warn_native_missing()
     assert "make native" not in capsys.readouterr().err
     assert vm._NATIVE_WARNED is False
+
+
+def test_fused_plan_path_carries_the_device_kind():
+    """A fused plan's measurements are keyed by the device kind, and the
+    cache prune still parses the keyed file name."""
+    program, _ = bb._program("g1_subgroup")
+    tag = vm_compile.device_kind_tag()
+    assert tag == "cpu"
+    path = vm_compile._plan_cache_path(program)
+    assert os.path.basename(path).endswith(f"_d{tag}.pkl")
+    assert not bb._vm_cache_entry_stale(os.path.basename(path))
 
 
 # -- full-registry identity (out of tier-1) --------------------------------

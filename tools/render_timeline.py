@@ -1,13 +1,15 @@
 #!/usr/bin/env python
-"""Render a soak time-series JSONL into one self-contained HTML/SVG
-timeline (`make soak-bench` / the soak-smoke CI artifact).
+"""Render a time-series JSONL dump into one self-contained HTML/SVG
+timeline: the gauge history of a long run (a simnet run sampling
+`chain/health.py` ledgers per slot, or a fleet's merged store), one
+panel per gauge family.
 
 Input is the artifact `obs/timeseries.py::dump_wire_jsonl` writes: one
 header line (interval, levels, point count), then one line per
 (resolution, point) with plain gauge values and histogram-delta
 percentile summaries. Output is a single HTML file with inline SVG —
-no JavaScript, no external assets, nothing to fetch: the file a CI run
-attaches is the file a browser opens, offline, years later.
+no JavaScript, no external assets, nothing to fetch: the file opens in
+a browser offline, years later.
 
 Panels group dynamically-labelled gauge families onto shared axes:
 ``health[n0].participation_rate`` and ``health[n3].participation_rate``
@@ -16,8 +18,8 @@ single sick node shows up as the diverging line, which is the whole
 point of recording per-node families side by side.
 
 Usage:
-  python tools/render_timeline.py soak_artifacts/soak_timeseries.jsonl \\
-      -o soak_artifacts/soak_timeline.html \\
+  python tools/render_timeline.py timeseries.jsonl \\
+      -o timeline.html \\
       [--match REGEX] [--resolution SECONDS]
 
 ``--match`` filters gauge labels (default: the consensus health family
@@ -172,11 +174,11 @@ def render_html(header, rows, match, resolution=None):
     parts = [
         "<!DOCTYPE html>",
         '<html><head><meta charset="utf-8">',
-        "<title>telemetry soak timeline</title>",
+        "<title>telemetry timeline</title>",
         "<style>body{font-family:monospace;margin:24px;}"
         "svg{display:block;margin-bottom:10px;}</style>",
         "</head><body>",
-        "<h2>telemetry soak timeline</h2>",
+        "<h2>telemetry timeline</h2>",
         f"<p>source interval {header.get('interval_s', '?')}s · "
         f"plotted resolution {_fmt(res)}s · "
         f"{len(selected)} points · retention rings "
@@ -184,8 +186,8 @@ def render_html(header, rows, match, resolution=None):
         f"match <code>{html.escape(match)}</code></p>",
     ]
     if not panels:
-        parts.append("<p><b>no gauge labels matched</b> — the soak ran "
-                     "with the matched families disabled?</p>")
+        parts.append("<p><b>no gauge labels matched</b> — the run had "
+                     "the matched families disabled?</p>")
     for title in sorted(panels):
         parts.append(render_panel(title, panels[title]))
     parts.append("</body></html>")

@@ -5,7 +5,7 @@ GENERATED spec (reference Makefile:133-136, linter.ini) in an image that
 ships neither tool (no installs allowed). Two layers:
 
 1. SOURCE checks over every repo .py file (symtable-based, pyflakes-class)
-   — the walk covers the package, tests/, tools/, bench.py and
+   — the walk covers the package, tests/, tools/, chip_smoke.py and
    __graft_entry__.py, so the repo's own tooling is linted too:
    - undefined names: a symbol referenced in any scope that is neither
      local, nor enclosing, nor module-level, nor a builtin. This is the
@@ -61,7 +61,7 @@ def _py_files():
             for f in sorted(files):
                 if f.endswith(".py"):
                     yield os.path.join(dirpath, f)
-    for f in ("bench.py", "__graft_entry__.py"):
+    for f in ("chip_smoke.py", "__graft_entry__.py"):
         yield os.path.join(REPO, f)
 
 
